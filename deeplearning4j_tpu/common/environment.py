@@ -529,8 +529,8 @@ class Environment:
     # -- attention auto-dispatch (kernels/__init__.py) ---------------------
     def flash_min_seq(self) -> int:
         """Minimum sequence length at which flash=True configs actually
-        run the Pallas flash kernel; below it the XLA path wins (BENCH_r05:
-        93.7 vs 1373 samples/sec at seq_len=128) and is silently used."""
+        run the Pallas flash kernel; below it the XLA path is used (the
+        crossover is not measured on current code)."""
         v = self.property(SystemProperties.FLASH_MIN_SEQ)
         try:
             return int(v)

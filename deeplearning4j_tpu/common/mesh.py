@@ -42,27 +42,18 @@ DATA, FSDP, TENSOR, SEQ, PIPE = "data", "fsdp", "tensor", "seq", "pipe"
 # a 2-D ("batch"|"data", "model") mesh with jit inserting the collectives)
 MODEL = "model"
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.5
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False, **kw):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma, **kw)
-except ImportError:  # jax 0.4.x: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False, **kw):
-        # check_rep must stay False: 0.4.x has no replication rule for
-        # pallas_call, so check_rep=True rejects the flash-ring bodies
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma, **kw)
+from jax import shard_map as _shard_map
 
 
-def axis_size(axis):
-    """lax.axis_size (jax >= 0.5), or the static psum-of-1 idiom on 0.4.x."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False, **kw):
+    """``jax.shard_map`` with ``check_vma`` defaulting to False: there is
+    no replication rule for ``pallas_call``, so the checked form rejects
+    the flash-ring bodies."""
+    return _shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=check_vma, **kw)
+
+
+axis_size = lax.axis_size
 
 
 @dataclasses.dataclass

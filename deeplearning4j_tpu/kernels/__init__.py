@@ -7,9 +7,8 @@ that list is short (XLA fuses most of the op library); the kernels here
 cover the known gaps for the flagship workloads:
 
 - `flash_attention`: online-softmax attention with a full Pallas backward —
-  no [S,S] HBM materialization in either direction. Measured on v5e at
-  B=4 S=2048 H=12 D=64: 1.27x XLA forward, 1.64x XLA training step; at
-  S=8192 the XLA path cannot compile on one chip while this trains.
+  no [S,S] HBM materialization in either direction. Its speed against
+  XLA attention is not measured on current code.
 - `paged_flash_decode`: the decode-side counterpart — walks the paged KV
   block tables in-kernel (scalar-prefetch) with online-softmax
   accumulation, replacing the `jnp.take` gather read of
@@ -116,11 +115,10 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
     seq_len really is the attention width. Gather-view callers that pass
     no tiling info (``paged_prefill``) always get "paged".
 
-    BENCH_r05 measured the flash BERT variant at 93.7 samples/sec vs 1373
-    for plain XLA attention at seq_len=128 — the Pallas kernel's blocking
-    only pays past roughly ``DL4J_TPU_FLASH_MIN_SEQ`` (default 1024), so
-    below the threshold flash-requesting models silently take the XLA
-    path. Evaluated at trace time (shapes are static under jit), so the
+    At short sequences the Pallas kernel's blocking does not pay (where
+    the crossover lies is not measured on current code), so below
+    ``DL4J_TPU_FLASH_MIN_SEQ`` (default 1024) flash-requesting models
+    take the XLA path and the decision is recorded with its reason. Evaluated at trace time (shapes are static under jit), so the
     ``dl4j_attn_dispatch_total{path=}`` and
     ``dl4j_kernel_dispatch_total{kernel,path}`` counters tick once per
     compiled executable, and the debug log fires once per process.

@@ -45,9 +45,8 @@ def _interpret() -> bool:
 
 
 def _params(n_parallel):
-    # CompilerParams (jax >= 0.5) was TPUCompilerParams in 0.4.x
-    cp = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cp(dimension_semantics=("parallel",) * n_parallel + ("arbitrary",))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * n_parallel + ("arbitrary",))
 
 
 # ---------------------------------------------------------------------------

@@ -59,10 +59,7 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str):
-    # lax.axis_size (jax >= 0.5), or the static psum-of-1 idiom on 0.4.x
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    return lax.axis_size(axis)
 
 
 def broadcast_from(x, axis: str, root: int = 0):

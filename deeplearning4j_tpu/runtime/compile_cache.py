@@ -82,7 +82,7 @@ from ..common.locks import ordered_lock
 log = logging.getLogger(__name__)
 
 #: bump to invalidate every existing on-disk entry (layout change)
-FORMAT_VERSION = 2
+FORMAT_VERSION = 3
 
 _PAYLOAD_EXT = ".bin"
 _META_EXT = ".json"
@@ -790,7 +790,13 @@ def _backstop_wanted() -> bool:
     unstable when XLA:CPU deserializes them under churn — reproducible
     nondeterministic SIGABRTs / corrupted updates mid-train-step across
     full-suite runs, gone with the backstop off — so auto keeps CPU on
-    the store alone."""
+    the store alone.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the outside has placed
+    jax's cache: it stays there, whatever the mode, and this module never
+    touches ``jax_compilation_cache_dir``."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return False
     mode = environment().xla_cache()
     if mode == "on":
         return True
@@ -1040,8 +1046,6 @@ def cost_analysis(compiled) -> Optional[dict]:
     out = {}
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-            ca = ca[0] if ca else {}
         if isinstance(ca, dict):
             for key, name in (("flops", "flops"),
                               ("bytes accessed", "bytes_accessed")):
@@ -1129,7 +1133,10 @@ def _serialize(compiled) -> Tuple[bytes, dict]:
     kept = getattr(compiled._executable, "_kept_var_idx", None)
     if kept is None:
         raise ValueError("executable exposes no kept_var_idx")
+    # the executable's device assignment, in its own order: deserialize
+    # must be handed the same devices back
     meta = {"kept_var_idx": sorted(int(i) for i in kept),
+            "device_ids": [int(d.id) for d in exe.local_devices()],
             "created": time.time()}
     sharded = _sharding_meta(compiled)
     if sharded:
@@ -1164,14 +1171,17 @@ def _load_executor(payload: bytes, meta: dict, lowered) -> Optional[Callable]:
             # injected deserialize fault: the caller must fall back to a
             # live recompile, never surface the failure to a request
             faults.check("cache.deserialize")
+        from jax._src.lib import xla_client as xc
         backend = jax.devices()[0].client
-        exe = backend.deserialize_executable(payload)
+        by_id = {d.id: d for d in jax.devices()}
+        exe = backend.deserialize_executable(
+            payload,
+            xc.DeviceList(tuple(by_id[i] for i in meta["device_ids"])))
         kept = meta["kept_var_idx"]
         out_tree = lowered.out_tree
         in_sh = out_sh = out_avals = None
         mesh_meta = meta.get("mesh")
         if mesh_meta:
-            by_id = {d.id: d for d in jax.devices()}
             devs = np.asarray(
                 [by_id[i] for i in mesh_meta["device_ids"]],
                 dtype=object).reshape(mesh_meta["shape"])
@@ -1218,10 +1228,14 @@ def aot_entry(jfn, tag: str, args, jit_kwargs: Dict[str, Any]
     - ``"miss"``   — lowered + compiled AOT, serialized into the store;
     - ``"bypass:<reason>"`` — caching disabled, entry ineligible for raw
       serialization (e.g. ``bypass:donation`` for the DecodeEngine's
-      donated-KV steps), or a step failed: the live ``jax.jit`` dispatch
-      is returned unchanged (the jax persistent-cache backstop still
-      shortens its compile when enabled). ``dl4j_compiles_total`` records
-      the base label; the reasoned form lands on ``dl4j_compile_seconds``.
+      donated-KV steps), or a step failed (``bypass:lower-error``,
+      ``:compile-error``, ``:serialize-error``, ``:store-error``; logged
+      at warning): the live ``jax.jit`` dispatch is returned unchanged
+      (the jax persistent-cache backstop still shortens its compile when
+      enabled). ``dl4j_compiles_total`` records the base label; the
+      reasoned form lands on ``dl4j_compile_seconds``, and a stored
+      entry that failed to load is observed there as
+      ``bypass:deserialize-error`` before it is recompiled.
     """
     cc = cache()
     if cc is None:
@@ -1233,27 +1247,36 @@ def aot_entry(jfn, tag: str, args, jit_kwargs: Dict[str, Any]
         lowered = jfn.lower(*args)
         key = cache_key(lowered, jit_kwargs, args)
     except Exception as e:
-        log.debug("AOT lowering failed for %s (%s); live jit", tag, e)
+        log.warning("AOT lowering failed for %s (%s: %s); live jit", tag,
+                    type(e).__name__, e)
         return jfn, "bypass:lower-error"
     entry = cc.get(key)
     if entry is not None:
+        t0 = time.perf_counter()
         call = _load_executor(entry[0], entry[1], lowered)
         if call is not None:
             return call, "hit"
-        cc._drop(key)  # deserialization failure: stale artifact
+        # stale or foreign artifact: drop it, recompile below, and count
+        # the drop under its own label — an entry this same
+        # jax/jaxlib/backend wrote must load, so a non-zero count outside
+        # fault injection is a defect, not a cache miss
+        cc._drop(key)
+        observe_compile(tag.split(":")[0], "bypass:deserialize-error",
+                        time.perf_counter() - t0)
     try:
         compiled = lowered.compile()
     except Exception as e:
-        log.debug("AOT compile failed for %s (%s); live jit", tag, e)
+        log.warning("AOT compile failed for %s (%s: %s); live jit", tag,
+                    type(e).__name__, e)
         return jfn, "bypass:compile-error"
     try:
         payload, meta = _serialize(compiled)
         meta["tag_kind"] = tag.split(":")[0]
         stored = cc.put(key, payload, meta)
     except Exception as e:
-        log.debug("executable serialization unavailable for %s (%s); "
-                  "backstop only", tag, e)
-        return compiled, "bypass:serialize"
+        log.warning("executable serialization failed for %s (%s: %s); "
+                    "entry not stored", tag, type(e).__name__, e)
+        return compiled, "bypass:serialize-error"
     return compiled, ("miss" if stored else "bypass:store-error")
 
 

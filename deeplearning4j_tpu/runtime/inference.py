@@ -160,7 +160,8 @@ def counted_jit(fn: Callable, tag: str, **jit_kwargs) -> Callable:
     fails to accept a call (e.g. the param tree was re-initialized with
     new shapes under an unchanged data signature), the entry permanently
     falls back to the live jit for that signature — cache problems may
-    cost a compile, never an exception.
+    cost a compile, never an exception; each such fallback is logged at
+    warning and observed as ``cache=bypass:call-error``.
     """
     from . import compile_cache
 
@@ -188,9 +189,8 @@ def counted_jit(fn: Callable, tag: str, **jit_kwargs) -> Callable:
             else:
                 try:
                     out = call(*args)
-                except Exception:
-                    entries[sig] = jfn
-                    return jfn(*args)
+                except Exception as e:
+                    return call_failed(sig, e, args)
             compile_cache.observe_compile(kind, label,
                                           time.perf_counter() - t0)
             entries[sig] = call
@@ -199,11 +199,22 @@ def counted_jit(fn: Callable, tag: str, **jit_kwargs) -> Callable:
             return jfn(*args)
         try:
             return call(*args)
-        except Exception:
-            entries[sig] = jfn
-            return jfn(*args)
+        except Exception as e:
+            return call_failed(sig, e, args)
+
+    def call_failed(sig, e, args):
+        # the resolved executable refused the call: this signature rides
+        # the live jit from here on, and the event is counted under its
+        # own label so nothing that watches the cache mistakes it for a hit
+        logging.getLogger(__name__).warning(
+            "AOT executable for %s refused a call (%s: %s); live jit",
+            tag, type(e).__name__, e)
+        compile_cache.observe_compile(kind, "bypass:call-error", 0.0)
+        entries[sig] = jfn
+        return jfn(*args)
 
     wrapped._jit = jfn
+    wrapped.lower = jfn.lower
     return wrapped
 
 

@@ -201,6 +201,14 @@ class TestStoreRoundTrip:
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(_x() @ jnp.ones((16, 32))),
                                    rtol=1e-6)
+        # ... and the fallback is counted under its own label
+        assert _observed("tstale", "bypass:call-error") == 1
+
+    def test_counted_jit_lowers_like_the_jit_it_wraps(self):
+        f = counted_jit(lambda p, x: x @ p, tag="tlower:1")
+        lowered = f.lower(jnp.ones((16, 16)), _x())
+        assert "stablehlo" in lowered.as_text()
+        assert lowered.compile()(jnp.ones((16, 16)), _x()).shape == (4, 16)
 
     def test_sharded_predict_hits_store_on_warm_restart(self, fresh_cache):
         # the fleet regression: a mesh-sharded predict executable must be
@@ -269,6 +277,15 @@ class TestStoreRoundTrip:
 # corruption recovery: a bad cache may cost a compile, never an exception
 # ---------------------------------------------------------------------------
 
+def _observed(kind, cache_label):
+    """Observation count of dl4j_compile_seconds{kind, cache}."""
+    from deeplearning4j_tpu.common.metrics import registry
+    fam = registry().get("dl4j_compile_seconds")
+    return sum(child.count() for key, child in
+               (fam.children() if fam else [])
+               if key == (kind, cache_label))
+
+
 def _entry_files(cc, ext):
     return [os.path.join(cc.aot_dir, n) for n in os.listdir(cc.aot_dir)
             if n.endswith(ext)]
@@ -333,9 +350,28 @@ class TestCorruptionRecovery:
             with open(meta_p) as fh:
                 meta = json.load(fh)
             fresh_cache.put(key, b"not-an-executable",
-                            {"kept_var_idx": meta["kept_var_idx"]})
+                            {"kept_var_idx": meta["kept_var_idx"],
+                             "device_ids": meta["device_ids"]})
+        before = _observed("tcor", "bypass:deserialize-error")
         out = self._rerun()
         np.testing.assert_array_equal(ref, out)
+        # the drop is counted under its own label, not passed off as a miss
+        assert _observed("tcor", "bypass:deserialize-error") == before + 1
+
+    def test_entry_records_its_devices_and_loads(self, fresh_cache):
+        """jaxlib's deserialize needs the executable's devices: they are
+        stored with the entry, and a healthy entry loads as a hit with no
+        deserialize-error observed."""
+        ref = self._seed_entry(fresh_cache)
+        (meta_p,) = _entry_files(fresh_cache, ".json")
+        with open(meta_p) as fh:
+            assert json.load(fh)["device_ids"] == [jax.devices()[0].id]
+        before = _observed("tcor", "bypass:deserialize-error")
+        hits = fresh_cache.stats["hits"]
+        np.testing.assert_array_equal(ref, self._rerun())
+        assert fresh_cache.stats["hits"] == hits + 1
+        assert _observed("tcor", "hit") >= 1
+        assert _observed("tcor", "bypass:deserialize-error") == before
 
     def test_truncated_payload_recompiles(self, fresh_cache):
         ref = self._seed_entry(fresh_cache)
@@ -562,7 +598,9 @@ class TestWarmCompile:
     def test_warm_compile_populates_backstop_without_stepping(
             self, fresh_cache, monkeypatch):
         # the backstop defaults off on the CPU backend (DL4J_TPU_XLA_CACHE
-        # =auto); force it on to exercise the wiring
+        # =auto); force it on to exercise the wiring — which exists only
+        # where the outside has not placed jax's cache itself
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.setenv("DL4J_TPU_XLA_CACHE", "on")
         compile_cache.reset_cache()
         try:

@@ -1,0 +1,204 @@
+"""Compile the main path's Pallas kernels for a TPU v5e that is described,
+not attached — the one file in the suite that describes a chip.
+
+The TPU compiler is installed with jax; it compiles for a ``v5e:2x2``
+topology without a device, and refuses what the chip's compiler would
+refuse (a slice off the tiling, too much VMEM, a program that does not
+fit). Nothing runs, so these say nothing about results or times — they
+guard every later PR against a kernel that stops compiling, at no chip
+time. Interpret-mode tests cannot: they accept any shape.
+
+Only one process may load libtpu, so the topology is described inside a
+fixture (never at import, never in a ``skipif``), the kernels compile in
+this test process, and every case lives in this one file.
+"""
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+# the package re-exports each kernel function under its module's name
+fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
+pfd = importlib.import_module("deeplearning4j_tpu.kernels.paged_flash_decode")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    from deeplearning4j_tpu.common.mesh import MeshConfig, make_mesh
+    return make_mesh(MeshConfig(data=2, tensor=2), devices=topo.devices)
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to jax's persistent
+    cache but cannot be read back without the chip (the next one warns
+    and recompiles): switch the cache off around these tests."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch, no_persistent_cache):
+    """Steer the kernels to compile (not interpret) though the backend
+    here is the CPU."""
+    for mod in (fa, pfd):  # pfd binds its own name for the same switch
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *specs):
+    compiled = jax.jit(fn).lower(*specs).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return compiled, text
+
+
+FLASH_SHAPES = [(4, 12, 2048, 64), (32, 12, 128, 64), (1, 12, 8192, 64)]
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_flash_attention_compiles_for_v5e(shape, grad, one_chip,
+                                          compiled_kernels):
+    B, H, S, D = shape
+    spec = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    if grad:
+        def fn(q, k, v):
+            return jax.grad(lambda q, k, v: jnp.sum(
+                fa.flash_attention(q, k, v).astype(jnp.float32) ** 2),
+                argnums=(0, 1, 2))(q, k, v)
+    else:
+        fn = fa.flash_attention
+    compiled, _ = _compile(fn, spec, spec, spec)
+    if S > 128:  # O(S) memory: no [S, S] scores among the temporaries
+        assert compiled.memory_analysis().temp_size_in_bytes < \
+            B * H * S * S * 2
+
+
+PAGED_CASES = [(1, 12, 64, jnp.bfloat16), (1, 16, 128, jnp.bfloat16),
+               (5, 16, 128, jnp.bfloat16), (1, 16, 128, jnp.float32)]
+
+
+@pytest.mark.parametrize(
+    "case", PAGED_CASES,
+    ids=lambda c: f"Q{c[0]}-H{c[1]}-D{c[2]}-{jnp.dtype(c[3]).name}")
+def test_paged_flash_decode_compiles_for_v5e(case, one_chip,
+                                             compiled_kernels):
+    """Pool [512, 16, H, D], 8 slots x 64 table columns (max_ctx 1024 at
+    the default block size). head_dim 64 compiles too: ``tileable()``
+    keeps ``auto`` off it as a performance guess, not a compile limit."""
+    Q, H, D, dtype = case
+    S, MB, NB, Bs = 8, 64, 512, 16
+    sds = lambda shape, dt: _sds(shape, dt, one_chip)
+    _compile(pfd.paged_flash_decode,
+             sds((S, Q, H, D), dtype), sds((NB, Bs, H, D), dtype),
+             sds((NB, Bs, H, D), dtype), sds((S, MB), jnp.int32),
+             sds((S,), jnp.int32))
+
+
+DEQUANT_CASES = [(8, 768, 3072, jnp.bfloat16), (1, 768, 3072, jnp.bfloat16),
+                 (256, 3072, 768, jnp.bfloat16), (8, 2048, 1024, jnp.float32)]
+
+
+@pytest.mark.parametrize(
+    "case", DEQUANT_CASES,
+    ids=lambda c: f"{c[0]}x{c[1]}x{c[2]}-{jnp.dtype(c[3]).name}")
+def test_fused_dequant_matmul_compiles_for_v5e(case, one_chip,
+                                               compiled_kernels):
+    from deeplearning4j_tpu.quant.transforms import _fused_dequant_matmul
+    M, K, N, dtype = case
+    sds = lambda shape, dt: _sds(shape, dt, one_chip)
+    _compile(lambda x, q, s: _fused_dequant_matmul(x, q, s, False),
+             sds((M, K), dtype), sds((K, N), jnp.int8),
+             sds((N,), jnp.float32))
+
+
+def test_paged_decode_call_site_compiles_for_v5e(one_chip, compiled_kernels):
+    """The kernel inside its call site: ``models.causal_lm.paged_decode``
+    hands it the strided per-layer slice ``cache_k[:, i]`` of the
+    ``[blocks, layers, block, heads, head_dim]`` pool. Serving widths
+    (hidden 768, 12 heads of 64, vocab 32000), depth cut to 2."""
+    from deeplearning4j_tpu.common.environment import environment
+    from deeplearning4j_tpu.models import causal_lm
+    config = causal_lm.CausalLMConfig(num_layers=2)
+    S, MB, Bs = 8, 64, 16
+    place = lambda t: jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), t)
+    params = place(jax.eval_shape(
+        lambda: causal_lm.init_params(jax.random.key(0), config)))
+    cache = place(jax.eval_shape(
+        lambda: causal_lm.init_paged_kv_cache(config, S * MB + 1, Bs)))
+    sds = lambda shape: _sds(shape, jnp.int32, one_chip)
+    env = environment()
+    env.set_paged_kernel("on")
+    try:
+        _compile(lambda p, c, t, tok, ln: causal_lm.paged_decode(
+            p, c, t, tok, ln, config),
+            params, cache, sds((S, MB)), sds((S, 1)), sds((S,)))
+    finally:
+        env.set_paged_kernel(None)
+
+
+def test_sharded_bert_step_compiles_for_the_2x2_mesh(mesh_2x2,
+                                                     no_persistent_cache):
+    """A data=2 x tensor=2 BERT loss+grad compiled for the described
+    mesh: the compiler partitions it and puts the collectives in."""
+    from deeplearning4j_tpu.models import bert
+    config = bert.BertConfig.tiny()
+    params = jax.tree_util.tree_map(
+        lambda a, s: _sds(a.shape, a.dtype, NamedSharding(mesh_2x2, s)),
+        jax.eval_shape(lambda: bert.init_params(jax.random.key(0), config)),
+        bert.param_specs(config))
+    batch = {k: _sds((8, 32), jnp.int32, NamedSharding(mesh_2x2, P("data")))
+             for k in ("input_ids", "labels", "attention_mask")}
+    compiled = jax.jit(jax.value_and_grad(
+        lambda p, b: bert.mlm_loss(p, b, config, mesh=mesh_2x2))).lower(
+            params, batch).compile()
+    assert "all-reduce" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n_seq", [1, 4])
+def test_ring_flash_compiles_for_v5e(n_seq, topo, compiled_kernels):
+    """The flash kernel per KV block inside the sequence-parallel ring
+    (``shard_map`` around ``pallas_call``), at the bench's shape: one
+    device degenerates to a single scan step; four carry the ring's
+    collective-permutes."""
+    from deeplearning4j_tpu.common.mesh import MeshConfig, make_mesh
+    from deeplearning4j_tpu.parallel.ring_attention import ring_attention
+    mesh = make_mesh(MeshConfig(data=1, seq=n_seq),
+                     devices=topo.devices[:n_seq])
+    spec = _sds((4, 2048, 12, 64), jnp.float32,
+                NamedSharding(mesh, P(None, "seq")))
+    _, text = _compile(
+        lambda q, k, v: ring_attention(q, k, v, mesh, use_flash=True),
+        spec, spec, spec)
+    assert ("collective-permute" in text) == (n_seq > 1)
