@@ -28,6 +28,11 @@ Primitives:
   spans this way; contextvars do not cross threads).
 - ``capture_profile(seconds)`` — on-demand ``jax.profiler`` device
   capture for the ``/debug/profile`` endpoint.
+- ``model_scope(name)`` — the device-side counterpart of ``span()``:
+  ``jax.named_scope("dl4j.<name>")`` around a model component inside a
+  traced function, so every device operation of a jitted step carries
+  the component it belongs to in its ``op_name`` (the profiler's
+  ``tf_op``). Trace-time only: the compiled program is unchanged.
 
 Export (``tracer().export(path)``) writes exactly the format
 `load_trace`/`aggregate` consume — atomically (tmp + rename, parent dirs
@@ -155,6 +160,23 @@ def context_from_traceparent(header: Optional[str]) -> TraceContext:
     trace."""
     ctx = parse_traceparent(header)
     return ctx if ctx is not None else TraceContext(new_trace_id())
+
+
+#: every model scope is ``dl4j.<component>``: the fixed prefix is how a
+#: reader finds the scopes in an operation's ``op_name`` path
+#: (``jit(step)/transpose(jvp(dl4j.attn))/dl4j.attn_core/mul``) without a
+#: list of components kept in two places
+MODEL_SCOPE_PREFIX = "dl4j."
+
+
+def model_scope(name: str):
+    """``with model_scope("attn_core"): ...`` inside traced model code:
+    the operations traced in the block are named ``dl4j.attn_core`` in
+    the compiled program's metadata, forward and (under autodiff)
+    backward. Nothing runs at step time and there is nothing to switch
+    off; the innermost scope is the one an operation is counted under."""
+    import jax
+    return jax.named_scope(MODEL_SCOPE_PREFIX + name)
 
 
 class _NullSpan:

@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..common.tracing import model_scope
 from ..ops import updater_ops
 
 
@@ -21,17 +22,18 @@ def adam_apply(params, grads, opt_state, learning_rate, iteration,
     flat_p = jax.tree_util.tree_flatten(params)[0]
     u, m = opt_state
     new_p, new_u, new_m = [], [], []
-    for p, g, ui, mi in zip(flat_p, flat_g, u, m):
-        g_ = g.astype(jnp.float32) if cast_f32 else g
-        upd, u2, m2 = updater_ops.adam_updater(g_, ui, mi,
-                                               lr=learning_rate,
-                                               iteration=iteration)
-        if cast_f32:
-            new_p.append((p.astype(jnp.float32) - upd).astype(p.dtype))
-        else:
-            new_p.append(p - upd)
-        new_u.append(u2)
-        new_m.append(m2)
+    with model_scope("optimizer"):
+        for p, g, ui, mi in zip(flat_p, flat_g, u, m):
+            g_ = g.astype(jnp.float32) if cast_f32 else g
+            upd, u2, m2 = updater_ops.adam_updater(g_, ui, mi,
+                                                   lr=learning_rate,
+                                                   iteration=iteration)
+            if cast_f32:
+                new_p.append((p.astype(jnp.float32) - upd).astype(p.dtype))
+            else:
+                new_p.append(p - upd)
+            new_u.append(u2)
+            new_m.append(m2)
     return jax.tree_util.tree_unflatten(treedef, new_p), (new_u, new_m)
 
 

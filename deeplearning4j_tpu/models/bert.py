@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..common.tracing import model_scope
 from ..parallel.mesh import DATA, FSDP, PIPE, SEQ, TENSOR
 from ..quant.transforms import (dequant_matmul, dequantize, take_rows,
                                 tied_logits)
@@ -150,10 +151,11 @@ def param_specs(config: BertConfig) -> Dict:
 
 
 def _ln(x, g, b, eps):
-    x32 = x.astype(jnp.float32)
-    mean = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.var(x32, axis=-1, keepdims=True)
-    return ((x32 - mean) * lax.rsqrt(var + eps) * g + b).astype(x.dtype)
+    with model_scope("ln"):
+        x32 = x.astype(jnp.float32)
+        mean = jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.var(x32, axis=-1, keepdims=True)
+        return ((x32 - mean) * lax.rsqrt(var + eps) * g + b).astype(x.dtype)
 
 
 # -- forward ------------------------------------------------------------
@@ -175,40 +177,44 @@ def _attention(layer_params, h, attention_mask, config: BertConfig,
     tp_reduce after the output projection); None means replicated weights
     or GSPMD-annotated sharding (XLA inserts the collectives)."""
     a = layer_params["attn"]
-    if tp_axis is not None:
-        from ..parallel.pipeline import tp_copy
-        h_in = tp_copy(h, tp_axis)
-    else:
-        h_in = h
-    q = jnp.einsum("bte,ehd->bthd", h_in,
-                   dequantize(a["wq"], h_in.dtype)) + a["bq"]
-    k = jnp.einsum("bte,ehd->bthd", h_in,
-                   dequantize(a["wk"], h_in.dtype)) + a["bk"]
-    v = jnp.einsum("bte,ehd->bthd", h_in,
-                   dequantize(a["wv"], h_in.dtype)) + a["bv"]
-    if seq_parallel and mesh is not None:
-        # use_flash composes with SP: the Pallas kernel computes each
-        # K/V block inside the ring (VERDICT r4 #4 / SURVEY §5)
-        ctx = ring_attention(q, k, v, mesh, mask=attention_mask,
-                             causal=False, use_flash=use_flash)
-    elif use_flash and _flash_effective(q.shape[1]):
-        from ..kernels import flash_attention
-        ctx = flash_attention(q, k, v, mask=attention_mask)
-    else:
-        scale = config.head_dim ** -0.5
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32) * scale
-        if attention_mask is not None:
-            big_neg = jnp.finfo(jnp.float32).min
-            logits = jnp.where(attention_mask[:, None, None, :].astype(bool),
-                               logits, big_neg)
-        probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    out = jnp.einsum("bqhd,hde->bqe", ctx, dequantize(a["wo"], ctx.dtype))
-    if tp_axis is not None:
-        from ..parallel.pipeline import tp_reduce
-        out = tp_reduce(out, tp_axis)
-    return out + a["bo"]
+    with model_scope("attn"):
+        if tp_axis is not None:
+            from ..parallel.pipeline import tp_copy
+            h_in = tp_copy(h, tp_axis)
+        else:
+            h_in = h
+        q = jnp.einsum("bte,ehd->bthd", h_in,
+                       dequantize(a["wq"], h_in.dtype)) + a["bq"]
+        k = jnp.einsum("bte,ehd->bthd", h_in,
+                       dequantize(a["wk"], h_in.dtype)) + a["bk"]
+        v = jnp.einsum("bte,ehd->bthd", h_in,
+                       dequantize(a["wv"], h_in.dtype)) + a["bv"]
+        with model_scope("attn_core"):
+            if seq_parallel and mesh is not None:
+                # use_flash composes with SP: the Pallas kernel computes
+                # each K/V block inside the ring (VERDICT r4 #4 / SURVEY §5)
+                ctx = ring_attention(q, k, v, mesh, mask=attention_mask,
+                                     causal=False, use_flash=use_flash)
+            elif use_flash and _flash_effective(q.shape[1]):
+                from ..kernels import flash_attention
+                ctx = flash_attention(q, k, v, mask=attention_mask)
+            else:
+                scale = config.head_dim ** -0.5
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                                    preferred_element_type=jnp.float32) * scale
+                if attention_mask is not None:
+                    big_neg = jnp.finfo(jnp.float32).min
+                    logits = jnp.where(
+                        attention_mask[:, None, None, :].astype(bool),
+                        logits, big_neg)
+                probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
+                ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        out = jnp.einsum("bqhd,hde->bqe", ctx,
+                         dequantize(a["wo"], ctx.dtype))
+        if tp_axis is not None:
+            from ..parallel.pipeline import tp_reduce
+            out = tp_reduce(out, tp_axis)
+        return out + a["bo"]
 
 
 def encode(params, input_ids, token_type_ids=None, attention_mask=None, *,
@@ -218,12 +224,13 @@ def encode(params, input_ids, token_type_ids=None, attention_mask=None, *,
     c = config
     e = params["embeddings"]
     B, T = input_ids.shape
-    h = take_rows(e["word"], input_ids, dtype=c.dtype)
-    h = h + e["position"][None, :T]
-    if token_type_ids is not None:
-        h = h + jnp.take(e["token_type"], token_type_ids, axis=0)
-    else:
-        h = h + e["token_type"][0]
+    with model_scope("embed"):
+        h = take_rows(e["word"], input_ids, dtype=c.dtype)
+        h = h + e["position"][None, :T]
+        if token_type_ids is not None:
+            h = h + jnp.take(e["token_type"], token_type_ids, axis=0)
+        else:
+            h = h + e["token_type"][0]
     h = _ln(h, e["ln_g"], e["ln_b"], c.layer_norm_eps)
     if mesh is not None:
         h = lax.with_sharding_constraint(
@@ -235,13 +242,14 @@ def encode(params, input_ids, token_type_ids=None, attention_mask=None, *,
                               use_flash)
         h = _ln(h + attn_out, layer["ln1_g"], layer["ln1_b"], c.layer_norm_eps)
         mlp = layer["mlp"]
-        inter = jax.nn.gelu(dequant_matmul(h, mlp["w1"]) + mlp["b1"])
-        if mesh is not None:
-            inter = lax.with_sharding_constraint(
-                inter, NamedSharding(
-                    mesh, P((DATA, FSDP), SEQ if seq_parallel else None,
-                            TENSOR)))
-        mlp_out = dequant_matmul(inter, mlp["w2"]) + mlp["b2"]
+        with model_scope("mlp"):
+            inter = jax.nn.gelu(dequant_matmul(h, mlp["w1"]) + mlp["b1"])
+            if mesh is not None:
+                inter = lax.with_sharding_constraint(
+                    inter, NamedSharding(
+                        mesh, P((DATA, FSDP), SEQ if seq_parallel else None,
+                                TENSOR)))
+            mlp_out = dequant_matmul(inter, mlp["w2"]) + mlp["b2"]
         h = _ln(h + mlp_out, layer["ln2_g"], layer["ln2_b"], c.layer_norm_eps)
         if mesh is not None:
             h = lax.with_sharding_constraint(
@@ -253,11 +261,13 @@ def encode(params, input_ids, token_type_ids=None, attention_mask=None, *,
 def mlm_logits(params, encodings, config: BertConfig):
     """Masked-LM head with tied decoder weights."""
     m = params["mlm"]
-    h = jax.nn.gelu(dequant_matmul(encodings, m["dense"]) + m["dense_b"])
-    h = _ln(h, m["ln_g"], m["ln_b"], config.layer_norm_eps)
-    # tied decoder: per-row scales of a quantized word table fold into the
-    # f32 logits
-    return tied_logits(h, params["embeddings"]["word"]) + m["bias"]
+    with model_scope("head"):
+        h = jax.nn.gelu(dequant_matmul(encodings, m["dense"])
+                        + m["dense_b"])
+        h = _ln(h, m["ln_g"], m["ln_b"], config.layer_norm_eps)
+        # tied decoder: per-row scales of a quantized word table fold into
+        # the f32 logits
+        return tied_logits(h, params["embeddings"]["word"]) + m["bias"]
 
 
 def pooled(params, encodings):
@@ -278,14 +288,15 @@ def mlm_loss(params, batch, config: BertConfig, mesh=None,
                  config=config, mesh=mesh, seq_parallel=seq_parallel,
                  use_flash=use_flash)
     logits = mlm_logits(params, enc, config)
-    labels = batch["labels"]
-    valid = labels >= 0
-    safe_labels = jnp.where(valid, labels, 0)
-    lsm = jax.nn.log_softmax(logits, axis=-1)
-    per_tok = -jnp.take_along_axis(lsm, safe_labels[..., None],
-                                   axis=-1)[..., 0]
-    per_tok = jnp.where(valid, per_tok, 0.0)
-    return jnp.sum(per_tok) / jnp.maximum(jnp.sum(valid), 1)
+    with model_scope("loss"):
+        labels = batch["labels"]
+        valid = labels >= 0
+        safe_labels = jnp.where(valid, labels, 0)
+        lsm = jax.nn.log_softmax(logits, axis=-1)
+        per_tok = -jnp.take_along_axis(lsm, safe_labels[..., None],
+                                       axis=-1)[..., 0]
+        per_tok = jnp.where(valid, per_tok, 0.0)
+        return jnp.sum(per_tok) / jnp.maximum(jnp.sum(valid), 1)
 
 
 # -- training step ------------------------------------------------------
@@ -540,31 +551,35 @@ def make_pipeline_train_step(config: BertConfig, mesh: Mesh,
             h = _ln(h + attn_out, layer["ln1_g"], layer["ln1_b"],
                     c.layer_norm_eps)
             mlp = layer["mlp"]
-            hin = tp_copy(h, TENSOR) if tp > 1 else h
-            inter = jax.nn.gelu(jnp.einsum("bte,ef->btf", hin, mlp["w1"])
-                                + mlp["b1"])
-            part = jnp.einsum("btf,fe->bte", inter, mlp["w2"])
-            if tp > 1:
-                part = tp_reduce(part, TENSOR)
-            mlp_out = part + mlp["b2"]
+            with model_scope("mlp"):
+                hin = tp_copy(h, TENSOR) if tp > 1 else h
+                inter = jax.nn.gelu(
+                    jnp.einsum("bte,ef->btf", hin, mlp["w1"]) + mlp["b1"])
+                part = jnp.einsum("btf,fe->bte", inter, mlp["w2"])
+                if tp > 1:
+                    part = tp_reduce(part, TENSOR)
+                mlp_out = part + mlp["b2"]
             h = _ln(h + mlp_out, layer["ln2_g"], layer["ln2_b"],
                     c.layer_norm_eps)
         return h
 
     def head_fn(head_params, y, aux):
         m = head_params["mlm"]
-        h = jax.nn.gelu(jnp.einsum("bte,ef->btf", y, m["dense"])
-                        + m["dense_b"])
-        h = _ln(h, m["ln_g"], m["ln_b"], c.layer_norm_eps)
-        logits = jnp.einsum("bte,ve->btv", h, head_params["word"])
-        logits = logits.astype(jnp.float32) + m["bias"]
-        labels = aux["labels"]
-        valid = labels >= 0
-        safe = jnp.where(valid, labels, 0)
-        lsm = jax.nn.log_softmax(logits, axis=-1)
-        per_tok = -jnp.take_along_axis(lsm, safe[..., None], axis=-1)[..., 0]
-        per_tok = jnp.where(valid, per_tok, 0.0)
-        return jnp.sum(per_tok), jnp.sum(valid).astype(jnp.float32)
+        with model_scope("head"):
+            h = jax.nn.gelu(jnp.einsum("bte,ef->btf", y, m["dense"])
+                            + m["dense_b"])
+            h = _ln(h, m["ln_g"], m["ln_b"], c.layer_norm_eps)
+            logits = jnp.einsum("bte,ve->btv", h, head_params["word"])
+            logits = logits.astype(jnp.float32) + m["bias"]
+        with model_scope("loss"):
+            labels = aux["labels"]
+            valid = labels >= 0
+            safe = jnp.where(valid, labels, 0)
+            lsm = jax.nn.log_softmax(logits, axis=-1)
+            per_tok = -jnp.take_along_axis(lsm, safe[..., None],
+                                           axis=-1)[..., 0]
+            per_tok = jnp.where(valid, per_tok, 0.0)
+            return jnp.sum(per_tok), jnp.sum(valid).astype(jnp.float32)
 
     # per-leaf specs only needed for tp; the default P(pipe) blanket
     # otherwise (spec trees act as pytree prefixes of the stage params)
@@ -589,10 +604,11 @@ def make_pipeline_train_step(config: BertConfig, mesh: Mesh,
         e = params["embeddings"]
         ids = batch["input_ids"]
         B, T = ids.shape
-        h = jnp.take(e["word"], ids, axis=0) + e["position"][None, :T]
-        tt = batch.get("token_type_ids")
-        h = h + (jnp.take(e["token_type"], tt, axis=0) if tt is not None
-                 else e["token_type"][0])
+        with model_scope("embed"):
+            h = jnp.take(e["word"], ids, axis=0) + e["position"][None, :T]
+            tt = batch.get("token_type_ids")
+            h = h + (jnp.take(e["token_type"], tt, axis=0)
+                     if tt is not None else e["token_type"][0])
         h = _ln(h, e["ln_g"], e["ln_b"], c.layer_norm_eps)
         head_params = {"mlm": params["mlm"], "word": e["word"]}
         aux = {"labels": batch["labels"]}

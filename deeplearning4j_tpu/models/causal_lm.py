@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..common.tracing import model_scope
 from ..quant.transforms import (dequant_matmul, dequantize, take_rows,
                                 tied_logits)
 from .bert import _ln
@@ -142,22 +143,25 @@ def _mlp_ln(layer, h, attn_out, c: CausalLMConfig):
     mlp = layer["mlp"]
     # dequant_matmul == einsum("...e,ef->...f") for plain weights, and the
     # int8/fp8-at-rest contraction for a quantized twin
-    inter = jax.nn.gelu(dequant_matmul(h, mlp["w1"]) + mlp["b1"])
-    mlp_out = dequant_matmul(inter, mlp["w2"]) + mlp["b2"]
+    with model_scope("mlp"):
+        inter = jax.nn.gelu(dequant_matmul(h, mlp["w1"]) + mlp["b1"])
+        mlp_out = dequant_matmul(inter, mlp["w2"]) + mlp["b2"]
     return _ln(h + mlp_out, layer["ln2_g"], layer["ln2_b"], c.layer_norm_eps)
 
 
 def _embed(params, input_ids, positions, c: CausalLMConfig):
     e = params["embeddings"]
-    h = take_rows(e["word"], input_ids, dtype=c.dtype)
-    h = h + jnp.take(e["position"], positions, axis=0)
+    with model_scope("embed"):
+        h = take_rows(e["word"], input_ids, dtype=c.dtype)
+        h = h + jnp.take(e["position"], positions, axis=0)
     return _ln(h, e["ln_g"], e["ln_b"], c.layer_norm_eps)
 
 
 def _lm_logits(params, h):
     """Tied word-embedding head, f32 logits (per-row scales of a
     quantized word table multiply the logits)."""
-    return tied_logits(h, params["embeddings"]["word"])
+    with model_scope("head"):
+        return tied_logits(h, params["embeddings"]["word"])
 
 
 _BIG_NEG = jnp.finfo(jnp.float32).min
@@ -170,22 +174,27 @@ def _causal_block(layer, h, c: CausalLMConfig, use_flash: bool = False):
 
     a = layer["attn"]
     B, T = h.shape[0], h.shape[1]
-    q = jnp.einsum("bte,ehd->bthd", h, dequantize(a["wq"], h.dtype)) + a["bq"]
-    k = jnp.einsum("bte,ehd->bthd", h, dequantize(a["wk"], h.dtype)) + a["bk"]
-    v = jnp.einsum("bte,ehd->bthd", h, dequantize(a["wv"], h.dtype)) + a["bv"]
-    if use_flash and attention_dispatch(T) == "flash":
-        from ..kernels import flash_attention
-        ctx = flash_attention(q, k, v, causal=True)
-    else:
-        scale = (q.shape[-1]) ** -0.5
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32) * scale
-        causal = jnp.tril(jnp.ones((T, T), bool))
-        logits = jnp.where(causal[None, None], logits, _BIG_NEG)
-        probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    out = jnp.einsum("bqhd,hde->bqe", ctx,
-                     dequantize(a["wo"], h.dtype)) + a["bo"]
+    with model_scope("attn"):
+        q = jnp.einsum("bte,ehd->bthd", h,
+                       dequantize(a["wq"], h.dtype)) + a["bq"]
+        k = jnp.einsum("bte,ehd->bthd", h,
+                       dequantize(a["wk"], h.dtype)) + a["bk"]
+        v = jnp.einsum("bte,ehd->bthd", h,
+                       dequantize(a["wv"], h.dtype)) + a["bv"]
+        with model_scope("attn_core"):
+            if use_flash and attention_dispatch(T) == "flash":
+                from ..kernels import flash_attention
+                ctx = flash_attention(q, k, v, causal=True)
+            else:
+                scale = (q.shape[-1]) ** -0.5
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                                    preferred_element_type=jnp.float32) * scale
+                causal = jnp.tril(jnp.ones((T, T), bool))
+                logits = jnp.where(causal[None, None], logits, _BIG_NEG)
+                probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
+                ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        out = jnp.einsum("bqhd,hde->bqe", ctx,
+                         dequantize(a["wo"], h.dtype)) + a["bo"]
     return _mlp_ln(layer, h, out, c), (k, v)
 
 
@@ -224,8 +233,9 @@ def prefill(params, cache, input_ids, slot, length, config: CausalLMConfig):
     upd_k = jnp.stack(ks)[None].astype(cache["k"].dtype)  # [1, L, T, H, Dh]
     upd_v = jnp.stack(vs)[None].astype(cache["v"].dtype)
     start = (slot, 0, 0, 0, 0)
-    cache = {"k": lax.dynamic_update_slice(cache["k"], upd_k, start),
-             "v": lax.dynamic_update_slice(cache["v"], upd_v, start)}
+    with model_scope("kv_write"):
+        cache = {"k": lax.dynamic_update_slice(cache["k"], upd_k, start),
+                 "v": lax.dynamic_update_slice(cache["v"], upd_v, start)}
     last = lax.dynamic_index_in_dim(h[0], length - 1, axis=0,
                                     keepdims=False)
     return cache, _lm_logits(params, last)
@@ -258,23 +268,28 @@ def decode(params, cache, tokens, lengths, config: CausalLMConfig):
     cache_k, cache_v = cache["k"], cache["v"]
     for i, layer in enumerate(params["layers"]):
         a = layer["attn"]
-        q = jnp.einsum("se,ehd->shd", h, dequantize(a["wq"], h.dtype)) \
-            + a["bq"]
-        k = jnp.einsum("se,ehd->shd", h, dequantize(a["wk"], h.dtype)) \
-            + a["bk"]
-        v = jnp.einsum("se,ehd->shd", h, dequantize(a["wv"], h.dtype)) \
-            + a["bv"]
-        cache_k = cache_k.at[rows, i, lengths].set(
-            k.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[rows, i, lengths].set(
-            v.astype(cache_v.dtype), mode="drop")
-        att = jnp.einsum("shd,schd->shc", q, cache_k[:, i],
-                         preferred_element_type=jnp.float32) * scale
-        att = jnp.where(key_mask[:, None, :], att, _BIG_NEG)
-        probs = jax.nn.softmax(att, axis=-1).astype(h.dtype)
-        ctx = jnp.einsum("shc,schd->shd", probs, cache_v[:, i])
-        out = jnp.einsum("shd,hde->se", ctx,
-                         dequantize(a["wo"], h.dtype)) + a["bo"]
+        with model_scope("attn"):
+            q = jnp.einsum("se,ehd->shd", h,
+                           dequantize(a["wq"], h.dtype)) + a["bq"]
+            k = jnp.einsum("se,ehd->shd", h,
+                           dequantize(a["wk"], h.dtype)) + a["bk"]
+            v = jnp.einsum("se,ehd->shd", h,
+                           dequantize(a["wv"], h.dtype)) + a["bv"]
+            with model_scope("kv_write"):
+                cache_k = cache_k.at[rows, i, lengths].set(
+                    k.astype(cache_k.dtype), mode="drop")
+                cache_v = cache_v.at[rows, i, lengths].set(
+                    v.astype(cache_v.dtype), mode="drop")
+            with model_scope("kv_read"):
+                ks, vs = cache_k[:, i], cache_v[:, i]
+            with model_scope("attn_core"):
+                att = jnp.einsum("shd,schd->shc", q, ks,
+                                 preferred_element_type=jnp.float32) * scale
+                att = jnp.where(key_mask[:, None, :], att, _BIG_NEG)
+                probs = jax.nn.softmax(att, axis=-1).astype(h.dtype)
+                ctx = jnp.einsum("shc,schd->shd", probs, vs)
+            out = jnp.einsum("shd,hde->se", ctx,
+                             dequantize(a["wo"], h.dtype)) + a["bo"]
         h = _mlp_ln(layer, h, out, c)
     return {"k": cache_k, "v": cache_v}, _lm_logits(params, h)
 
@@ -352,29 +367,33 @@ def paged_prefill(params, cache, input_ids, tables, lengths,
     cache_k, cache_v = cache["k"], cache["v"]
     for i, layer in enumerate(params["layers"]):
         a = layer["attn"]
-        q = jnp.einsum("bte,ehd->bthd", h, dequantize(a["wq"], h.dtype)) \
-            + a["bq"]
-        k = jnp.einsum("bte,ehd->bthd", h, dequantize(a["wk"], h.dtype)) \
-            + a["bk"]
-        v = jnp.einsum("bte,ehd->bthd", h, dequantize(a["wv"], h.dtype)) \
-            + a["bv"]
-        cache_k = cache_k.at[blk, i, off].set(
-            k.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[blk, i, off].set(
-            v.astype(cache_v.dtype), mode="drop")
-        # gather each row's blocks into its contiguous [C] key view: the
-        # cached prefix rows plus the tail rows written just above
-        ks = jnp.take(cache_k[:, i], tables, axis=0).reshape(
-            B, C, c.num_heads, c.head_dim)
-        vs = jnp.take(cache_v[:, i], tables, axis=0).reshape(
-            B, C, c.num_heads, c.head_dim)
-        att = jnp.einsum("bqhd,bchd->bhqc", q, ks,
-                         preferred_element_type=jnp.float32) * scale
-        att = jnp.where(key_mask[:, None], att, _BIG_NEG)
-        probs = jax.nn.softmax(att, axis=-1).astype(h.dtype)
-        ctx = jnp.einsum("bhqc,bchd->bqhd", probs, vs)
-        out = jnp.einsum("bqhd,hde->bqe", ctx,
-                         dequantize(a["wo"], h.dtype)) + a["bo"]
+        with model_scope("attn"):
+            q = jnp.einsum("bte,ehd->bthd", h,
+                           dequantize(a["wq"], h.dtype)) + a["bq"]
+            k = jnp.einsum("bte,ehd->bthd", h,
+                           dequantize(a["wk"], h.dtype)) + a["bk"]
+            v = jnp.einsum("bte,ehd->bthd", h,
+                           dequantize(a["wv"], h.dtype)) + a["bv"]
+            with model_scope("kv_write"):
+                cache_k = cache_k.at[blk, i, off].set(
+                    k.astype(cache_k.dtype), mode="drop")
+                cache_v = cache_v.at[blk, i, off].set(
+                    v.astype(cache_v.dtype), mode="drop")
+            # gather each row's blocks into its contiguous [C] key view:
+            # the cached prefix rows plus the tail rows written just above
+            with model_scope("kv_read"):
+                ks = jnp.take(cache_k[:, i], tables, axis=0).reshape(
+                    B, C, c.num_heads, c.head_dim)
+                vs = jnp.take(cache_v[:, i], tables, axis=0).reshape(
+                    B, C, c.num_heads, c.head_dim)
+            with model_scope("attn_core"):
+                att = jnp.einsum("bqhd,bchd->bhqc", q, ks,
+                                 preferred_element_type=jnp.float32) * scale
+                att = jnp.where(key_mask[:, None], att, _BIG_NEG)
+                probs = jax.nn.softmax(att, axis=-1).astype(h.dtype)
+                ctx = jnp.einsum("bhqc,bchd->bqhd", probs, vs)
+            out = jnp.einsum("bqhd,hde->bqe", ctx,
+                             dequantize(a["wo"], h.dtype)) + a["bo"]
         h = _mlp_ln(layer, h, out, c)
     last = jnp.take_along_axis(
         h, jnp.clip(lengths - start_pos - 1, 0, T - 1)[:, None, None],
@@ -425,34 +444,44 @@ def paged_decode(params, cache, tables, tokens, lengths,
     cache_k, cache_v = cache["k"], cache["v"]
     for i, layer in enumerate(params["layers"]):
         a = layer["attn"]
-        q = jnp.einsum("sqe,ehd->sqhd", h, dequantize(a["wq"], h.dtype)) \
-            + a["bq"]
-        k = jnp.einsum("sqe,ehd->sqhd", h, dequantize(a["wk"], h.dtype)) \
-            + a["bk"]
-        v = jnp.einsum("sqe,ehd->sqhd", h, dequantize(a["wv"], h.dtype)) \
-            + a["bv"]
-        cache_k = cache_k.at[blk, i, off].set(
-            k.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[blk, i, off].set(
-            v.astype(cache_v.dtype), mode="drop")
-        if path == "paged_flash":
-            # walk the block table in-kernel: each pool block is DMA'd
-            # once, straight from its pool position — no gathered copy
-            ctx = paged_flash_decode(q, cache_k[:, i], cache_v[:, i],
-                                     tables, lengths, scale=scale)
-        else:
-            # gather each slot's blocks into its contiguous [C] key view
-            ks = jnp.take(cache_k[:, i], tables, axis=0).reshape(
-                S, C, c.num_heads, c.head_dim)
-            vs = jnp.take(cache_v[:, i], tables, axis=0).reshape(
-                S, C, c.num_heads, c.head_dim)
-            att = jnp.einsum("sqhd,schd->shqc", q, ks,
-                             preferred_element_type=jnp.float32) * scale
-            att = jnp.where(key_mask[:, None], att, _BIG_NEG)
-            probs = jax.nn.softmax(att, axis=-1).astype(h.dtype)
-            ctx = jnp.einsum("shqc,schd->sqhd", probs, vs)
-        out = jnp.einsum("sqhd,hde->sqe", ctx,
-                         dequantize(a["wo"], h.dtype)) + a["bo"]
+        with model_scope("attn"):
+            q = jnp.einsum("sqe,ehd->sqhd", h,
+                           dequantize(a["wq"], h.dtype)) + a["bq"]
+            k = jnp.einsum("sqe,ehd->sqhd", h,
+                           dequantize(a["wk"], h.dtype)) + a["bk"]
+            v = jnp.einsum("sqe,ehd->sqhd", h,
+                           dequantize(a["wv"], h.dtype)) + a["bv"]
+            with model_scope("kv_write"):
+                cache_k = cache_k.at[blk, i, off].set(
+                    k.astype(cache_k.dtype), mode="drop")
+                cache_v = cache_v.at[blk, i, off].set(
+                    v.astype(cache_v.dtype), mode="drop")
+            if path == "paged_flash":
+                # walk the block table in-kernel: each pool block is
+                # DMA'd once, straight from its pool position — no
+                # gathered copy
+                with model_scope("kv_read"):
+                    pool_k, pool_v = cache_k[:, i], cache_v[:, i]
+                with model_scope("attn_core"):
+                    ctx = paged_flash_decode(q, pool_k, pool_v, tables,
+                                             lengths, scale=scale)
+            else:
+                # gather each slot's blocks into its contiguous [C] key
+                # view
+                with model_scope("kv_read"):
+                    ks = jnp.take(cache_k[:, i], tables, axis=0).reshape(
+                        S, C, c.num_heads, c.head_dim)
+                    vs = jnp.take(cache_v[:, i], tables, axis=0).reshape(
+                        S, C, c.num_heads, c.head_dim)
+                with model_scope("attn_core"):
+                    att = jnp.einsum(
+                        "sqhd,schd->shqc", q, ks,
+                        preferred_element_type=jnp.float32) * scale
+                    att = jnp.where(key_mask[:, None], att, _BIG_NEG)
+                    probs = jax.nn.softmax(att, axis=-1).astype(h.dtype)
+                    ctx = jnp.einsum("shqc,schd->sqhd", probs, vs)
+            out = jnp.einsum("sqhd,hde->sqe", ctx,
+                             dequantize(a["wo"], h.dtype)) + a["bo"]
         h = _mlp_ln(layer, h, out, c)
     return {"k": cache_k, "v": cache_v}, _lm_logits(params, h)
 

@@ -82,12 +82,23 @@ Observability: ``dl4j_decode_requests_total``, ``dl4j_decode_tokens_total``,
 ``dl4j_tokens_total{model,slo=ok|violated}`` — a token is "good" when
 its request's TTFT met the per-model latency objective
 (``DL4J_TPU_SLO_LATENCY_MS``; with no objective set every token is ok). Each request's trace gains a
-``generation/prefill`` span (queue wait + prompt dispatch, TTFT) and a
-``generation/decode`` span (first token → finish), and its result
+``generation/queue`` span (submit → its first prefill dispatch), a
+``generation/prefill`` span (the prompt dispatch; with the queue, TTFT)
+and a ``generation/decode`` span (first token → finish), and its result
 carries a ``phases`` dict (``queue_s``/``prefill_s``/``decode_s``) so
 ``/debug/requests`` reconstructs — and attributes — a generation's
 timeline end to end; ``/debug/decode`` dumps the live slot map and
-block tables.
+block tables. The scheduler thread's own loop is live ``span()``s under
+one trace id per thread: ``generation/idle`` (waiting for work),
+``generation/admit`` (with ``generation/prefill_dispatch`` per prefill
+group), ``generation/step`` (children ``generation/ensure_blocks``,
+``generation/decode_dispatch``, ``generation/readback`` — the wait for
+the device — and ``generation/emit``) and ``generation/reconcile``.
+Under a device profile they are host events on the profiler's clock, so
+a device gap is attributed to the phase that covered it. Inside the
+jitted steps the device operations carry model scopes
+(``common.tracing.model_scope``: ``dl4j.embed|attn|attn_core|kv_write|
+kv_read|mlp|ln|head|sample``).
 """
 from __future__ import annotations
 
@@ -106,7 +117,9 @@ from ..common.environment import environment
 from ..common.locks import (ordered_condition, ordered_lock,
                             ordered_rlock)
 from ..common.metrics import exponential_buckets, registry
-from ..common.tracing import current_context, record_disposition, tracer
+from ..common.tracing import (TraceContext, current_context, model_scope,
+                              new_trace_id, record_disposition, span,
+                              tracer, use_context)
 from .inference import (EngineClosedError, bucket_for, bucket_ladder,
                         counted_jit)
 
@@ -143,15 +156,16 @@ def sample_tokens(logits, temperature, top_k, key):
     stay one fused program with fixed shapes.
     """
     V = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    k = jnp.clip(jnp.where(top_k <= 0, V, top_k), 1, V)
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    thr = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
-    masked = jnp.where(scaled >= thr, scaled, -jnp.inf)
-    sampled = jnp.argmax(masked + jax.random.gumbel(key, logits.shape),
-                         axis=-1).astype(jnp.int32)
-    return jnp.where(temperature > 0, sampled, greedy)
+    with model_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        k = jnp.clip(jnp.where(top_k <= 0, V, top_k), 1, V)
+        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+        thr = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
+        masked = jnp.where(scaled >= thr, scaled, -jnp.inf)
+        sampled = jnp.argmax(masked + jax.random.gumbel(key, logits.shape),
+                             axis=-1).astype(jnp.int32)
+        return jnp.where(temperature > 0, sampled, greedy)
 
 
 # ---------------------------------------------------------------------------
@@ -819,18 +833,22 @@ class DecodeEngine:
         with self._dispatch_lock:
             self._dispatch_started_at = time.monotonic()
             try:
-                cache, nxt = self._decode(
-                    self._params, self._cache, jnp.asarray(self._tables),
-                    jnp.asarray(self._tokens), jnp.asarray(self._lengths),
-                    jnp.asarray(active), jnp.asarray(self._temps),
-                    jnp.asarray(self._topks),
-                    jnp.asarray(self._seed, jnp.int32),
-                    jnp.asarray(self._step, jnp.int32))
+                with span("generation/decode_dispatch"):
+                    cache, nxt = self._decode(
+                        self._params, self._cache,
+                        jnp.asarray(self._tables),
+                        jnp.asarray(self._tokens),
+                        jnp.asarray(self._lengths),
+                        jnp.asarray(active), jnp.asarray(self._temps),
+                        jnp.asarray(self._topks),
+                        jnp.asarray(self._seed, jnp.int32),
+                        jnp.asarray(self._step, jnp.int32))
                 self._cache = cache
                 self._step += 1
             finally:
                 self._dispatch_started_at = None
-        return np.asarray(nxt)
+        with span("generation/readback"):     # waits for the device
+            return np.asarray(nxt)
 
     def _run_spec(self, active):
         if faults.active():
@@ -839,17 +857,19 @@ class DecodeEngine:
         with self._dispatch_lock:
             self._dispatch_started_at = time.monotonic()
             try:
-                cache, dcache, commit, n_commit = self._spec(
-                    self._params, self._dparams, self._cache,
-                    self._dcache, jnp.asarray(self._tables),
-                    jnp.asarray(self._tokens), jnp.asarray(self._lengths),
-                    jnp.asarray(active))
+                with span("generation/decode_dispatch", spec=True):
+                    cache, dcache, commit, n_commit = self._spec(
+                        self._params, self._dparams, self._cache,
+                        self._dcache, jnp.asarray(self._tables),
+                        jnp.asarray(self._tokens),
+                        jnp.asarray(self._lengths), jnp.asarray(active))
                 self._cache = cache
                 self._dcache = dcache
                 self._step += 1
             finally:
                 self._dispatch_started_at = None
-        return np.asarray(commit), np.asarray(n_commit)
+        with span("generation/readback"):     # waits for the device
+            return np.asarray(commit), np.asarray(n_commit)
 
     # -- warmup ------------------------------------------------------------
     def warmup(self, example=None,
@@ -989,7 +1009,11 @@ class DecodeEngine:
             base_s=0.01, max_s=2.0, seed=0)
         while True:
             try:
-                self._loop()
+                # the scheduler's own trace: its loop spans
+                # (generation/idle|admit|step|...) nest under one trace id
+                # per thread, apart from every request's tree
+                with use_context(TraceContext(new_trace_id())):
+                    self._loop()
                 return  # normal stop
             except Exception:
                 log.exception("decode loop crashed; restarting")
@@ -1026,16 +1050,21 @@ class DecodeEngine:
             with self._cv:
                 while (not self._pending and self._active_n == 0
                        and not self._stopping):
-                    self._cv.wait()
+                    # no work: a device gap under this span is the
+                    # traffic's, not the scheduler's
+                    with span("generation/idle"):
+                        self._cv.wait()
                 if (self._stopping and not self._pending
                         and self._active_n == 0):
                     if self._thread is threading.current_thread():
                         self._thread = None
                     return
             try:
-                self._admit_pending()
+                with span("generation/admit"):
+                    self._admit_pending()
                 if self._active_n > 0:
-                    self._decode_once()
+                    with span("generation/step"):
+                        self._decode_once()
             except Exception as e:  # a dispatch fault must not strand
                 # futures — but it fails only THIS dispatch's riders
                 # (the active slots); queued requests stay queued and
@@ -1043,7 +1072,8 @@ class DecodeEngine:
                 log.exception("decode dispatch failed; failing its "
                               "riders only")
                 self._fail_dispatch_riders(e)
-            self._reconcile_slots()
+            with span("generation/reconcile"):
+                self._reconcile_slots()
 
     def _fail_dispatch_riders(self, exc: Exception):
         """Fail + release only the sequences that rode the failed
@@ -1452,8 +1482,9 @@ class DecodeEngine:
             temps[r] = req.temperature
             topks[r] = req.top_k
         t0 = time.perf_counter()
-        toks = self._run_prefill(ids, tables, lengths, starts, temps,
-                                 topks)
+        with span("generation/prefill_dispatch", bucket=bucket, batch=B):
+            toks = self._run_prefill(ids, tables, lengths, starts, temps,
+                                     topks)
         t_done = time.perf_counter()
         hits = sum(1 for req in group if req.start > 0)
         reused = int(sum(req.start for req in group))
@@ -1479,6 +1510,9 @@ class DecodeEngine:
                 # preempted rider keeps its original boundary so queue
                 # attribution stays honest across requeues
                 req.t_prefill0 = t0
+                if req.ctx is not None:
+                    tracer().record("generation/queue", req.t_submit, t0,
+                                    context=req.ctx)
             if first:
                 req.t_first = t_done
             if self._reg.enabled:
@@ -1536,8 +1570,9 @@ class DecodeEngine:
             return self._blocks_deficit(k + 1) <= self._available_blocks()
 
     def _decode_once(self):
-        spec = self._spec_ready()
-        self._ensure_blocks(self.spec_k + 1 if spec else 1)
+        with span("generation/ensure_blocks"):
+            spec = self._spec_ready()
+            self._ensure_blocks(self.spec_k + 1 if spec else 1)
         active = np.array([r is not None for r in self._slot_req])
         if not active.any():
             return
@@ -1548,14 +1583,15 @@ class DecodeEngine:
             with self._stats_lock:
                 self._stats["decode_steps"] += 1
             self._m_steps.inc()
-            for slot, req in enumerate(list(self._slot_req)):
-                if req is None:
-                    continue
-                self._lengths[slot] += 1
-                tok = int(nxt[slot])
-                self._tokens[slot] = tok
-                self._emit_token(req, tok)
-                self._check_stop(req, slot, tok)
+            with span("generation/emit"):
+                for slot, req in enumerate(list(self._slot_req)):
+                    if req is None:
+                        continue
+                    self._lengths[slot] += 1
+                    tok = int(nxt[slot])
+                    self._tokens[slot] = tok
+                    self._emit_token(req, tok)
+                    self._check_stop(req, slot, tok)
 
     def _spec_once(self, active):
         commit, n_commit = self._run_spec(active)
@@ -1570,17 +1606,18 @@ class DecodeEngine:
         self._m_steps.inc()
         self._m_spec_proposed.inc(k * n_active)
         self._m_spec_accepted.inc(accepted)
-        for slot, req in enumerate(list(self._slot_req)):
-            if req is None:
-                continue
-            for j in range(int(n_commit[slot])):
-                tok = int(commit[slot, j])
-                self._lengths[slot] += 1
-                self._tokens[slot] = tok
-                self._emit_token(req, tok)
-                self._check_stop(req, slot, tok)
-                if self._slot_req[slot] is not req:
-                    break  # finished mid-prefix: drop the rest
+        with span("generation/emit"):
+            for slot, req in enumerate(list(self._slot_req)):
+                if req is None:
+                    continue
+                for j in range(int(n_commit[slot])):
+                    tok = int(commit[slot, j])
+                    self._lengths[slot] += 1
+                    self._tokens[slot] = tok
+                    self._emit_token(req, tok)
+                    self._check_stop(req, slot, tok)
+                    if self._slot_req[slot] is not req:
+                        break  # finished mid-prefix: drop the rest
 
     def _emit_token(self, req: _GenRequest, tok: int):
         req.tokens.append(tok)
