@@ -446,8 +446,8 @@ def _kernel_flash(shape, dtype, seed):
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
     # the decision a flash=True model takes at this length
-    check(attention_dispatch(S) == "flash",
-          f"attention_dispatch({S}) did not pick the kernel")
+    check(attention_dispatch(S, head_dim=D) == "flash",
+          f"attention_dispatch({S}, head_dim={D}) did not pick the kernel")
     snap = dispatch_snapshot()["attention"]
 
     def grads(fn):

@@ -294,7 +294,6 @@ _DEFAULTS = {
     SystemProperties.CACHE_TIER: "auto",
     SystemProperties.XLA_CACHE: "auto",
     SystemProperties.WARMUP_THREADS: "0",  # 0 = auto
-    SystemProperties.FLASH_MIN_SEQ: "1024",
     SystemProperties.PAGED_KERNEL: "auto",
     SystemProperties.FUSED_DEQUANT: "auto",
     SystemProperties.INFERENCE_BUCKETING: "1",
@@ -527,17 +526,22 @@ class Environment:
             return 0
 
     # -- attention auto-dispatch (kernels/__init__.py) ---------------------
-    def flash_min_seq(self) -> int:
-        """Minimum sequence length at which flash=True configs actually
-        run the Pallas flash kernel; below it the XLA path is used (the
-        crossover is not measured on current code)."""
+    def flash_min_seq(self) -> Optional[int]:
+        """Override of ``kernels.attention_dispatch``'s measured rule:
+        unset (None, the default) the rule decides from sequence length,
+        head_dim and backend; set, attention takes the Pallas flash kernel
+        from this sequence length up on any backend (interpreted on the
+        CPU — how the tests steer a model onto the kernel)."""
         v = self.property(SystemProperties.FLASH_MIN_SEQ)
         try:
             return int(v)
         except (TypeError, ValueError):
-            return 1024
+            return None
 
-    def set_flash_min_seq(self, n: int):
+    def set_flash_min_seq(self, n: Optional[int]):
+        """Programmatic override; None restores the rule."""
+        if n is None:
+            return self.clear_property(SystemProperties.FLASH_MIN_SEQ)
         return self.set_property(SystemProperties.FLASH_MIN_SEQ, int(n))
 
     def paged_kernel(self) -> str:

@@ -6,9 +6,13 @@ letting the compiler lower naively leaves performance on the table. On TPU
 that list is short (XLA fuses most of the op library); the kernels here
 cover the known gaps for the flagship workloads:
 
-- `flash_attention`: online-softmax attention with a full Pallas backward —
-  no [S,S] HBM materialization in either direction. Its speed against
-  XLA attention is not measured on current code.
+- `flash_attention`: attention with the scores kept in VMEM, forward and a
+  full Pallas backward — no [S,S] HBM materialization in either direction.
+  Up to S=512 a head is one tile and the backward one fused kernel; longer
+  sequences stream tiles with an online softmax. `attention_dispatch`
+  chooses between it and XLA by a measured rule (`_flash_rule` has the
+  sweep: on a v5e the kernel wins training from S=256 up, 2.9x at BERT's
+  S=512 with heads of 64).
 - `paged_flash_decode`: the decode-side counterpart — walks the paged KV
   block tables in-kernel (scalar-prefetch) with online-softmax
   accumulation, replacing the `jnp.take` gather read of
@@ -94,11 +98,72 @@ def _paged_path(env, head_dim, block_size):
     return "paged_flash", ""
 
 
+#: shortest sequence at which the flash kernel is chosen on an accelerator
+#: (``_flash_rule``'s table)
+_FLASH_MIN_SEQ = 256
+#: narrowest head the sweep covers; below it the one-tile kernel would
+#: spend a full-depth MXU pass on each of four or more heads a block
+_FLASH_MIN_HEAD_DIM = 64
+
+
+def _flash_rule(seq_len, head_dim):
+    """The measured rule for the non-paged path: ("flash" | "xla", reason).
+
+    One layer's attention core on one TPU v5e chip, ms per layer, q/k/v
+    bf16 with B·T = 4,096 tokens and H·D = 1,024 (the training cell's
+    sizes), an all-ones key mask when not causal; forward alone, and
+    forward+backward under ``jax.grad``. "kernel" is
+    ``flash_attention`` as it stands (one tile up to T=512, streaming
+    above), "before" the streaming kernel it was at every length
+    (``attn_sweep.py``, chip runs of PR 26):
+
+            T    D  causal |  XLA fwd  fwd+bwd | kernel fwd  fwd+bwd | before
+          128   64  no     |    0.034    0.185 |      0.129    0.326 | 0.381  1.263
+          128   64  yes    |    0.035    0.187 |      0.129    0.322 | 0.297  1.081
+          256   64  no     |    0.106    0.549 |      0.125    0.307 | 0.303  1.082
+          256   64  yes    |    0.133    0.553 |      0.125    0.302 | 0.325  0.985
+          512   64  no     |    0.416    1.169 |      0.118    0.402 | 0.278  0.994
+          512   64  yes    |    0.418    1.170 |      0.115    0.374 | 0.305  0.879
+         1024   64  no     |    0.819    2.897 |      0.413    1.649 | (same code)
+         1024   64  yes    |    0.812    2.908 |      1.180    1.482 | (same code)
+         2048   64  no     |    1.619    5.591 |      0.684    3.000 | (same code)
+         2048   64  yes    |    3.697    5.583 |      0.820    2.555 | (same code)
+          128  128  no     |    0.040    0.142 |      0.125    0.310 | 0.195  0.585
+          128  128  yes    |    0.040    0.143 |      0.125    0.306 | 0.155  0.460
+          256  128  no     |    0.062    0.243 |      0.080    0.223 | 0.154  0.502
+          256  128  yes    |    0.064    0.247 |      0.080    0.218 | 0.163  0.437
+          512  128  no     |    0.225    0.706 |      0.067    0.230 | 0.146  0.465
+          512  128  yes    |    0.226    0.719 |      0.065    0.214 | 0.157  0.405
+         1024  128  no     |    0.994    1.766 |      0.212    0.817 | (same code)
+         1024  128  yes    |    0.994    1.768 |      0.269    0.723 | (same code)
+         2048  128  no     |    1.983    4.081 |      0.344    1.479 | (same code)
+         2048  128  yes    |    1.981    4.072 |      0.414    1.254 | (same code)
+
+    The crossover lies between 128 and 256 for both head sizes, causal or
+    not, so the rule takes no ``causal``: at 128 XLA wins everything
+    (a grid step's fixed cost, about 0.4 µs, is most of a [128, 128] tile's
+    time); from 256 up the kernel wins every forward+backward row, by 1.1x
+    (T=256, D=128) to 3.3x; forward alone it wins from 512 up and loses
+    0.02 ms a layer at 256, which the rule accepts for training's sake.
+    One forward row is out of line, T=1024 D=64 causal (1.18 ms against
+    XLA's 0.81, though forward+backward wins): the streaming path, not
+    touched here. ``head_dim`` below 64 was not measured and stays on
+    XLA; ``None`` (a caller that does not say) is not checked."""
+    import jax
+    if jax.default_backend() == "cpu":
+        return "xla", "cpu backend (the kernel would run interpreted)"
+    if seq_len < _FLASH_MIN_SEQ:
+        return "xla", f"seq_len<{_FLASH_MIN_SEQ} (measured crossover)"
+    if head_dim is not None and head_dim < _FLASH_MIN_HEAD_DIM:
+        return "xla", f"head_dim<{_FLASH_MIN_HEAD_DIM} (not measured)"
+    return "flash", ""
+
+
 def attention_dispatch(seq_len: int, paged: bool = False, *,
                        head_dim: Optional[int] = None,
                        block_size: Optional[int] = None) -> str:
-    """Auto-dispatch for ``flash=True`` attention configs: "flash",
-    "xla", "paged", or "paged_flash".
+    """Auto-dispatch for attention: "flash", "xla", "paged", or
+    "paged_flash".
 
     ``paged=True`` marks the paged-KV decode path
     (``models.causal_lm.paged_decode``): when the caller passes the pool
@@ -115,13 +180,18 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
     seq_len really is the attention width. Gather-view callers that pass
     no tiling info (``paged_prefill``) always get "paged".
 
-    At short sequences the Pallas kernel's blocking does not pay (where
-    the crossover lies is not measured on current code), so below
-    ``DL4J_TPU_FLASH_MIN_SEQ`` (default 1024) flash-requesting models
-    take the XLA path and the decision is recorded with its reason. Evaluated at trace time (shapes are static under jit), so the
-    ``dl4j_attn_dispatch_total{path=}`` and
+    The non-paged path follows ``_flash_rule``: what one sweep on the
+    chip says wins, from ``seq_len``, ``head_dim`` and the backend (the
+    causal rows of the sweep agree with the others), with the reason
+    recorded when XLA is taken. On the CPU backend that is always XLA
+    (the kernel would run in the Pallas interpreter). ``DL4J_TPU_FLASH_MIN_SEQ`` is unset by default; set
+    (or ``Environment.set_flash_min_seq``), it replaces the rule with a
+    plain threshold on any backend — the hook the CPU tests use to steer
+    a model onto the interpreted kernel. Evaluated at trace time (shapes
+    are static under jit), so the ``dl4j_attn_dispatch_total{path=}`` and
     ``dl4j_kernel_dispatch_total{kernel,path}`` counters tick once per
-    compiled executable, and the debug log fires once per process.
+    traced model (``models.bert`` asks once for all its layers), and the
+    debug log fires once per process.
 
     Decode-shaped queries (seq_len < 2 — the KV-cached single-token step
     of ``runtime.generation.DecodeEngine``) take the XLA path
@@ -138,10 +208,13 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
         path, reason = _paged_path(env, head_dim, block_size)
     elif int(seq_len) < 2:
         path, reason = "xla", "seq_len<2 decode pin"
-    elif int(seq_len) >= env.flash_min_seq():
-        path = "flash"
+    elif env.flash_min_seq() is not None:
+        if int(seq_len) >= env.flash_min_seq():
+            path = "flash"
+        else:
+            path, reason = "xla", "seq_len<DL4J_TPU_FLASH_MIN_SEQ"
     else:
-        path, reason = "xla", "seq_len<DL4J_TPU_FLASH_MIN_SEQ"
+        path, reason = _flash_rule(int(seq_len), head_dim)
     try:
         env.metrics().counter(
             "dl4j_attn_dispatch_total",
@@ -154,7 +227,6 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
         _dispatch_logged = True
         import logging
         logging.getLogger(__name__).debug(
-            "flash=True requested at seq_len=%d < DL4J_TPU_FLASH_MIN_SEQ=%d;"
-            " using the XLA attention path (override the threshold via the"
-            " env var)", seq_len, env.flash_min_seq())
+            "attention at seq_len=%d takes the XLA path: %s",
+            seq_len, reason)
     return path

@@ -1,17 +1,28 @@
-"""Flash attention — Pallas TPU kernels (online softmax, O(S) memory, fwd+bwd).
+"""Flash attention — Pallas TPU kernels (softmax in VMEM, O(S) memory, fwd+bwd).
 
 Reference counterpart: the vendor-accelerated attention path
 (`libnd4j/include/ops/declarable/platform/cudnn/` attention kernels and
 `helpers/AttentionHelper.h`). On TPU the hot path is a Pallas kernel that
-keeps only [TQ, TK] score tiles in VMEM, accumulates the online softmax in
-f32 scratch, and never materializes the [S, S] probability matrix in HBM —
-forward OR backward, so S=2048+ training fits where the XLA path OOMs.
+keeps score tiles in VMEM with f32 softmax statistics and never
+materializes the [S, S] probability matrix in HBM — forward OR backward.
+Matmuls run in the input dtype with f32 accumulation; max, exp and sum are
+f32; probabilities are cast to the input dtype only for p·v, where the XLA
+path casts them too.
 
-Layout: q/k/v are [BH, S, D] (batch*heads flattened; callers reshape).
-All three kernels use a 3-D grid whose innermost dimension is the
-*sequential* stream (kv blocks for fwd/dq, q blocks for dkv) so Mosaic
-double-buffers the streamed blocks while f32 accumulators persist in VMEM
-scratch across the sequential steps:
+Two shapes of the same algorithm, chosen by the static sequence length:
+
+**One tile** (padded S <= 512, no tiles given — the training shapes): a
+head's whole [S, S] score tile lives in VMEM, so nothing streams, nothing
+accumulates across grid steps and nothing but q, k, v is saved for the
+backward, which is ONE kernel. Blocks come straight from the [B, S, H*D]
+layout of the q/k/v projections, two heads of 64 to a 128-lane block. See
+the section comment below.
+
+**Streaming** (longer S, or explicit tiles): q/k/v are [BH, S, D]
+(batch*heads flattened; callers reshape) and all three kernels use a 3-D
+grid whose innermost dimension is the *sequential* stream (kv blocks for
+fwd/dq, q blocks for dkv), so Mosaic double-buffers the streamed blocks
+while f32 accumulators persist in VMEM scratch across the sequential steps:
 
   fwd : grid (BH, nQ, nK)  scratch m/l/acc     outputs o, lse=m+log(l)
   dq  : grid (BH, nQ, nK)  scratch dq_acc      p recomputed from q,k,lse
@@ -22,10 +33,10 @@ delta = rowsum(o ⊙ do) is precomputed with plain XLA (one elementwise pass).
 Sequence lengths that don't divide the tiles are zero-padded to the tile
 boundary (padded keys masked off, padded query rows sliced away). A fully
 masked row degrades to a uniform softmax — identical to what the XLA
-softmax produces for an all-−1e30 row, and the lse identity keeps the
-backward consistent with that without special cases.
+softmax produces for an all-−1e30 row.
 
-Tests run interpret mode on CPU; the real chip runs compiled.
+Tests run interpret mode on CPU; the real chip runs compiled. Times on the
+chip against XLA: `kernels._flash_rule` (measured by `attn_sweep.py`).
 """
 from __future__ import annotations
 
@@ -371,6 +382,193 @@ def _flash_lse_masked_b(scale, causal, tile_q, tile_k, res, g):
 _flash_lse_masked.defvjp(_flash_lse_masked_f, _flash_lse_masked_b)
 
 
+# ---------------------------------------------------------------------------
+# one-tile path: the whole sequence of a head is one [S, S] score tile
+# ---------------------------------------------------------------------------
+#
+# At S_pad <= _ONE_TILE_MAX nothing streams and nothing accumulates across
+# grid steps, so the kernels drop what the streaming path needs only for
+# that: no m/l/acc scratch, no saved lse, no delta pass, and ONE backward
+# kernel that makes dq, dk and dv from one probability tile (5 matmuls and
+# one exp pass a head where the dq + dkv pair spends 7 and two).
+#
+# Blocks come straight from the [B, S, H*D] layout the projections produce
+# (a free reshape of [B, S, H, D]): a block is [S, W] with W = a whole
+# number of heads filling the 128 lanes (two heads at head_dim 64). A head
+# inside the block is selected by zeroing the other heads' lanes of ONE
+# operand of each matmul: the contraction then runs over all W lanes at the
+# MXU's full depth and the zeroed lanes add exact zeros. No moveaxis around
+# the call, no lane slicing inside it.
+#
+# The forward works on s[q, k] (row statistics by lane reduction, the key
+# mask a lane-major [1, S] row). The backward works on the transpose
+# sT[k, q], as splash attention does: the statistics are then column
+# statistics (plain VPU maxima and sums over sublanes, broadcast back for
+# free), dv = pT·do and dk = dsT·q are natural matmuls and only dq
+# contracts over the sublane axis. The key mask is needed along sublanes
+# there, so the wrapper hands it over a second time as [B, S, 1].
+
+_ONE_TILE_MAX = 512
+
+
+def _head_lanes(shape, head_dim):
+    """[(lane selector or None)] per head of a [S, W] block."""
+    n = shape[1] // head_dim
+    if n == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return [(lane >= h * head_dim) & (lane < (h + 1) * head_dim)
+            for h in range(n)]
+
+
+def _only(sel, x):
+    return x if sel is None else jnp.where(sel, x, jnp.zeros_like(x))
+
+
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_TN = (((0,), (0,)), ((), ()))   # aᵀ · b
+
+
+def _one_tile_fwd_kernel(*refs, scale, causal, masked, head_dim):
+    (q_ref, k_ref, v_ref), o_ref = refs[:3], refs[-1]
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]                      # [S, W]
+    S = q.shape[0]
+    keep = refs[3][0] != 0 if masked else None                  # [1, S]
+    if causal:
+        tri = (jax.lax.broadcasted_iota(jnp.int32, (S, S), 0) >=
+               jax.lax.broadcasted_iota(jnp.int32, (S, S), 1))
+        keep = tri if keep is None else tri & keep
+    out = None
+    for sel in _head_lanes(q.shape, head_dim):
+        s = jax.lax.dot_general(_only(sel, q), k, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if keep is not None:
+            s = jnp.where(keep, s, _NEG_INF)
+        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        o = jnp.dot(e.astype(v.dtype), v,                       # [S, W]
+                    preferred_element_type=jnp.float32)
+        o = o * (1.0 / jnp.sum(e, axis=-1, keepdims=True))
+        out = o if out is None else jnp.where(sel, o, out)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _one_tile_bwd_kernel(*refs, scale, causal, masked, head_dim):
+    q, k, v, g = (r[0] for r in refs[:4])                        # [S, W]
+    dq_ref, dk_ref, dv_ref = refs[-3:]
+    S = q.shape[0]
+    keep = refs[4][0] != 0 if masked else None                   # [S, 1]
+    if causal:                      # sT[k, q]: key row <= query column
+        tri = (jax.lax.broadcasted_iota(jnp.int32, (S, S), 0) <=
+               jax.lax.broadcasted_iota(jnp.int32, (S, S), 1))
+        keep = tri if keep is None else tri & keep
+    dq = dk = dv = None
+    for sel in _head_lanes(q.shape, head_dim):
+        kh, vh, qh, gh = (_only(sel, x) for x in (k, v, q, g))
+        st = jax.lax.dot_general(kh, q, _NT,
+                                 preferred_element_type=jnp.float32) * scale
+        if keep is not None:
+            st = jnp.where(keep, st, _NEG_INF)
+        e = jnp.exp(st - jnp.max(st, axis=0, keepdims=True))
+        pt = e * (1.0 / jnp.sum(e, axis=0, keepdims=True))       # [Sk, Sq]
+        dpt = jax.lax.dot_general(vh, g, _NT,
+                                  preferred_element_type=jnp.float32)
+        # delta = rowsum(o ⊙ do) = Σ_k p·dp, here a sum over sublanes
+        dst = pt * (dpt - jnp.sum(pt * dpt, axis=0, keepdims=True))
+        dst = dst.astype(q.dtype)
+        dv_h = jnp.dot(pt.astype(g.dtype), gh,
+                       preferred_element_type=jnp.float32)
+        dk_h = jnp.dot(dst, qh, preferred_element_type=jnp.float32)
+        dq_h = jax.lax.dot_general(dst, kh, _TN,
+                                   preferred_element_type=jnp.float32)
+        # the selected operand's zero lanes leave each head's product
+        # zero outside its own lanes: heads add without a select
+        dq = dq_h if dq is None else dq + dq_h
+        dk = dk_h if dk is None else dk + dk_h
+        dv = dv_h if dv is None else dv + dv_h
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+def _block_lanes(H, D):
+    """Lanes per block: the fewest whole heads that fill 128-lane vregs,
+    or every head where H*D does not divide that way."""
+    w = D * 128 // math.gcd(D, 128)
+    return w if (H * D) % w == 0 else H * D
+
+
+def _one_tile_call(kernel, ins, mask, n_out, D, scale, causal):
+    B, S, HD = ins[0].shape
+    W = _block_lanes(HD // D, D)
+    blk = pl.BlockSpec((1, S, W), lambda b, j: (b, 0, j))
+    in_specs, args = [blk] * len(ins), list(ins)
+    if mask is not None:
+        in_specs.append(pl.BlockSpec((1,) + mask.shape[1:],
+                                     lambda b, j: (b, 0, 0)))
+        args.append(mask)
+    return pl.pallas_call(
+        functools.partial(kernel, scale=scale, causal=causal,
+                          masked=mask is not None, head_dim=D),
+        grid=(B, HD // W),
+        in_specs=in_specs,
+        out_specs=[blk] * n_out,
+        out_shape=[jax.ShapeDtypeStruct(ins[0].shape, ins[0].dtype)] * n_out,
+        # three or four live [S, S] f32 tiles a head at S=512 (1 MiB each)
+        # beside the double-buffered [S, W] blocks: over the 16 MiB default
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+    )(*args)
+
+
+def _one_tile_fwd(q, k, v, mask, D, scale, causal):
+    mrow = None if mask is None else mask[:, None, :]
+    return _one_tile_call(_one_tile_fwd_kernel, (q, k, v), mrow, 1,
+                          D, scale, causal)[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _one_tile(q, k, v, mask, D, scale, causal):
+    """q/k/v [B, S, H*D]; mask [B, S] int32 or None."""
+    return _one_tile_fwd(q, k, v, mask, D, scale, causal)
+
+
+def _one_tile_f(q, k, v, mask, D, scale, causal):
+    return _one_tile_fwd(q, k, v, mask, D, scale, causal), (q, k, v, mask)
+
+
+def _one_tile_b(D, scale, causal, res, g):
+    q, k, v, mask = res
+    mcol = None if mask is None else mask[:, :, None]
+    dq, dk, dv = _one_tile_call(_one_tile_bwd_kernel, (q, k, v, g), mcol, 3,
+                                D, scale, causal)
+    return dq, dk, dv, None
+
+
+_one_tile.defvjp(_one_tile_f, _one_tile_b)
+
+
+def _padded_len(S):
+    return S if S <= 128 else -(-S // 128) * 128
+
+
+def _flash_one_tile(q, k, v, mask, causal, scale, D):
+    """q/k/v [B, S, H*D] -> [B, S, H*D]."""
+    B, S, _ = q.shape
+    S_pad = _padded_len(S)
+    if mask is not None:
+        mask = mask.astype(jnp.int32)
+    if S_pad != S:
+        pad = [(0, 0), (0, S_pad - S), (0, 0)]
+        q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
+        if mask is None:
+            mask = jnp.ones((B, S), jnp.int32)
+        mask = jnp.pad(mask, [(0, 0), (0, S_pad - S)])
+    out = _one_tile(q, k, v, mask, D, scale, causal)
+    return out[:, :S] if S_pad != S else out
+
+
 def _fit_tile(want, s_pad):
     """Largest multiple of 128 ≤ want that divides s_pad (s_pad is a
     multiple of 128)."""
@@ -418,18 +616,33 @@ def _prep(q, k, v, mask, scale, tile_q, tile_k):
 
 def flash_attention(q, k, v, mask=None, causal: bool = False,
                     scale: float = None, tile_q: int = None,
-                    tile_k: int = None):
-    """Flash attention over [B, S, H, D] (BTHD, the framework convention).
+                    tile_k: int = None, head_dim: int = None):
+    """Flash attention over [B, S, H, D] (BTHD, the framework convention)
+    or, with ``head_dim`` given, over [B, S, H*D] as the q/k/v projections
+    produce it (the result has the layout of the inputs).
 
     mask: optional [B, S] key validity (1 = attend). Differentiable in
     q/k/v; O(S) HBM in both forward and backward (the probability matrix
-    only ever exists as [tile_q, tile_k] VMEM tiles).
+    only ever exists as VMEM tiles).
     Any S is accepted: inputs are zero-padded to the tile boundary (padded
     keys masked off; padded query rows sliced away).
 
-    Default tiles are tuned on v5e at S=2048, D=64 (tq=2048/tk=512:
-    fwd 4.7ms vs XLA 8.8/7.1ms f32/bf16; train 5.8-6.1ms vs 13.5/7.5ms);
-    they shrink to divisors of the padded length for other shapes."""
+    With no tiles given, a padded length up to 512 takes the one-tile
+    kernels (a head's whole [S, S] score tile in VMEM, one fused backward
+    kernel, blocks straight from the packed layout) and longer sequences
+    stream [tile_q, tile_k] tiles (2048 x 512, shrunk to divisors of the
+    padded length). Times on the chip: ``kernels._flash_rule``."""
+    D = head_dim if head_dim is not None else q.shape[-1]
+    scale = scale if scale is not None else D ** -0.5
+    one_tile = (tile_q is None and tile_k is None
+                and _padded_len(q.shape[1]) <= _ONE_TILE_MAX)
+    # the one-tile kernels take heads packed, the streaming ones apart;
+    # going from one to the other is a free reshape
+    shape = q.shape
+    if one_tile:
+        q, k, v = (x.reshape(shape[:2] + (-1,)) for x in (q, k, v))
+        return _flash_one_tile(q, k, v, mask, causal, scale, D).reshape(shape)
+    q, k, v = (x.reshape(shape[:2] + (-1, D)) for x in (q, k, v))
     (qf, kf, vf, mf, scale, tile_q, tile_k,
      S, S_pad, B, H, D) = _prep(q, k, v, mask, scale, tile_q, tile_k)
     if mf is not None:
@@ -437,7 +650,7 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     else:
         out = _flash(qf, kf, vf, scale, causal, tile_q, tile_k)
     out = jnp.moveaxis(out.reshape(B, H, S_pad, D), 1, 2)
-    return out[:, :S] if S_pad != S else out
+    return (out[:, :S] if S_pad != S else out).reshape(shape)
 
 
 def flash_attention_with_lse(q, k, v, mask=None, causal: bool = False,

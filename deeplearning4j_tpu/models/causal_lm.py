@@ -182,7 +182,8 @@ def _causal_block(layer, h, c: CausalLMConfig, use_flash: bool = False):
         v = jnp.einsum("bte,ehd->bthd", h,
                        dequantize(a["wv"], h.dtype)) + a["bv"]
         with model_scope("attn_core"):
-            if use_flash and attention_dispatch(T) == "flash":
+            if use_flash and attention_dispatch(
+                    T, head_dim=q.shape[-1]) == "flash":
                 from ..kernels import flash_attention
                 ctx = flash_attention(q, k, v, causal=True)
             else:

@@ -84,7 +84,7 @@ class TestPhases:
                 lm_config=_lm_config(), slots=2, max_ctx=64, bucket=16,
                 decode_steps=3, mm_shape=(8, 128, 256), dtype=jnp.float32)
         finally:
-            env.set_flash_min_seq(1024)
+            env.set_flash_min_seq(None)
         assert rec["flash_attention"]["dispatch"]["path"] == "flash"
         assert rec["paged_decode"]["dispatch"]["path"] == "paged_flash"
         assert rec["dequant_matmul"]["dispatch"]["path"] == "fused"

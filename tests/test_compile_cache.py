@@ -652,13 +652,31 @@ class TestWarmCompile:
 # ---------------------------------------------------------------------------
 
 class TestAttentionDispatch:
-    def test_threshold_default(self):
-        from deeplearning4j_tpu.kernels import attention_dispatch
+    def test_rule_default(self, monkeypatch):
+        """Unset, the measured rule decides: XLA on the CPU backend at any
+        length; on an accelerator the kernel from the crossover up, from
+        seq_len and head_dim, with the reason recorded for XLA."""
+        from deeplearning4j_tpu import kernels
+        from deeplearning4j_tpu.kernels import (attention_dispatch,
+                                                dispatch_snapshot)
 
-        assert environment().flash_min_seq() == 1024
-        assert attention_dispatch(128) == "xla"
-        assert attention_dispatch(1024) == "flash"
-        assert attention_dispatch(4096) == "flash"
+        assert environment().flash_min_seq() is None
+        for seq in (128, 512, 1024, 4096):
+            assert attention_dispatch(seq, head_dim=64) == "xla"
+        assert "cpu backend" in dispatch_snapshot()["attention"]["reason"]
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        lo = kernels._FLASH_MIN_SEQ
+        for head_dim in (64, 128):
+            assert attention_dispatch(lo // 2, head_dim=head_dim) == "xla"
+            assert str(lo) in dispatch_snapshot()["attention"]["reason"]
+            assert attention_dispatch(lo, head_dim=head_dim) == "flash"
+            assert dispatch_snapshot()["attention"]["reason"] is None
+            assert attention_dispatch(512, head_dim=head_dim) == "flash"
+            assert attention_dispatch(4096, head_dim=head_dim) == "flash"
+        assert attention_dispatch(512, head_dim=32) == "xla"  # not measured
+        assert "head_dim" in dispatch_snapshot()["attention"]["reason"]
+        assert attention_dispatch(1, head_dim=64) == "xla"  # decode pin
 
     def test_threshold_env_override(self):
         from deeplearning4j_tpu.kernels import attention_dispatch
@@ -671,9 +689,10 @@ class TestAttentionDispatch:
         finally:
             env.clear_property(SystemProperties.FLASH_MIN_SEQ)
 
-    def test_dispatch_counter(self):
+    def test_dispatch_counter(self, monkeypatch):
         from deeplearning4j_tpu.kernels import attention_dispatch
 
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         fam = registry().counter("dl4j_attn_dispatch_total",
                                  "Attention path decisions for flash=True "
                                  "configs", labels=("path",))
@@ -698,6 +717,41 @@ class TestAttentionDispatch:
         out_xla = bert.encode(params, ids, config=config, use_flash=False)
         np.testing.assert_array_equal(np.asarray(out_flash),
                                       np.asarray(out_xla))
+
+    def test_bert_default_step_on_cpu_is_the_xla_step(self):
+        """``make_train_step``'s default lets the dispatcher decide; on the
+        CPU backend that is the XLA path, bit for bit the ``use_flash=False``
+        step, decided once per trace and with the reason on record."""
+        from deeplearning4j_tpu.kernels import dispatch_snapshot
+        from deeplearning4j_tpu.models import bert
+
+        config = bert.BertConfig.tiny()
+        rng = np.random.RandomState(0)
+        ids = rng.randint(0, config.vocab_size, (2, 16))
+        batch = {"input_ids": jnp.asarray(ids, jnp.int32),
+                 "labels": jnp.asarray(np.where(rng.rand(2, 16) < 0.3, ids,
+                                                -100), jnp.int32),
+                 "attention_mask": jnp.ones((2, 16), jnp.int32)}
+        fam = registry().counter("dl4j_kernel_dispatch_total", "",
+                                 labels=("kernel", "path"))
+        xla = fam.labels(kernel="attention", path="xla")
+
+        def run(**kw):
+            params = bert.init_params(jax.random.key(0), config)
+            step = bert.make_train_step(config, None, learning_rate=1e-3,
+                                        remat=False, **kw)
+            return step(params, bert.init_opt_state(params), batch, 0)
+
+        x0 = xla.value()
+        got = run()
+        assert xla.value() == x0 + 1
+        snap = dispatch_snapshot()["attention"]
+        assert snap["path"] == "xla" and "cpu backend" in snap["reason"]
+        want = run(use_flash=False)
+        assert xla.value() == x0 + 1        # False never asks
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 # ---------------------------------------------------------------------------

@@ -127,6 +127,108 @@ class TestFlashAttention:
                                        atol=1e-4)
 
 
+def _pallas_calls(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
+
+
+class TestOneTilePath:
+    """The training cell's shape family (S=512, head_dim 64): with no tiles
+    given, a head's whole [S, S] score tile is one kernel step and the
+    backward is one fused kernel; longer sequences still stream."""
+
+    S, D = 512, 64
+
+    def _qkv(self, seed, B=1, H=2, S=None):
+        rs = np.random.RandomState(seed)
+        mk = lambda: jnp.asarray(
+            rs.randn(B, S or self.S, H, self.D).astype(np.float32))
+        return mk(), mk(), mk(), mk()
+
+    def _mask(self, kind, B):
+        if kind == "none":
+            return None
+        mask = np.ones((B, self.S), np.int32)
+        if kind == "padding":
+            mask[0, 300:] = 0
+            mask[-1, 511:] = 0
+        return jnp.asarray(mask)
+
+    @pytest.mark.parametrize("mask_kind", ["ones", "padding", "none"])
+    def test_forward_matches_reference(self, mask_kind):
+        q, k, v, _ = self._qkv(10, B=2)
+        mask = self._mask(mask_kind, 2)
+        out = flash_attention(q, k, v, mask=mask)
+        ref = _ref_attention(q, k, v, mask=mask)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("mask_kind", ["ones", "padding", "none"])
+    def test_gradients_match_reference(self, mask_kind):
+        q, k, v, ct = self._qkv(11, B=2)
+        mask = self._mask(mask_kind, 2)
+
+        def grads(fn):
+            return jax.grad(lambda q, k, v: jnp.sum(
+                fn(q, k, v, mask=mask) * ct), argnums=(0, 1, 2))(q, k, v)
+
+        assert _pallas_calls(lambda q, k, v: jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(q, k, v, mask=mask)),
+            argnums=(0, 1, 2))(q, k, v), q, k, v) == 2   # fwd + fused bwd
+        for name, g, w in zip("qkv", grads(flash_attention),
+                              grads(_ref_attention)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-4, err_msg=f"d{name}")
+
+    def test_causal_gradients_match_reference(self):
+        q, k, v, ct = self._qkv(12, S=256)
+        for name, g, w in zip("qkv", *(jax.grad(lambda q, k, v: jnp.sum(
+                fn(q, k, v, causal=True) * ct), argnums=(0, 1, 2))(q, k, v)
+                for fn in (flash_attention, _ref_attention))):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-4, err_msg=f"d{name}")
+
+    @pytest.mark.parametrize("H,D", [(1, 128), (3, 64), (4, 32)])
+    def test_head_packing(self, H, D):
+        """One head a block (D=128), an odd head count (every head in one
+        block) and four heads a block."""
+        rs = np.random.RandomState(13)
+        q, k, v, ct = (jnp.asarray(rs.randn(1, 128, H, D).astype(np.float32))
+                       for _ in range(4))
+        for g, w in zip(*(jax.grad(lambda q, k, v: jnp.sum(
+                fn(q, k, v) * ct), argnums=(0, 1, 2))(q, k, v)
+                for fn in (flash_attention, _ref_attention))):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-4)
+
+    def test_packed_layout_is_the_same_kernel(self):
+        """``head_dim=`` takes and returns [B, S, H*D], as the projections
+        produce it: the same numbers as the [B, S, H, D] call."""
+        q, k, v, _ = self._qkv(14)
+        mask = self._mask("padding", 1)
+        pack = lambda x: x.reshape(x.shape[:2] + (-1,))
+        got = flash_attention(pack(q), pack(k), pack(v), mask=mask,
+                              head_dim=self.D)
+        want = flash_attention(q, k, v, mask=mask)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(pack(want)))
+
+    def test_s1024_still_streams(self):
+        q, k, v, ct = self._qkv(15, S=1024)
+        mask = np.ones((1, 1024), np.int32)
+        mask[0, 900:] = 0
+        mask = jnp.asarray(mask)
+
+        def grads(fn):
+            return jax.grad(lambda q, k, v: jnp.sum(
+                fn(q, k, v, mask=mask) * ct), argnums=(0, 1, 2))
+
+        assert _pallas_calls(grads(flash_attention), q, k, v) == 3
+        for g, w in zip(grads(flash_attention)(q, k, v),
+                        grads(_ref_attention)(q, k, v)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-4)
+
+
 class TestNonDivisibleShapes:
     """Regression: non-tile-multiple shapes must pad, not silently corrupt."""
 

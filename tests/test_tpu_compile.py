@@ -81,27 +81,73 @@ def _compile(fn, *specs):
     return compiled, text
 
 
-FLASH_SHAPES = [(4, 12, 2048, 64), (32, 12, 128, 64), (1, 12, 8192, 64)]
+# (B, H, S, D, masked); the last is the training cell's: one-tile kernels
+FLASH_SHAPES = [(4, 12, 2048, 64, False), (32, 12, 128, 64, False),
+                (1, 12, 8192, 64, False), (8, 16, 512, 64, True)]
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 @pytest.mark.parametrize("shape", FLASH_SHAPES,
-                         ids=lambda s: "x".join(map(str, s)))
+                         ids=lambda s: "x".join(map(str, s[:4]))
+                         + ("-mask" if s[4] else ""))
 def test_flash_attention_compiles_for_v5e(shape, grad, one_chip,
                                           compiled_kernels):
-    B, H, S, D = shape
+    B, H, S, D, masked = shape
     spec = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    specs = [spec, spec, spec] + (
+        [_sds((B, S), jnp.int32, one_chip)] if masked else [])
     if grad:
-        def fn(q, k, v):
+        def fn(q, k, v, *mask):
             return jax.grad(lambda q, k, v: jnp.sum(
-                fa.flash_attention(q, k, v).astype(jnp.float32) ** 2),
+                fa.flash_attention(q, k, v, *mask).astype(jnp.float32) ** 2),
                 argnums=(0, 1, 2))(q, k, v)
     else:
         fn = fa.flash_attention
-    compiled, _ = _compile(fn, spec, spec, spec)
+    compiled, _ = _compile(fn, *specs)
     if S > 128:  # O(S) memory: no [S, S] scores among the temporaries
         assert compiled.memory_analysis().temp_size_in_bytes < \
             B * H * S * S * 2
+
+
+def test_bert_default_gradient_runs_the_kernel_on_v5e(one_chip,
+                                                      compiled_kernels,
+                                                      monkeypatch):
+    """The training cell's attention (T=512, 16 heads of 64, B=8) through
+    ``bert.mlm_loss`` as the benchmark's driver calls it — no flag: on an
+    accelerator backend the dispatcher picks the kernel, the program holds
+    the forward and the fused backward custom call of each layer, and each
+    layer's [B, H, T, T] bf16 probabilities are gone from the temporaries
+    the XLA path keeps for the backward. Widths are BERT-large's; depth and
+    vocabulary are cut so that the layers, not the head, fill the
+    temporaries."""
+    from deeplearning4j_tpu.kernels import dispatch_snapshot
+    from deeplearning4j_tpu.models import bert
+    B, T, layers = 8, 512, 2
+    config = bert.BertConfig(vocab_size=1024, hidden_size=1024,
+                             num_layers=layers, num_heads=16,
+                             intermediate_size=4096)
+    params = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: bert.init_params(jax.random.key(0), config)))
+    batch = {k: _sds((B, T), jnp.int32, one_chip)
+             for k in ("input_ids", "labels", "attention_mask")}
+
+    def temporaries(**kw):
+        return jax.jit(jax.grad(lambda p, b: bert.mlm_loss(
+            p, b, config, **kw))).lower(params, batch).compile()
+
+    xla = temporaries(use_flash=False)
+    assert "tpu_custom_call" not in xla.as_text()
+    # the dispatcher asks jax for the backend, which here is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kernel = temporaries()
+    assert dispatch_snapshot()["attention"] == {
+        "kernel": "attention", "path": "flash", "reason": None}
+    assert kernel.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 2 * layers
+    saved = config.num_heads * B * T * T * 2 * layers
+    assert (kernel.memory_analysis().temp_size_in_bytes
+            < xla.memory_analysis().temp_size_in_bytes - saved)
 
 
 PAGED_CASES = [(1, 12, 64, jnp.bfloat16), (1, 16, 128, jnp.bfloat16),
