@@ -9,10 +9,13 @@ timing chains ``--layers`` cores in one jitted program (a layer's output is
 the next layer's query, so they run in order), runs it ``--reps`` times
 back to back and divides the host clock by layers x reps: dispatch hides
 behind the device and the figure is device time per layer. B·T = 4,096 and
-H·D = 1,024 throughout, the training cell's.
+H·D = 1,024 throughout, the BERT training cell's, unless ``--tokens`` and
+``--hidden`` say otherwise (the hybrid model's attention blocks: one
+sequence of 8,192, 32 heads of 128).
 
     chiprun -- python attn_sweep.py                    # the table
     chiprun -- python attn_sweep.py --only 512x64x0    # one row, 24 layers
+    chiprun -- python attn_sweep.py --only 8192x128x1 --tokens 8192 --hidden 4096 --layers 2
     python attn_sweep.py --kernel _parent/deeplearning4j_tpu/kernels/flash_attention.py
 
 Prints one JSON line per row and writes them to
@@ -57,12 +60,13 @@ def _load_flash(path):
     return mod.flash_attention
 
 
-def time_core(core, T, D, causal, layers, reps, seed, packed=False):
+def time_core(core, T, D, causal, layers, reps, seed, packed=False,
+              tokens=TOKENS, hidden=HIDDEN):
     """(forward ms, forward+backward ms) per layer. ``packed``: q/k/v are
     [B, T, H*D] as the model's flash path keeps them, else [B, T, H, D]."""
     import jax
     import jax.numpy as jnp
-    B, H = TOKENS // T, HIDDEN // D
+    B, H = tokens // T, hidden // D
     keys = jax.random.split(jax.random.key(seed), 4)
     shape = (B, T, H * D) if packed else (B, T, H, D)
     q, k, v, ct = (jax.random.normal(kk, shape, jnp.float32)
@@ -103,6 +107,10 @@ def main(argv=None):
     ap.add_argument("--layers", type=int, default=8)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--tokens", type=int, default=TOKENS,
+                    help="B x T of every row")
+    ap.add_argument("--hidden", type=int, default=HIDDEN,
+                    help="H x D of every row")
     ap.add_argument("--out", default="chiprun_out/attn_sweep.jsonl")
     args = ap.parse_args(argv)
 
@@ -126,7 +134,7 @@ def main(argv=None):
     with open(args.out, "a") as f:
         for T, D, causal in rows:
             rec = {"T": T, "head_dim": D, "causal": bool(causal),
-                   "B": TOKENS // T, "H": HIDDEN // D,
+                   "B": args.tokens // T, "H": args.hidden // D,
                    "layers": args.layers, "kernel": args.kernel or "tree",
                    "device_kind": dev.device_kind}
             def flash_core(q, k, v, mask, causal):
@@ -139,7 +147,8 @@ def main(argv=None):
                 try:
                     fw, fb = time_core(core, T, D, bool(causal),
                                        args.layers, args.reps, args.seed,
-                                       packed and name == "flash")
+                                       packed and name == "flash",
+                                       args.tokens, args.hidden)
                     rec[f"{name}_fwd_ms"] = round(fw, 4)
                     rec[f"{name}_fwd_bwd_ms"] = round(fb, 4)
                 except Exception as e:  # an OOM at long T is a reading too

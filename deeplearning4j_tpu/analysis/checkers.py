@@ -26,12 +26,15 @@ from . import Finding, Module, PACKAGE_ROOT
 #: hand-written-kernel family on ``dl4j_kernel_dispatch_total`` —
 #: attention|paged_decode|dequant_matmul), a deploy-bounded identity
 #: (model/version/bucket/worker/name/replica — replica is a fleet
-#: member's URL, bounded by the router's configured replica set), or
+#: member's URL, bounded by the router's configured replica set;
+#: block/expert — the expert blocks of a model's layer pattern and the
+#: experts a chip holds of each, on ``dl4j_moe_expert_tokens_total``), or
 #: process identity (the build-info trio). A request-scoped value (trace id, user id, prompt)
 #: must ride on exemplars or spans, never on labels.
 REGISTERED_LABELS: Set[str] = {
-    "bucket", "cache", "engine", "good", "kernel", "kind", "mode", "model",
-    "name", "outcome", "path", "priority", "reason", "replica", "site",
+    "block", "bucket", "cache", "engine", "expert", "good", "kernel", "kind",
+    "mode", "model", "name", "outcome", "path", "priority", "reason",
+    "replica", "site",
     "slo", "state", "tier", "version", "window", "worker", "jax_version",
     "jaxlib_version", "platform",
 }

@@ -139,6 +139,17 @@ def _flash_rule(seq_len, head_dim):
          2048  128  no     |    1.983    4.081 |      0.344    1.479 | (same code)
          2048  128  yes    |    1.981    4.072 |      0.414    1.254 | (same code)
 
+    And the hybrid language model's attention blocks (``models.hybrid_lm``:
+    one sequence of 8,192, 32 query heads of 128 on 2 repeated KV heads,
+    B·T = 8,192, H·D = 4,096; ``attn_sweep.py --only 8192x128x1 --tokens
+    8192 --hidden 4096 --layers 2``, chip run of PR 27; the streaming
+    kernel is PR 26's; XLA's path does not fit the chip, 16.25 GB of scores):
+
+         8192  128  yes    |    out of memory |     12.858   39.706 | (same code)
+
+    (The streaming kernel computes every tile there, also the half that
+    the causal mask empties: a ``perf_opt`` issue's to take.)
+
     The crossover lies between 128 and 256 for both head sizes, causal or
     not, so the rule takes no ``causal``: at 128 XLA wins everything
     (a grid step's fixed cost, about 0.4 µs, is most of a [128, 128] tile's

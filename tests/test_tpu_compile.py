@@ -87,6 +87,48 @@ FLASH_SHAPES = [(4, 12, 2048, 64, False), (32, 12, 128, 64, False),
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_hybrid_causal_attention_compiles_for_v5e(grad, one_chip,
+                                                  compiled_kernels):
+    """The hybrid model's attention core as its training cell runs it:
+    one sequence of 8,192, 32 query heads of 128 (the two KV heads
+    repeated), causal, through the streaming kernels."""
+    spec = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+    core = lambda q, k, v: fa.flash_attention(q, k, v, causal=True)
+    if grad:
+        fn = jax.grad(lambda q, k, v: jnp.sum(
+            core(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    else:
+        fn = core
+    compiled, _ = _compile(fn, spec, spec, spec)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 8192 * 8192
+
+
+def test_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch,
+                                         no_persistent_cache):
+    """The expert layer's grouped product at the published widths, forward
+    and backward: a buffer of 12,288 sorted rows, 8 held experts of
+    2,688 x 1,856 (neither a multiple of the 512-wide tiles) and back,
+    both matrices held [expert, 1,856, 2,688]."""
+    from deeplearning4j_tpu.ops import moe
+    # the kernel is interpreted where jax's backend is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows = _sds((12288, 2688), jnp.bfloat16, one_chip)
+    w1 = _sds((8, 1856, 2688), jnp.bfloat16, one_chip)       # [g, F, E]
+    w2 = _sds((8, 1856, 2688), jnp.bfloat16, one_chip)
+    sizes = _sds((8,), jnp.int32, one_chip)
+
+    def loss(rows, w1, w2, sizes):
+        h = moe.grouped_matmul(rows, w1, sizes, True)
+        h = jnp.square(jax.nn.relu(h)).astype(rows.dtype)
+        return jnp.sum(moe.grouped_matmul(h, w2, sizes))
+
+    _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), rows, w1, w2, sizes)
+    # the first product forward (the gradient needs no value of the
+    # second), two for the rows and two for the weights back
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 @pytest.mark.parametrize("shape", FLASH_SHAPES,
                          ids=lambda s: "x".join(map(str, s[:4]))
                          + ("-mask" if s[4] else ""))
