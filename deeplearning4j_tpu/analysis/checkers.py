@@ -24,7 +24,9 @@ from . import Finding, Module, PACKAGE_ROOT
 #: e.g. the router dispatch set and the session-affinity pair
 #: hit|fallback on ``dl4j_fleet_affinity_total``; kernel is the
 #: hand-written-kernel family on ``dl4j_kernel_dispatch_total`` —
-#: attention|paged_decode|dequant_matmul), a deploy-bounded identity
+#: attention|paged_decode|dequant_matmul — and the streaming flash pass,
+#: fwd|dq|dkv, on ``dl4j_flash_tiles_total``, whose kind is
+#: computed|skipped), a deploy-bounded identity
 #: (model/version/bucket/worker/name/replica — replica is a fleet
 #: member's URL, bounded by the router's configured replica set;
 #: block/expert — the expert blocks of a model's layer pattern and the

@@ -139,16 +139,29 @@ def _flash_rule(seq_len, head_dim):
          2048  128  no     |    1.983    4.081 |      0.344    1.479 | (same code)
          2048  128  yes    |    1.981    4.072 |      0.414    1.254 | (same code)
 
+    Under ``causal`` the streaming kernels have since skipped the tiles
+    the mask empties, fetched nothing for them, masked only the tiles on
+    the diagonal and taken 1,024 x 1,024 tiles in all three passes
+    (``flash_attention._default_tiles``). The causal rows from T=1,024
+    again, the kernel before ("parent", PR 26's streaming code, re-read)
+    and after (``attn_sweep.py`` against ``--kernel <the parent's file>``,
+    chip runs of PR 28; the rows without ``causal`` read the same on both,
+    0.413 / 1.649 and 0.344 / 1.480 at T=1,024, D=64 and T=2,048, D=128):
+
+            T    D  causal |  XLA fwd  fwd+bwd | kernel fwd  fwd+bwd | parent
+         1024   64  yes    |    0.812    2.908 |      0.237    1.048 | 0.538  1.481
+         1024  128  yes    |    0.994    1.767 |      0.115    0.485 | 0.269  0.723
+         2048   64  yes    |    3.698    5.584 |      0.423    1.642 | 0.821  2.556
+         2048  128  yes    |    1.981    4.075 |      0.214    0.795 | 0.414  1.254
+
     And the hybrid language model's attention blocks (``models.hybrid_lm``:
     one sequence of 8,192, 32 query heads of 128 on 2 repeated KV heads,
     B·T = 8,192, H·D = 4,096; ``attn_sweep.py --only 8192x128x1 --tokens
-    8192 --hidden 4096 --layers 2``, chip run of PR 27; the streaming
-    kernel is PR 26's; XLA's path does not fit the chip, 16.25 GB of scores):
+    8192 --hidden 4096 --layers 2``; XLA's path does not fit the chip,
+    16.25 GB of scores), 36 of a head's 64 tiles live where the parent
+    computed 64 forward and 256 backward:
 
-         8192  128  yes    |    out of memory |     12.858   39.706 | (same code)
-
-    (The streaming kernel computes every tile there, also the half that
-    the causal mask empties: a ``perf_opt`` issue's to take.)
+         8192  128  yes    |    out of memory |      5.236   19.188 | 12.791 39.615
 
     The crossover lies between 128 and 256 for both head sizes, causal or
     not, so the rule takes no ``causal``: at 128 XLA wins everything
@@ -156,10 +169,12 @@ def _flash_rule(seq_len, head_dim):
     time); from 256 up the kernel wins every forward+backward row, by 1.1x
     (T=256, D=128) to 3.3x; forward alone it wins from 512 up and loses
     0.02 ms a layer at 256, which the rule accepts for training's sake.
-    One forward row is out of line, T=1024 D=64 causal (1.18 ms against
-    XLA's 0.81, though forward+backward wins): the streaming path, not
-    touched here. ``head_dim`` below 64 was not measured and stays on
-    XLA; ``None`` (a caller that does not say) is not checked."""
+    One forward row of the first table is out of line, T=1024 D=64 causal
+    (1.18 ms against XLA's 0.81): PR 28's sweep did not reproduce it (the
+    same code read 0.538) and the kernel now reads 0.237 there, so no row
+    is out of line any more.
+    ``head_dim`` below 64 was not measured and stays on XLA; ``None`` (a
+    caller that does not say) is not checked."""
     import jax
     if jax.default_backend() == "cpu":
         return "xla", "cpu backend (the kernel would run interpreted)"

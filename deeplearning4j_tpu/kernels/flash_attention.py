@@ -30,10 +30,26 @@ while f32 accumulators persist in VMEM scratch across the sequential steps:
 
 delta = rowsum(o ⊙ do) is precomputed with plain XLA (one elementwise pass).
 
+Under ``causal=True`` the streaming kernels do work only for the tiles that
+hold an unmasked (query, key) pair. A grid step whose tile lies wholly above
+the diagonal runs no matmul, no exp and no accumulator update, and its
+block index maps name the block that is resident already, so nothing is
+fetched for it; of the live tiles only those the diagonal crosses build the
+iota/compare/select of the mask. The grids keep their shape (the skipped
+steps are the trailing kv steps of fwd/dq and the leading q steps of dkv),
+``dl4j_flash_tiles_total{kernel,kind}`` counts both kinds per traced pass,
+and a call without ``causal`` traces to kernels without any of this.
+
 Sequence lengths that don't divide the tiles are zero-padded to the tile
 boundary (padded keys masked off, padded query rows sliced away). A fully
 masked row degrades to a uniform softmax — identical to what the XLA
-softmax produces for an all-−1e30 row.
+softmax produces for an all-−1e30 row — with one difference under
+``causal`` and a key mask together (left padding, a ring's diagonal shard):
+a row that sees no valid key averages v over the keys of the tiles its
+query block visits, up to the end of the key tile that holds the block's
+last diagonal element, not over all S. Such a row is garbage by contract
+either way; its lse stays at the −1e30 floor that
+``parallel/ring_attention`` reads as "no live key".
 
 Tests run interpret mode on CPU; the real chip runs compiled. Times on the
 chip against XLA: `kernels._flash_rule` (measured by `attn_sweep.py`).
@@ -64,9 +80,21 @@ def _params(n_parallel):
 # forward
 # ---------------------------------------------------------------------------
 
+def _on_live_tile(step, iq, ik, tq, tk):
+    """Run ``step(masked)`` once if the [tq, tk] tile (iq, ik) is live
+    under a causal mask — with the mask only where the diagonal crosses
+    the tile — and not at all if the mask empties it."""
+    live = ik * tk <= iq * tq + (tq - 1)     # some key <= some query
+    below = ik * tk + (tk - 1) <= iq * tq    # every key <= every query
+    pl.when(below)(lambda: step(False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(below)))(
+        lambda: step(True))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                m_sc, l_sc, acc_sc, *, scale, causal, n_k):
+                m_sc, l_sc, acc_sc, *, scale, causal, n_k, skip):
     iq, ik = pl.program_id(1), pl.program_id(2)
+    tq, tk = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(ik == 0)
     def _init():
@@ -74,27 +102,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    # dots run in the input dtype (bf16 stays on the fast MXU path) with
-    # f32 accumulation; softmax stats are always f32
-    q, k, v = q_ref[0], k_ref[0], v_ref[0]               # [TQ,D],[TK,D]
-    tq, tk = q.shape[0], k.shape[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if mask_ref is not None:
-        s = jnp.where(mask_ref[0][:, 0][None, :] != 0, s, _NEG_INF)
-    if causal:
-        q_pos = iq * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-        k_pos = ik * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    def _step(masked):
+        # dots run in the input dtype (bf16 stays on the fast MXU path)
+        # with f32 accumulation; softmax stats are always f32
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]           # [TQ,D],[TK,D]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if mask_ref is not None:
+            s = jnp.where(mask_ref[0][:, 0][None, :] != 0, s, _NEG_INF)
+        if masked:
+            q_pos = iq * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+            k_pos = ik * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
 
-    m_prev = m_sc[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    m_sc[...] = m_new[:, None]
-    l_sc[...] = l_sc[...] * alpha[:, None] + jnp.sum(p, axis=-1)[:, None]
-    acc_sc[...] = acc_sc[...] * alpha[:, None] + \
-        jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_prev = m_sc[:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[:, None])
+        alpha = jnp.exp(m_prev - m_new)
+        m_sc[...] = m_new[:, None]
+        l_sc[...] = l_sc[...] * alpha[:, None] + jnp.sum(p, axis=-1)[:, None]
+        acc_sc[...] = acc_sc[...] * alpha[:, None] + \
+            jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    if skip:
+        _on_live_tile(_step, iq, ik, tq, tk)
+    else:
+        _step(causal)
 
     @pl.when(ik == n_k - 1)
     def _done():
@@ -110,23 +143,68 @@ def _fwd_kernel_nomask(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_sc, l_sc, acc_sc, **kw)
 
 
-def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k):
+def _kv_block(iq, ik, tile_q, tile_k, skip):
+    """The k/v block that step (iq, ik) of fwd/dq reads. A step the causal
+    mask empties names the last live block of its row instead of its own:
+    that block is resident already, so no DMA is issued for it."""
+    if not skip:
+        return ik
+    return jnp.minimum(ik, jax.lax.div(iq * tile_q + (tile_q - 1), tile_k))
+
+
+def _q_block(ik, iq, tile_q, tile_k, skip):
+    """The q-side block that step (ik, iq) of dkv reads: the first live
+    one of its column while the steps above the diagonal pass."""
+    if not skip:
+        return iq
+    return jnp.maximum(iq, jax.lax.div(ik * tile_k, tile_q))
+
+
+def _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip):
+    """Tick ``dl4j_flash_tiles_total{kernel,kind}`` with one head's grid:
+    the tiles a pass computes and the ones it skips, static per traced
+    call."""
+    live = sum(min(n_k, (iq * tile_q + tile_q - 1) // tile_k + 1)
+               for iq in range(n_q)) if skip else n_q * n_k
+    try:
+        from ..common.environment import environment
+        tiles = environment().metrics().counter(
+            "dl4j_flash_tiles_total",
+            "Tiles of one head's grid that a streaming flash-attention "
+            "pass computes, and that it skips because a causal mask "
+            "empties them, added up at trace time",
+            labels=("kernel", "kind"))
+        tiles.labels(kernel=kernel, kind="computed").inc(live)
+        tiles.labels(kernel=kernel, kind="skipped").inc(n_q * n_k - live)
+    except Exception:
+        pass  # observability must never break a trace
+
+
+def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
+               skip_empty=True):
+    """``skip_empty=False`` (tests only) computes and masks every tile of
+    a causal call, as the kernels did before they skipped."""
     BH, S, D = q.shape
     n_q, n_k = S // tile_q, S // tile_k
+    skip = causal and skip_empty
+    _count_tiles("fwd", n_q, n_k, tile_q, tile_k, skip)
     grid = (BH, n_q, n_k)
+
+    def kv(bh, iq, ik):
+        return bh, _kv_block(iq, ik, tile_q, tile_k, skip), 0
+
     in_specs = [
         pl.BlockSpec((1, tile_q, D), lambda bh, iq, ik: (bh, iq, 0)),
-        pl.BlockSpec((1, tile_k, D), lambda bh, iq, ik: (bh, ik, 0)),
-        pl.BlockSpec((1, tile_k, D), lambda bh, iq, ik: (bh, ik, 0)),
+        pl.BlockSpec((1, tile_k, D), kv),
+        pl.BlockSpec((1, tile_k, D), kv),
     ]
     args = [q, k, v]
     if mask is not None:
-        in_specs.append(pl.BlockSpec((1, tile_k, 1),
-                                     lambda bh, iq, ik: (bh, ik, 0)))
+        in_specs.append(pl.BlockSpec((1, tile_k, 1), kv))
         args.append(mask)
     kern = functools.partial(
         _fwd_kernel if mask is not None else _fwd_kernel_nomask,
-        scale=scale, causal=causal, n_k=n_k)
+        scale=scale, causal=causal, n_k=n_k, skip=skip)
     return pl.pallas_call(
         kern,
         grid=grid,
@@ -168,21 +246,27 @@ def _p_tile(q, k, mask_row, lse, iq, ik, scale, causal):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
-               dq_ref, dq_sc, *, scale, causal, n_k):
+               dq_ref, dq_sc, *, scale, causal, n_k, skip):
     iq, ik = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
-    mrow = mask_ref[0] if mask_ref is not None else None
-    p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, causal)
-    dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [TQ, TK]
-    ds = p * (dp - delta_ref[0])
-    dq_sc[...] += jnp.dot(ds.astype(k.dtype), k,
-                          preferred_element_type=jnp.float32) * scale
+    def _step(masked):
+        q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
+        mrow = mask_ref[0] if mask_ref is not None else None
+        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked)
+        dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)  # [TQ, TK]
+        ds = p * (dp - delta_ref[0])
+        dq_sc[...] += jnp.dot(ds.astype(k.dtype), k,
+                              preferred_element_type=jnp.float32) * scale
+
+    if skip:
+        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1])
+    else:
+        _step(causal)
 
     @pl.when(ik == n_k - 1)
     def _done():
@@ -196,7 +280,7 @@ def _dq_kernel_nomask(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
-                dk_ref, dv_ref, dk_sc, dv_sc, *, scale, causal, n_q):
+                dk_ref, dv_ref, dk_sc, dv_sc, *, scale, causal, n_q, skip):
     ik, iq = pl.program_id(1), pl.program_id(2)
 
     @pl.when(iq == 0)
@@ -204,18 +288,24 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
-    mrow = mask_ref[0] if mask_ref is not None else None
-    p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, causal)
-    dv_sc[...] += jax.lax.dot_general(
-        p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0])
-    dk_sc[...] += jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+    def _step(masked):
+        q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
+        mrow = mask_ref[0] if mask_ref is not None else None
+        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked)
+        dv_sc[...] += jax.lax.dot_general(
+            p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0])
+        dk_sc[...] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+
+    if skip:
+        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1])
+    else:
+        _step(causal)
 
     @pl.when(iq == n_q - 1)
     def _done():
@@ -230,15 +320,17 @@ def _dkv_kernel_nomask(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
-               lse_cot=None):
+               lse_cot=None, skip_empty=True):
     BH, S, D = q.shape
-    # the bwd kernels hold three [TQ, TK] f32 tiles live (p, dp, ds); cap
-    # tiles at 512 so long-seq fwd tiles (2048) don't blow the 16MB VMEM
-    if tile_q > 512 and S % 512 == 0:
-        tile_q = 512
-    if tile_k > 512 and S % 512 == 0:
-        tile_k = 512
+    cap = _bwd_tile_cap(causal)
+    if tile_q > cap and S % cap == 0:
+        tile_q = cap
+    if tile_k > cap and S % cap == 0:
+        tile_k = cap
     n_q, n_k = S // tile_q, S // tile_k
+    skip = causal and skip_empty
+    for kernel in ("dq", "dkv"):
+        _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip)
     delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1,
                     keepdims=True)  # [BH, S, 1]
     if lse_cot is not None:
@@ -247,33 +339,32 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
         # kernel changes (ds = p * (dp - delta'))
         delta = delta - lse_cot.astype(jnp.float32)
 
-    def qspec(f):
-        return pl.BlockSpec((1, tile_q, D), f)
+    def qspec(f, width=D):
+        return pl.BlockSpec((1, tile_q, width), f)
 
-    def kspec(f):
-        return pl.BlockSpec((1, tile_k, D), f)
+    def kspec(f, width=D):
+        return pl.BlockSpec((1, tile_k, width), f)
 
     # dq: stream kv blocks for each q block
-    in_specs = [
-        qspec(lambda bh, iq, ik: (bh, iq, 0)),          # q
-        kspec(lambda bh, iq, ik: (bh, ik, 0)),          # k
-        kspec(lambda bh, iq, ik: (bh, ik, 0)),          # v
-        qspec(lambda bh, iq, ik: (bh, iq, 0)),          # g
-        pl.BlockSpec((1, tile_q, 1), lambda bh, iq, ik: (bh, iq, 0)),  # lse
-        pl.BlockSpec((1, tile_q, 1), lambda bh, iq, ik: (bh, iq, 0)),  # delta
-    ]
+    def own_q(bh, iq, ik):
+        return bh, iq, 0
+
+    def kv(bh, iq, ik):
+        return bh, _kv_block(iq, ik, tile_q, tile_k, skip), 0
+
+    in_specs = [qspec(own_q), kspec(kv), kspec(kv), qspec(own_q),   # q k v g
+                qspec(own_q, 1), qspec(own_q, 1)]                   # lse delta
     args = [q, k, v, g, lse, delta]
     if mask is not None:
-        in_specs.append(pl.BlockSpec((1, tile_k, 1),
-                                     lambda bh, iq, ik: (bh, ik, 0)))
+        in_specs.append(kspec(kv, 1))
         args.append(mask)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel if mask is not None else
                           _dq_kernel_nomask,
-                          scale=scale, causal=causal, n_k=n_k),
+                          scale=scale, causal=causal, n_k=n_k, skip=skip),
         grid=(BH, n_q, n_k),
         in_specs=in_specs,
-        out_specs=qspec(lambda bh, iq, ik: (bh, iq, 0)),
+        out_specs=qspec(own_q),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((tile_q, D), jnp.float32)],
         compiler_params=_params(2),
@@ -281,27 +372,25 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
     )(*args)
 
     # dk/dv: stream q blocks for each kv block
-    in_specs = [
-        qspec(lambda bh, ik, iq: (bh, iq, 0)),          # q
-        kspec(lambda bh, ik, iq: (bh, ik, 0)),          # k
-        kspec(lambda bh, ik, iq: (bh, ik, 0)),          # v
-        qspec(lambda bh, ik, iq: (bh, iq, 0)),          # g
-        pl.BlockSpec((1, tile_q, 1), lambda bh, ik, iq: (bh, iq, 0)),  # lse
-        pl.BlockSpec((1, tile_q, 1), lambda bh, ik, iq: (bh, iq, 0)),  # delta
-    ]
+    def own_kv(bh, ik, iq):
+        return bh, ik, 0
+
+    def qs(bh, ik, iq):
+        return bh, _q_block(ik, iq, tile_q, tile_k, skip), 0
+
+    in_specs = [qspec(qs), kspec(own_kv), kspec(own_kv), qspec(qs),
+                qspec(qs, 1), qspec(qs, 1)]
     args = [q, k, v, g, lse, delta]
     if mask is not None:
-        in_specs.append(pl.BlockSpec((1, tile_k, 1),
-                                     lambda bh, ik, iq: (bh, ik, 0)))
+        in_specs.append(kspec(own_kv, 1))
         args.append(mask)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel if mask is not None else
                           _dkv_kernel_nomask,
-                          scale=scale, causal=causal, n_q=n_q),
+                          scale=scale, causal=causal, n_q=n_q, skip=skip),
         grid=(BH, n_k, n_q),
         in_specs=in_specs,
-        out_specs=[kspec(lambda bh, ik, iq: (bh, ik, 0)),
-                   kspec(lambda bh, ik, iq: (bh, ik, 0))],
+        out_specs=[kspec(own_kv), kspec(own_kv)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((tile_k, D), jnp.float32),
@@ -579,7 +668,33 @@ def _fit_tile(want, s_pad):
     return t
 
 
-def _prep(q, k, v, mask, scale, tile_q, tile_k):
+def _default_tiles(causal):
+    """The forward's (tile_q, tile_k) where the caller names none, before
+    ``_fit_tile``; ``_flash_bwd`` caps both at ``_bwd_tile_cap``.
+
+    Not causal: 2,048 x 512. Causal: 1,024 x 1,024 — a key step costs the
+    forward its [TQ, 1] statistics and the accumulator's rescale whatever
+    the tile's width, and a skipped step still costs its grid step, so
+    few, wide tiles win though they leave more of the diagonal's waste:
+    at S=8,192, D=128 a layer's forward took 8.37 ms at 2,048 x 512 (40 of
+    64 tiles live), 8.82 at 512 x 512 (136 of 256), 18.08 at 1,024 x 256,
+    5.91 at 512 x 1,024 and 5.24 at 1,024 x 1,024 (36 of 64); S=1,024 and
+    2,048 at D=64 and 128 order the same way (``attn_sweep.py``, chip runs
+    of PR 28; 2,048-wide tiles need a raised VMEM limit and were no
+    faster). Larger D and longer S than those were not measured."""
+    return (1024, 1024) if causal else (2048, 512)
+
+
+def _bwd_tile_cap(causal):
+    """The backward kernels hold three [TQ, TK] f32 tiles live (p, dp,
+    ds), so they cut the forward's 2,048-row tiles to 512; a causal call
+    keeps 1,024 x 1,024, which fits the default VMEM limit and leaves 28
+    skipped steps a head at S=8,192 where 512 x 512 leaves 120 (13.95 ms a
+    layer against 16.98; faster at S=1,024 and 2,048 too)."""
+    return 1024 if causal else 512
+
+
+def _prep(q, k, v, mask, scale, tile_q, tile_k, causal):
     """Resolve tiles, zero-pad S to the tile boundary, flatten to the
     kernels' [B*H, S_pad, D] layout. Returns (qf, kf, vf, mf, scale,
     tile_q, tile_k, S, S_pad, B, H, D)."""
@@ -591,8 +706,9 @@ def _prep(q, k, v, mask, scale, tile_q, tile_k):
             tile_q = tile_k = S
         else:
             S_pad = -(-S // 128) * 128
-            tile_q = _fit_tile(tile_q or 2048, S_pad)
-            tile_k = _fit_tile(tile_k or 512, S_pad)
+            want_q, want_k = _default_tiles(causal)
+            tile_q = _fit_tile(tile_q or want_q, S_pad)
+            tile_k = _fit_tile(tile_k or want_k, S_pad)
     else:
         tile_q = min(tile_q, max(S, 1))
         tile_k = min(tile_k, max(S, 1))
@@ -630,8 +746,10 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     With no tiles given, a padded length up to 512 takes the one-tile
     kernels (a head's whole [S, S] score tile in VMEM, one fused backward
     kernel, blocks straight from the packed layout) and longer sequences
-    stream [tile_q, tile_k] tiles (2048 x 512, shrunk to divisors of the
-    padded length). Times on the chip: ``kernels._flash_rule``."""
+    stream [tile_q, tile_k] tiles (2048 x 512, or 1024 x 1024 under
+    ``causal``, where the tiles the mask empties are skipped; shrunk to
+    divisors of the padded length). Times on the chip:
+    ``kernels._flash_rule``."""
     D = head_dim if head_dim is not None else q.shape[-1]
     scale = scale if scale is not None else D ** -0.5
     one_tile = (tile_q is None and tile_k is None
@@ -644,7 +762,7 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
         return _flash_one_tile(q, k, v, mask, causal, scale, D).reshape(shape)
     q, k, v = (x.reshape(shape[:2] + (-1, D)) for x in (q, k, v))
     (qf, kf, vf, mf, scale, tile_q, tile_k,
-     S, S_pad, B, H, D) = _prep(q, k, v, mask, scale, tile_q, tile_k)
+     S, S_pad, B, H, D) = _prep(q, k, v, mask, scale, tile_q, tile_k, causal)
     if mf is not None:
         out = _flash_masked(qf, kf, vf, mf, scale, causal, tile_q, tile_k)
     else:
@@ -665,7 +783,7 @@ def flash_attention_with_lse(q, k, v, mask=None, causal: bool = False,
     backward kernels' delta term).
     """
     (qf, kf, vf, mf, scale, tile_q, tile_k,
-     S, S_pad, B, H, D) = _prep(q, k, v, mask, scale, tile_q, tile_k)
+     S, S_pad, B, H, D) = _prep(q, k, v, mask, scale, tile_q, tile_k, causal)
     out, lse = _flash_lse_masked(qf, kf, vf, mf, scale, causal,
                                  tile_q, tile_k)
     out = jnp.moveaxis(out.reshape(B, H, S_pad, D), 1, 2)

@@ -3,24 +3,35 @@
 VERDICT round-1 item 9: kernels/ was an empty placeholder. These tests run
 the exact kernel bodies through the Pallas interpreter.
 """
+import functools
+import importlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.kernels import flash_attention
+from deeplearning4j_tpu.kernels import (flash_attention,
+                                        flash_attention_with_lse)
+
+# the package re-exports the function under its module's name
+fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
 
 
-def _ref_attention(q, k, v, mask=None, causal=False):
-    D = q.shape[-1]
+def _ref_attention_with_lse(q, k, v, mask=None, causal=False):
+    S, D = q.shape[1], q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (D ** -0.5)
     if mask is not None:
         s = jnp.where(mask[:, None, None, :] != 0, s, -1e30)
     if causal:
-        S = q.shape[1]
         s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+def _ref_attention(q, k, v, mask=None, causal=False):
+    return _ref_attention_with_lse(q, k, v, mask, causal)[0]
 
 
 class TestFlashAttention:
@@ -125,6 +136,184 @@ class TestFlashAttention:
         for g, w in zip(got, want):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        atol=1e-4)
+
+
+class TestCausalTileSkipping:
+    """Under ``causal=True`` the streaming kernels compute only the tiles
+    that hold an unmasked (query, key) pair, fetch nothing for the others
+    and mask only the tiles the diagonal crosses: the same numbers as plain
+    masked softmax, and bitwise those of the same kernels computing and
+    masking every tile (``skip_empty=False`` of the private wrappers)."""
+
+    @staticmethod
+    def _inputs(S, masked, seed=20):
+        rs = np.random.RandomState(seed)
+        q, k, v, ct = (jnp.asarray(rs.randn(2, S, 1, 16).astype(np.float32))
+                       for _ in range(4))
+        ct_lse = jnp.asarray(rs.randn(2, 1, S).astype(np.float32))
+        mask = None
+        if masked:                       # right padding: key 0 stays valid
+            mask = np.ones((2, S), np.int32)
+            mask[0, S - 27:] = 0
+            mask[1, S - 1:] = 0
+            mask = jnp.asarray(mask)
+        return q, k, v, ct, ct_lse, mask
+
+    @pytest.mark.parametrize("with_lse", [False, True], ids=["out", "lse"])
+    @pytest.mark.parametrize("masked", [False, True],
+                             ids=["nomask", "keymask"])
+    @pytest.mark.parametrize("S", [128, 96])
+    @pytest.mark.parametrize("tiles", [(64, 16), (16, 64), (32, 32),
+                                       (128, 32)],
+                             ids=lambda t: f"{t[0]}x{t[1]}")
+    def test_matches_plain_softmax_and_the_unskipped_kernels(
+            self, tiles, S, masked, with_lse):
+        tile_q, tile_k = tiles
+        q, k, v, ct, ct_lse, mask = self._inputs(S, masked)
+
+        def kernel(q, k, v):
+            if with_lse:
+                return flash_attention_with_lse(
+                    q, k, v, mask=mask, causal=True, tile_q=tile_q,
+                    tile_k=tile_k)
+            return flash_attention(q, k, v, mask=mask, causal=True,
+                                   tile_q=tile_q, tile_k=tile_k), None
+
+        def plain(q, k, v):
+            out, lse = _ref_attention_with_lse(q, k, v, mask, causal=True)
+            return out, lse if with_lse else None
+
+        def run(fn):
+            def loss(q, k, v):
+                out, lse = fn(q, k, v)
+                total = jnp.sum(out * ct)
+                if lse is not None:
+                    total = total + jnp.sum(lse * ct_lse)
+                return total, (out, lse)
+            (_, fwd), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            return fwd, grads
+
+        ((out, lse), got), ((ref, ref_lse), want) = run(kernel), run(plain)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+        if with_lse:
+            np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                                       atol=2e-5)
+        for name, g, w in zip("qkv", got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-4, err_msg=f"d{name}")
+
+        # bitwise: the same tiles with nothing skipped, every tile masked
+        (qf, kf, vf, mf, scale, tq, tk, *_) = fa._prep(
+            q, k, v, mask, None, tile_q, tile_k, True)
+        gf = jnp.asarray(np.random.RandomState(21).randn(*qf.shape)
+                         .astype(np.float32))
+        cot = (jnp.asarray(np.random.RandomState(22).randn(*qf.shape[:2], 1)
+                           .astype(np.float32)) if with_lse else None)
+
+        @functools.partial(jax.jit, static_argnums=0)
+        def passes(skip_empty):     # one program: forward, dq, dkv
+            o, l = fa._flash_fwd(qf, kf, vf, mf, scale, True, tq, tk,
+                                 skip_empty=skip_empty)
+            return (o, l) + tuple(fa._flash_bwd(
+                qf, kf, vf, mf, o, l, gf, scale, True, tq, tk,
+                lse_cot=cot, skip_empty=skip_empty))
+
+        got, want = passes(True), passes(False)
+        for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+
+    def test_row_without_a_valid_key_averages_the_visited_tiles(self):
+        """The edge the module's docstring states: under a causal mask AND
+        a key mask (left padding) a row may see no valid key. Its softmax
+        is uniform over the keys of the tiles its query block visits, not
+        over all S; its lse sits at the -1e30 floor that
+        ``parallel/ring_attention`` reads as "no live key"; every other
+        row is exact."""
+        S, pad, t = 128, 40, 32
+        q, k, v, *_ = self._inputs(S, False, seed=23)
+        mask = np.ones((2, S), np.int32)
+        mask[:, :pad] = 0
+        mask = jnp.asarray(mask)
+        out, lse = flash_attention_with_lse(q, k, v, mask=mask, causal=True,
+                                            tile_q=t, tile_k=t)
+        ref, ref_lse = _ref_attention_with_lse(q, k, v, mask, causal=True)
+        np.testing.assert_allclose(np.asarray(out[:, pad:]),
+                                   np.asarray(ref[:, pad:]), atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse[:, :, pad:]),
+                                   np.asarray(ref_lse[:, :, pad:]), atol=2e-5)
+        assert np.all(np.asarray(lse[:, :, :pad]) < -1e29)
+        for row in range(pad):
+            visited = (row // t + 1) * t
+            np.testing.assert_allclose(
+                np.asarray(out[:, row]),
+                np.asarray(jnp.mean(v[:, :visited], axis=1)), atol=2e-5,
+                err_msg=f"row {row}")
+        # with every tile computed the same row averages all S keys
+        (qf, kf, vf, mf, scale, tq, tk, *_) = fa._prep(q, k, v, mask, None,
+                                                       t, t, True)
+        whole, _ = fa._flash_fwd(qf, kf, vf, mf, scale, True, tq, tk,
+                                 skip_empty=False)
+        np.testing.assert_allclose(
+            np.asarray(whole.reshape(2, 1, S, 16)[:, :, 0]),
+            np.asarray(jnp.mean(v, axis=1)), atol=2e-5)
+
+
+class TestFlashTilesCounter:
+    """``dl4j_flash_tiles_total{kernel,kind}``: one head's grid, ticked
+    once per traced pass."""
+
+    @staticmethod
+    def _ticks(trace):
+        """(what ``trace()`` returns, the counter's growth over it)."""
+        from deeplearning4j_tpu.common.metrics import registry
+
+        def read():
+            fam = registry().get("dl4j_flash_tiles_total")
+            return {(kernel, kind): fam.labels(kernel=kernel,
+                                               kind=kind).value()
+                    if fam else 0.0
+                    for kernel in ("fwd", "dq", "dkv")
+                    for kind in ("computed", "skipped")}
+        before = read()
+        out = trace()
+        return out, {key: n - before[key] for key, n in read().items()}
+
+    def _trace_grad(self, causal, shape, dtype=jnp.float32, **tiles):
+        x = jax.ShapeDtypeStruct(shape, dtype)
+        grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=causal, **tiles).astype(jnp.float32)),
+            argnums=(0, 1, 2))
+        return self._ticks(lambda: str(jax.make_jaxpr(grad)(x, x, x)))
+
+    @staticmethod
+    def _each_pass(computed, skipped):
+        return {(kernel, kind): n for kernel in ("fwd", "dq", "dkv")
+                for kind, n in (("computed", computed), ("skipped", skipped))}
+
+    def test_causal_trace_counts_live_and_skipped_tiles(self):
+        text, ticks = self._trace_grad(True, (1, 1024, 3, 64),
+                                       tile_q=512, tile_k=512)
+        assert ticks == self._each_pass(3, 1)
+        # init, done and the two bodies (below / on the diagonal) a kernel
+        assert len(re.findall(r"\bcond\[", text)) == 3 * 4
+
+    def test_non_causal_trace_skips_nothing_and_keeps_its_kernels(self):
+        text, ticks = self._trace_grad(False, (1, 1024, 3, 64),
+                                       tile_q=512, tile_k=512)
+        assert ticks == self._each_pass(4, 0)
+        # the kernels as they were: init and done are the only branches
+        assert text.count("pallas_call") == 3
+        assert len(re.findall(r"\bcond\[", text)) == 3 * 2
+
+    def test_hybrid_shape_counts(self):
+        """T=8,192, head_dim 128, no tiles given: a head of the hybrid
+        cell's attention blocks ticks 1,024 x 1,024 tiles in all three
+        passes, 36 on or below the diagonal and 28 above it."""
+        _, ticks = self._trace_grad(True, (1, 8192, 1, 128), jnp.bfloat16)
+        assert ticks == self._each_pass(36, 28)
 
 
 def _pallas_calls(fn, *args):

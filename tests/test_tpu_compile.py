@@ -86,21 +86,33 @@ FLASH_SHAPES = [(4, 12, 2048, 64, False), (32, 12, 128, 64, False),
                 (1, 12, 8192, 64, False), (8, 16, 512, 64, True)]
 
 
+# (B, H, S, D): the hybrid cell's attention blocks, and what
+# ``models.causal_lm`` would hand the kernel at a 2,048-token context
+CAUSAL_SHAPES = [(1, 32, 8192, 128), (1, 12, 2048, 64)]
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-def test_hybrid_causal_attention_compiles_for_v5e(grad, one_chip,
+@pytest.mark.parametrize("shape", CAUSAL_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_hybrid_causal_attention_compiles_for_v5e(shape, grad, one_chip,
                                                   compiled_kernels):
     """The hybrid model's attention core as its training cell runs it:
     one sequence of 8,192, 32 query heads of 128 (the two KV heads
-    repeated), causal, through the streaming kernels."""
-    spec = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+    repeated), causal, through the streaming kernels with the tiles they
+    choose for a causal call — inside the VMEM limit they ask for, the
+    skipped tiles' branches and clamped block indices accepted by Mosaic."""
+    B, H, S, D = shape
+    spec = _sds((B, S, H, D), jnp.bfloat16, one_chip)
     core = lambda q, k, v: fa.flash_attention(q, k, v, causal=True)
     if grad:
         fn = jax.grad(lambda q, k, v: jnp.sum(
             core(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
     else:
         fn = core
-    compiled, _ = _compile(fn, spec, spec, spec)
-    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 8192 * 8192
+    compiled, text = _compile(fn, spec, spec, spec)
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (3 if grad else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < B * H * S * S
 
 
 def test_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch,
