@@ -1,8 +1,8 @@
 """Crossover sweep of the attention core on the chip: XLA against the flash
 kernel, the measurement behind ``kernels.attention_dispatch``'s rule.
 
-One layer's attention core, as ``models.bert._attention`` and
-``models.causal_lm._causal_block`` run it: q/k/v ``[B, T, H, D]`` bf16, an
+One layer's attention core, the two paths of ``kernels.attention`` as the
+models call it: q/k/v ``[B, T, H, D]`` bf16 (``[B, T, H*D]`` for the kernel), an
 all-ones ``[B, T]`` int32 key mask when not causal (the training batch
 carries one), forward alone and forward+backward under ``jax.grad``. Each
 timing chains ``--layers`` cores in one jitted program (a layer's output is
@@ -35,19 +35,10 @@ SEQS, HEAD_DIMS = (128, 256, 512, 1024, 2048), (64, 128)
 
 
 def xla_core(q, k, v, mask, causal):
-    """The XLA path of ``bert._attention`` / ``causal_lm._causal_block``."""
-    import jax
-    import jax.numpy as jnp
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
-    neg = jnp.finfo(jnp.float32).min
-    if mask is not None:
-        s = jnp.where(mask[:, None, None, :].astype(bool), s, neg)
-    if causal:
-        T = q.shape[1]
-        s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, None], s, neg)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    """The XLA core the models run: ``kernels.attention`` on "xla"."""
+    from deeplearning4j_tpu.kernels import attention
+    return attention(q, k, v, path="xla", head_dim=q.shape[-1], mask=mask,
+                     causal=causal)
 
 
 def _load_flash(path):

@@ -49,7 +49,6 @@ class EnvironmentVars:
     DL4J_TPU_CACHE_TIER = "DL4J_TPU_CACHE_TIER"
     DL4J_TPU_XLA_CACHE = "DL4J_TPU_XLA_CACHE"
     DL4J_TPU_WARMUP_THREADS = "DL4J_TPU_WARMUP_THREADS"
-    DL4J_TPU_FLASH_MIN_SEQ = "DL4J_TPU_FLASH_MIN_SEQ"
     DL4J_TPU_PAGED_KERNEL = "DL4J_TPU_PAGED_KERNEL"
     DL4J_TPU_FUSED_DEQUANT = "DL4J_TPU_FUSED_DEQUANT"
     DL4J_TPU_INFERENCE_BUCKETING = "DL4J_TPU_INFERENCE_BUCKETING"
@@ -123,7 +122,6 @@ class SystemProperties:
     CACHE_TIER = "cache_tier"
     XLA_CACHE = "xla_cache"
     WARMUP_THREADS = "warmup_threads"
-    FLASH_MIN_SEQ = "flash_min_seq"
     PAGED_KERNEL = "paged_kernel"
     FUSED_DEQUANT = "fused_dequant"
     INFERENCE_BUCKETING = "inference_bucketing"
@@ -198,7 +196,6 @@ _ENV_FOR_PROP = {
     SystemProperties.CACHE_TIER: EnvironmentVars.DL4J_TPU_CACHE_TIER,
     SystemProperties.XLA_CACHE: EnvironmentVars.DL4J_TPU_XLA_CACHE,
     SystemProperties.WARMUP_THREADS: EnvironmentVars.DL4J_TPU_WARMUP_THREADS,
-    SystemProperties.FLASH_MIN_SEQ: EnvironmentVars.DL4J_TPU_FLASH_MIN_SEQ,
     SystemProperties.PAGED_KERNEL: EnvironmentVars.DL4J_TPU_PAGED_KERNEL,
     SystemProperties.FUSED_DEQUANT: EnvironmentVars.DL4J_TPU_FUSED_DEQUANT,
     SystemProperties.INFERENCE_BUCKETING:
@@ -525,25 +522,7 @@ class Environment:
         except (TypeError, ValueError):
             return 0
 
-    # -- attention auto-dispatch (kernels/__init__.py) ---------------------
-    def flash_min_seq(self) -> Optional[int]:
-        """Override of ``kernels.attention_dispatch``'s measured rule:
-        unset (None, the default) the rule decides from sequence length,
-        head_dim and backend; set, attention takes the Pallas flash kernel
-        from this sequence length up on any backend (interpreted on the
-        CPU — how the tests steer a model onto the kernel)."""
-        v = self.property(SystemProperties.FLASH_MIN_SEQ)
-        try:
-            return int(v)
-        except (TypeError, ValueError):
-            return None
-
-    def set_flash_min_seq(self, n: Optional[int]):
-        """Programmatic override; None restores the rule."""
-        if n is None:
-            return self.clear_property(SystemProperties.FLASH_MIN_SEQ)
-        return self.set_property(SystemProperties.FLASH_MIN_SEQ, int(n))
-
+    # -- kernel policies (kernels/__init__.py) ---------------------------
     def paged_kernel(self) -> str:
         """Policy for the Pallas paged-flash decode kernel
         (``DL4J_TPU_PAGED_KERNEL``): "auto" (default) runs it on

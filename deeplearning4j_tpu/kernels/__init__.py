@@ -9,10 +9,15 @@ cover the known gaps for the flagship workloads:
 - `flash_attention`: attention with the scores kept in VMEM, forward and a
   full Pallas backward — no [S,S] HBM materialization in either direction.
   Up to S=512 a head is one tile and the backward one fused kernel; longer
-  sequences stream tiles with an online softmax. `attention_dispatch`
-  chooses between it and XLA by a measured rule (`_flash_rule` has the
-  sweep: on a v5e the kernel wins training from S=256 up, 2.9x at BERT's
-  S=512 with heads of 64).
+  sequences stream tiles with an online softmax.
+- `attention_dispatch` / `attention`: the one full-sequence attention core
+  the models call. `attention_dispatch` chooses between the kernel and
+  XLA by a measured rule (`_flash_rule` has the sweep: on a v5e the kernel
+  wins training from S=256 up, 2.9x at BERT's S=512 with heads of 64),
+  once per traced model; `attention(q, k, v, path=...)` runs the chosen
+  core over packed ``[B,T,H·D]`` or ``[B,T,H,D]`` operands with
+  ``Hkv <= H`` KV heads: the kernel with the KV heads repeated here, or
+  the one XLA core (f32 scores, key and/or causal mask, f32 softmax).
 - `paged_flash_decode`: the decode-side counterpart — walks the paged KV
   block tables in-kernel (scalar-prefetch) with online-softmax
   accumulation, replacing the `jnp.take` gather read of
@@ -30,12 +35,15 @@ exact kernel code path hardware-free.
 """
 from typing import Dict, Optional
 
+import jax
+import jax.numpy as jnp
+
 from .flash_attention import flash_attention, flash_attention_with_lse
 from .paged_flash_decode import paged_flash_decode
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
-           "paged_flash_decode", "attention_dispatch", "kernel_dispatch",
-           "dispatch_snapshot"]
+           "paged_flash_decode", "attention", "attention_dispatch",
+           "kernel_dispatch", "dispatch_snapshot"]
 
 _dispatch_logged = False
 
@@ -88,7 +96,6 @@ def _paged_path(env, head_dim, block_size):
     if mode == "on":
         return "paged_flash", ""
     # auto: hardware only, and only when the pool layout tiles natively
-    import jax
     if jax.default_backend() == "cpu":
         return "paged", "cpu backend (auto gates the kernel to accelerators)"
     from .paged_flash_decode import tileable
@@ -175,7 +182,6 @@ def _flash_rule(seq_len, head_dim):
     is out of line any more.
     ``head_dim`` below 64 was not measured and stays on XLA; ``None`` (a
     caller that does not say) is not checked."""
-    import jax
     if jax.default_backend() == "cpu":
         return "xla", "cpu backend (the kernel would run interpreted)"
     if seq_len < _FLASH_MIN_SEQ:
@@ -210,21 +216,21 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
     chip says wins, from ``seq_len``, ``head_dim`` and the backend (the
     causal rows of the sweep agree with the others), with the reason
     recorded when XLA is taken. On the CPU backend that is always XLA
-    (the kernel would run in the Pallas interpreter). ``DL4J_TPU_FLASH_MIN_SEQ`` is unset by default; set
-    (or ``Environment.set_flash_min_seq``), it replaces the rule with a
-    plain threshold on any backend — the hook the CPU tests use to steer
-    a model onto the interpreted kernel. Evaluated at trace time (shapes
-    are static under jit), so the ``dl4j_attn_dispatch_total{path=}`` and
+    (the kernel would run in the Pallas interpreter); a test that wants a
+    model on the interpreted kernel substitutes ``_flash_rule`` (the
+    ``flash_everywhere`` fixture of ``tests/conftest.py``). Evaluated at
+    trace time (shapes are static under jit), so the
+    ``dl4j_attn_dispatch_total{path=}`` and
     ``dl4j_kernel_dispatch_total{kernel,path}`` counters tick once per
-    traced model (``models.bert`` asks once for all its layers), and the
-    debug log fires once per process.
+    traced model (a model asks once for all its layers and hands the
+    answer to ``attention`` as ``path``), and the debug log fires once
+    per process.
 
     Decode-shaped queries (seq_len < 2 — the KV-cached single-token step
     of ``runtime.generation.DecodeEngine``) take the XLA path
-    UNCONDITIONALLY on the non-paged path, whatever
-    ``DL4J_TPU_FLASH_MIN_SEQ`` says: a 1-row query can never amortize the
-    Pallas kernel's blocking, and the decode executable must stay stable
-    across env retunes."""
+    UNCONDITIONALLY on the non-paged path, whatever the rule says: a
+    1-row query can never amortize the Pallas kernel's blocking, and the
+    decode executable must stay stable."""
     global _dispatch_logged
     from ..common.environment import environment
 
@@ -234,11 +240,6 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
         path, reason = _paged_path(env, head_dim, block_size)
     elif int(seq_len) < 2:
         path, reason = "xla", "seq_len<2 decode pin"
-    elif env.flash_min_seq() is not None:
-        if int(seq_len) >= env.flash_min_seq():
-            path = "flash"
-        else:
-            path, reason = "xla", "seq_len<DL4J_TPU_FLASH_MIN_SEQ"
     else:
         path, reason = _flash_rule(int(seq_len), head_dim)
     try:
@@ -256,3 +257,39 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
             "attention at seq_len=%d takes the XLA path: %s",
             seq_len, reason)
     return path
+
+
+def attention(q, k, v, *, path: str, head_dim: int, mask=None,
+              causal: bool = False):
+    """The full-sequence attention core on ``path`` ("flash" | "xla", what
+    ``attention_dispatch`` answered for the traced model).
+
+    ``q`` is ``[B, T, H*D]`` or ``[B, T, H, D]``; ``k`` and ``v`` likewise
+    with ``Hkv <= H`` heads (``H % Hkv == 0``: query head ``i`` reads KV
+    head ``i // (H // Hkv)``). ``mask``: optional ``[B, T]`` key validity
+    (1 = attend). Returns the context in ``q``'s layout and dtype.
+
+    "flash" repeats the KV heads for the query heads that share them (the
+    kernel's entry takes as many KV heads as query heads) and calls
+    ``flash_attention`` in the layout given. "xla" is the plain core:
+    f32 scores, masks, f32 softmax, weighted sum."""
+    D = head_dim
+    B, T = q.shape[:2]
+    H, Hkv = q.size // (B * T * D), k.size // (B * T * D)
+    R = H // Hkv
+    if path == "flash":
+        if R > 1:
+            k, v = (jnp.repeat(x.reshape(B, T, Hkv, D), R, axis=2)
+                    for x in (k, v))
+        return flash_attention(q, k, v, mask=mask, causal=causal,
+                               head_dim=D)
+    big_neg = jnp.finfo(jnp.float32).min
+    k, v = (x.reshape(B, T, Hkv, D) for x in (k, v))
+    s = jnp.einsum("btgrd,bsgd->bgrts", q.reshape(B, T, Hkv, R, D), k,
+                   preferred_element_type=jnp.float32) * D ** -0.5
+    if mask is not None:
+        s = jnp.where(mask[:, None, None, None, :].astype(bool), s, big_neg)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, big_neg)
+    prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrts,bsgd->btgrd", prob, v).reshape(q.shape)

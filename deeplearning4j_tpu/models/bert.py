@@ -27,6 +27,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.tracing import model_scope
+from ..kernels import attention, attention_dispatch
 from ..parallel.mesh import DATA, FSDP, PIPE, SEQ, TENSOR
 from ..quant.transforms import (dequant_matmul, dequantize, take_rows,
                                 tied_logits)
@@ -160,91 +161,49 @@ def _ln(x, g, b, eps):
 
 # -- forward ------------------------------------------------------------
 
-def _resolve_flash(use_flash: Optional[bool], mesh: Optional[Mesh],
-                   seq_parallel: bool, seq_len: int, head_dim: int) -> bool:
-    """What ``_attention``'s ``flash`` is for a model traced at this shape,
-    decided once per trace (one tick of the dispatch counters).
-
-    use_flash None (the default) lets ``kernels.attention_dispatch``
-    choose between the Pallas flash kernel and XLA from the shape and the
-    backend — except under a GSPMD ``mesh``, where a ``pallas_call`` has
-    no partitioning rule and XLA keeps the core, and in the
-    sequence-parallel ring, which stays on its XLA blocks. True asks for
-    the kernel wherever the dispatcher grants it (and for the ring's
-    per-block kernel); False is the plain XLA reference."""
-    if seq_parallel and mesh is not None:
-        return bool(use_flash)
-    if use_flash is None:
-        use_flash = mesh is None
-    if not use_flash:
-        return False
-    from ..kernels import attention_dispatch
-    return attention_dispatch(seq_len, head_dim=head_dim) == "flash"
-
-
 def _attention(layer_params, h, attention_mask, config: BertConfig,
                mesh: Optional[Mesh], seq_parallel: bool,
-               flash: bool = False, tp_axis: Optional[str] = None):
+               path: str, tp_axis: Optional[str] = None):
     """Multi-head attention. tp_axis: when running INSIDE a shard_map with
     head-sharded weights (the pipeline's Megatron-TP stages), names the
     mesh axis for the explicit f/g collectives (tp_copy before QKV,
     tp_reduce after the output projection); None means replicated weights
     or GSPMD-annotated sharding (XLA inserts the collectives).
-    flash: ``_resolve_flash``'s decision — the Pallas kernel for the core
-    (per K/V block inside the sequence-parallel ring)."""
+    path: the core ``kernels.attention`` runs, as ``encode`` asked (in
+    the sequence-parallel ring: whether each K/V block takes the Pallas
+    kernel)."""
     a = layer_params["attn"]
-    ring = seq_parallel and mesh is not None
     with model_scope("attn"):
         if tp_axis is not None:
             from ..parallel.pipeline import tp_copy
             h_in = tp_copy(h, tp_axis)
         else:
             h_in = h
-        if flash and not ring:
-            # heads stay packed along the last axis, [B, T, H*D], from the
-            # projections through the kernel to the output projection: the
-            # kernel takes its blocks straight from that layout, and XLA
-            # has no [.., H, 64] minor dimensions to lay out around it
-            def proj(w, b):
-                w = dequantize(w, h_in.dtype)
-                return jnp.einsum("bte,ef->btf", h_in,
-                                  w.reshape(w.shape[0], -1)) + b.reshape(-1)
-            q, k, v = (proj(a["w" + n], a["b" + n]) for n in "qkv")
-            with model_scope("attn_core"):
-                from ..kernels import flash_attention
-                ctx = flash_attention(q, k, v, mask=attention_mask,
-                                      head_dim=config.head_dim)
-            wo = dequantize(a["wo"], ctx.dtype)
-            out = jnp.einsum("bqf,fe->bqe", ctx,
-                             wo.reshape(-1, wo.shape[-1]))
-        else:
-            q = jnp.einsum("bte,ehd->bthd", h_in,
-                           dequantize(a["wq"], h_in.dtype)) + a["bq"]
-            k = jnp.einsum("bte,ehd->bthd", h_in,
-                           dequantize(a["wk"], h_in.dtype)) + a["bk"]
-            v = jnp.einsum("bte,ehd->bthd", h_in,
-                           dequantize(a["wv"], h_in.dtype)) + a["bv"]
-            with model_scope("attn_core"):
-                if ring:
-                    # flash composes with SP: the Pallas kernel computes
-                    # each K/V block inside the ring (VERDICT r4 #4 /
-                    # SURVEY §5)
-                    ctx = ring_attention(q, k, v, mesh, mask=attention_mask,
-                                         causal=False, use_flash=flash)
-                else:
-                    scale = config.head_dim ** -0.5
-                    logits = jnp.einsum(
-                        "bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-                    if attention_mask is not None:
-                        big_neg = jnp.finfo(jnp.float32).min
-                        logits = jnp.where(
-                            attention_mask[:, None, None, :].astype(bool),
-                            logits, big_neg)
-                    probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
-                    ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-            out = jnp.einsum("bqhd,hde->bqe", ctx,
-                             dequantize(a["wo"], ctx.dtype))
+
+        # heads stay packed along the last axis, [B, T, H*D], from the
+        # projections through the core to the output projection: the
+        # kernel takes its blocks straight from that layout, and XLA has
+        # no [.., H, 64] minor dimensions to lay out around it
+        def proj(w, b):
+            w = dequantize(w, h_in.dtype)
+            return jnp.einsum("bte,ef->btf", h_in,
+                              w.reshape(w.shape[0], -1)) + b.reshape(-1)
+        q, k, v = (proj(a["w" + n], a["b" + n]) for n in "qkv")
+        with model_scope("attn_core"):
+            if seq_parallel and mesh is not None:
+                # flash composes with SP: the Pallas kernel computes each
+                # K/V block inside the ring (VERDICT r4 #4 / SURVEY §5)
+                heads = q.shape[:2] + (-1, config.head_dim)
+                ctx = ring_attention(
+                    q.reshape(heads), k.reshape(heads), v.reshape(heads),
+                    mesh, mask=attention_mask, causal=False,
+                    use_flash=path == "flash").reshape(q.shape)
+            else:
+                ctx = attention(q, k, v, path=path,
+                                head_dim=config.head_dim,
+                                mask=attention_mask)
+        wo = dequantize(a["wo"], ctx.dtype)
+        out = jnp.einsum("bqf,fe->bqe", ctx, wo.reshape(-1, wo.shape[-1]))
         if tp_axis is not None:
             from ..parallel.pipeline import tp_reduce
             out = tp_reduce(out, tp_axis)
@@ -255,7 +214,13 @@ def encode(params, input_ids, token_type_ids=None, attention_mask=None, *,
            config: BertConfig, mesh: Optional[Mesh] = None,
            seq_parallel: bool = False,
            use_flash: Optional[bool] = None):
-    """Token ids [B, T] → contextual encodings [B, T, E]."""
+    """Token ids [B, T] → contextual encodings [B, T, E].
+
+    use_flash None (the default) lets ``kernels.attention_dispatch``
+    choose between the Pallas flash kernel and XLA from the shape and the
+    backend, once per trace; True asks for the kernel wherever the
+    dispatcher grants it; False is the plain XLA reference and never
+    asks."""
     c = config
     e = params["embeddings"]
     B, T = input_ids.shape
@@ -272,10 +237,17 @@ def encode(params, input_ids, token_type_ids=None, attention_mask=None, *,
             h, NamedSharding(mesh, P((DATA, FSDP), SEQ if seq_parallel else None,
                                      None)))
 
-    flash = _resolve_flash(use_flash, mesh, seq_parallel, T, c.head_dim)
+    if seq_parallel and mesh is not None:
+        # the ring is not a core: per K/V block, the kernel only on request
+        path = "flash" if use_flash else "xla"
+    elif use_flash is False or (use_flash is None and mesh is not None):
+        # under GSPMD a ``pallas_call`` has no partitioning rule
+        path = "xla"
+    else:
+        path = attention_dispatch(T, head_dim=c.head_dim)
     for layer in params["layers"]:
         attn_out = _attention(layer, h, attention_mask, c, mesh, seq_parallel,
-                              flash)
+                              path)
         h = _ln(h + attn_out, layer["ln1_g"], layer["ln1_b"], c.layer_norm_eps)
         mlp = layer["mlp"]
         with model_scope("mlp"):
@@ -385,8 +357,7 @@ def make_train_step(config: BertConfig, mesh: Optional[Mesh] = None,
     With a mesh: params placed per param_specs (TP/FSDP), batch sharded over
     (data, fsdp), sequence over seq when seq_parallel — XLA emits all ICI
     collectives (the entire reference PS stack, §2.5).
-    use_flash: see ``_resolve_flash`` (None: ``attention_dispatch``
-    decides).
+    use_flash: see ``encode`` (None: ``attention_dispatch`` decides).
     """
     loss_fn = _make_loss_fn(config, mesh, seq_parallel, remat, use_flash)
 
@@ -582,9 +553,9 @@ def make_pipeline_train_step(config: BertConfig, mesh: Mesh,
         # tp > 1 the attn/mlp leaves are the local TENSOR shard and the
         # math is Megatron column->row parallel per block (explicit f/g
         # collectives via tp_copy/tp_reduce)
-        flash = _resolve_flash(None, None, False, h.shape[1], c.head_dim)
+        path = attention_dispatch(h.shape[1], head_dim=c.head_dim)
         for layer in stage_layers:
-            attn_out = _attention(layer, h, None, c, None, False, flash,
+            attn_out = _attention(layer, h, None, c, None, False, path,
                                   tp_axis=tp_axis)
             h = _ln(h + attn_out, layer["ln1_g"], layer["ln1_b"],
                     c.layer_norm_eps)

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..common.tracing import model_scope
+from ..kernels import attention, attention_dispatch, paged_flash_decode
 from ..quant.transforms import (dequant_matmul, dequantize, take_rows,
                                 tied_logits)
 from .bert import _ln
@@ -167,13 +168,11 @@ def _lm_logits(params, h):
 _BIG_NEG = jnp.finfo(jnp.float32).min
 
 
-def _causal_block(layer, h, c: CausalLMConfig, use_flash: bool = False):
-    """Full-sequence causal attention block. Returns (h, (k, v)) with
-    k/v [B, T, H, Dh] so prefill can bulk-write them into the cache."""
-    from ..kernels import attention_dispatch
-
+def _causal_block(layer, h, c: CausalLMConfig, path: str = "xla"):
+    """Full-sequence causal attention block on the core ``path`` names
+    (``kernels.attention``). Returns (h, (k, v)) with k/v [B, T, H, Dh] so
+    prefill can bulk-write them into the cache."""
     a = layer["attn"]
-    B, T = h.shape[0], h.shape[1]
     with model_scope("attn"):
         q = jnp.einsum("bte,ehd->bthd", h,
                        dequantize(a["wq"], h.dtype)) + a["bq"]
@@ -182,18 +181,8 @@ def _causal_block(layer, h, c: CausalLMConfig, use_flash: bool = False):
         v = jnp.einsum("bte,ehd->bthd", h,
                        dequantize(a["wv"], h.dtype)) + a["bv"]
         with model_scope("attn_core"):
-            if use_flash and attention_dispatch(
-                    T, head_dim=q.shape[-1]) == "flash":
-                from ..kernels import flash_attention
-                ctx = flash_attention(q, k, v, causal=True)
-            else:
-                scale = (q.shape[-1]) ** -0.5
-                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                                    preferred_element_type=jnp.float32) * scale
-                causal = jnp.tril(jnp.ones((T, T), bool))
-                logits = jnp.where(causal[None, None], logits, _BIG_NEG)
-                probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
-                ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+            ctx = attention(q, k, v, path=path, head_dim=c.head_dim,
+                            causal=True)
         out = jnp.einsum("bqhd,hde->bqe", ctx,
                          dequantize(a["wo"], h.dtype)) + a["bo"]
     return _mlp_ln(layer, h, out, c), (k, v)
@@ -209,8 +198,10 @@ def forward(params, input_ids, config: CausalLMConfig,
     ``prefill``/``decode`` pair exists to avoid."""
     B, T = input_ids.shape
     h = _embed(params, input_ids, jnp.arange(T)[None, :], config)
+    path = (attention_dispatch(T, head_dim=config.head_dim) if use_flash
+            else "xla")
     for layer in params["layers"]:
-        h, _ = _causal_block(layer, h, config, use_flash)
+        h, _ = _causal_block(layer, h, config, path)
     return _lm_logits(params, h)
 
 
@@ -252,11 +243,9 @@ def decode(params, cache, tokens, lengths, config: CausalLMConfig):
     full-prefix recompute. Returns ``(cache, logits[S, V])``.
 
     The query is seq-len-1, so ``kernels.attention_dispatch`` pins this
-    step to the XLA attention path regardless of DL4J_TPU_FLASH_MIN_SEQ
-    (a 1-row query can never amortize the Pallas kernel's blocking).
+    step to the XLA attention path whatever its rule says (a 1-row query
+    can never amortize the Pallas kernel's blocking).
     """
-    from ..kernels import attention_dispatch
-
     c = config
     S = tokens.shape[0]
     C = cache["k"].shape[2]
@@ -347,8 +336,6 @@ def paged_prefill(params, cache, input_ids, tables, lengths,
     positions. Returns ``(cache, logits[B, V])`` with each row's logits
     taken at tail index ``lengths[b]-start_pos[b]-1``: the distribution
     of the row's first generated token."""
-    from ..kernels import attention_dispatch
-
     c = config
     B, T = input_ids.shape
     MB = tables.shape[1]
@@ -426,8 +413,6 @@ def paged_decode(params, cache, tables, tokens, lengths,
     ``Q=k+1`` speculative verify always share a path. Both compute the
     same masked softmax over the same rows — greedy decode is
     token-identical across them (regression-gated)."""
-    from ..kernels import attention_dispatch, paged_flash_decode
-
     c = config
     S, Q = tokens.shape
     MB = tables.shape[1]

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..common.tracing import model_scope
+from ..kernels import attention, attention_dispatch
 from ..ops import moe
 from ..ops.ssm_scan import ssd_chunked_scan
 from . import _optim
@@ -278,34 +279,18 @@ def _experts(p, u, c: HybridLMConfig):
     return out.reshape(B, T, E), counts
 
 
-def _attention(p, u, c: HybridLMConfig, flash: bool):
-    B, T, _ = u.shape
-    H, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    R = H // Hkv
+def _attention(p, u, c: HybridLMConfig, path: str):
     with model_scope("attn"):
         q = jnp.einsum("bte,ef->btf", u, p["wq"])
-        k = jnp.einsum("bte,ef->btf", u, p["wk"]).reshape(B, T, Hkv, D)
-        v = jnp.einsum("bte,ef->btf", u, p["wv"]).reshape(B, T, Hkv, D)
+        k = jnp.einsum("bte,ef->btf", u, p["wk"])
+        v = jnp.einsum("bte,ef->btf", u, p["wv"])
         with model_scope("attn_core"):
-            if flash:
-                # the kernel takes as many K/V heads as query heads: each
-                # KV head is repeated for the query heads that share it
-                from ..kernels import flash_attention
-                ctx = flash_attention(
-                    q.reshape(B, T, H, D), jnp.repeat(k, R, axis=2),
-                    jnp.repeat(v, R, axis=2), causal=True)
-            else:
-                s = jnp.einsum("btgrd,bsgd->bgrts",
-                               q.reshape(B, T, Hkv, R, D), k,
-                               preferred_element_type=jnp.float32) * D ** -0.5
-                s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s,
-                              jnp.finfo(jnp.float32).min)
-                prob = jax.nn.softmax(s, axis=-1).astype(u.dtype)
-                ctx = jnp.einsum("bgrts,bsgd->btgrd", prob, v)
-        return jnp.einsum("btf,fe->bte", ctx.reshape(B, T, H * D), p["wo"])
+            ctx = attention(q, k, v, path=path, head_dim=c.head_dim,
+                            causal=True)
+        return jnp.einsum("btf,fe->bte", ctx, p["wo"])
 
 
-def _block(p, h, kind: str, c: HybridLMConfig, flash: bool):
+def _block(p, h, kind: str, c: HybridLMConfig, path: str):
     """One pre-norm residual block: (h, expert_tokens or None)."""
     u = _rms_norm(h, p["norm"], c.norm_eps)
     counts = None
@@ -314,17 +299,11 @@ def _block(p, h, kind: str, c: HybridLMConfig, flash: bool):
     elif kind == EXPERTS:
         out, counts = _experts(p, u, c)
     else:
-        out = _attention(p, u, c, flash)
+        out = _attention(p, u, c, path)
     return h + out, counts
 
 
 # -- forward, loss, step ------------------------------------------------------
-
-def _resolve_flash(seq_len: int, head_dim: int) -> bool:
-    """Asked once per trace, as `models.bert` asks."""
-    from ..kernels import attention_dispatch
-    return attention_dispatch(seq_len, head_dim=head_dim) == "flash"
-
 
 def hidden_states(params, input_ids, config: HybridLMConfig,
                   remat: bool = False):
@@ -336,11 +315,12 @@ def hidden_states(params, input_ids, config: HybridLMConfig,
                          f"the pattern {c.pattern!r}")
     with model_scope("embed"):
         h = jnp.take(params["embed"], input_ids, axis=0).astype(c.dtype)
-    flash = (ATTENTION in c.pattern
-             and _resolve_flash(input_ids.shape[1], c.head_dim))
+    # asked once per trace, and only by a model that has attention blocks
+    path = (attention_dispatch(input_ids.shape[1], head_dim=c.head_dim)
+            if ATTENTION in c.pattern else None)
     counts = []
     for p, kind in zip(params["blocks"], c.pattern):
-        block = lambda p, h, kind=kind: _block(p, h, kind, c, flash)
+        block = lambda p, h, kind=kind: _block(p, h, kind, c, path)
         if remat:
             block = jax.checkpoint(block)
         h, n = block(p, h)

@@ -114,7 +114,6 @@ def env_fingerprint() -> str:
         "grad_accum": env.training_grad_accum(),
         "zero1": env.training_zero1(),
         "bucketing": env.inference_bucketing(),
-        "flash_min_seq": env.flash_min_seq(),
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
     }, sort_keys=True)
 

@@ -60,6 +60,17 @@ def _lock_order_check(request):
         f"(DL4J_TPU_LOCK_CHECK): {found}")
 
 
+@pytest.fixture
+def flash_everywhere(monkeypatch):
+    """``kernels.attention_dispatch``'s rule answers "flash" at every
+    shape, on the CPU backend too (where the kernel runs interpreted): how
+    a test steers a model onto the kernel. The decode pin and the paged
+    branch sit in front of the rule and must not follow it."""
+    from deeplearning4j_tpu import kernels
+    monkeypatch.setattr(kernels, "_flash_rule",
+                        lambda seq_len, head_dim: ("flash", ""))
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _compile_cache_tmpdir(tmp_path_factory):
     """Point the AOT executable cache (DL4J_TPU_CACHE_DIR) at a per-run

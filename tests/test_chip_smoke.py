@@ -75,16 +75,12 @@ class TestPhases:
             chip_smoke.phase_store(_mlp, jnp.asarray(_X),
                                    str(tmp_path / "store"))
 
-    def test_kernels(self):
+    def test_kernels(self, flash_everywhere):
         env = environment()
-        env.set_flash_min_seq(128)  # steer the dispatcher to the kernel
-        try:
-            rec = chip_smoke.phase_kernels(
-                interpret=True, flash_shape=(1, 2, 128, 32),
-                lm_config=_lm_config(), slots=2, max_ctx=64, bucket=16,
-                decode_steps=3, mm_shape=(8, 128, 256), dtype=jnp.float32)
-        finally:
-            env.set_flash_min_seq(None)
+        rec = chip_smoke.phase_kernels(
+            interpret=True, flash_shape=(1, 2, 128, 32),
+            lm_config=_lm_config(), slots=2, max_ctx=64, bucket=16,
+            decode_steps=3, mm_shape=(8, 128, 256), dtype=jnp.float32)
         assert rec["flash_attention"]["dispatch"]["path"] == "flash"
         assert rec["paged_decode"]["dispatch"]["path"] == "paged_flash"
         assert rec["dequant_matmul"]["dispatch"]["path"] == "fused"

@@ -660,7 +660,6 @@ class TestAttentionDispatch:
         from deeplearning4j_tpu.kernels import (attention_dispatch,
                                                 dispatch_snapshot)
 
-        assert environment().flash_min_seq() is None
         for seq in (128, 512, 1024, 4096):
             assert attention_dispatch(seq, head_dim=64) == "xla"
         assert "cpu backend" in dispatch_snapshot()["attention"]["reason"]
@@ -678,16 +677,17 @@ class TestAttentionDispatch:
         assert "head_dim" in dispatch_snapshot()["attention"]["reason"]
         assert attention_dispatch(1, head_dim=64) == "xla"  # decode pin
 
-    def test_threshold_env_override(self):
+    def test_threshold_env_override(self, monkeypatch):
+        """The rule is the seam: a substituted one decides, on any
+        backend (what the environment's threshold was used as)."""
+        from deeplearning4j_tpu import kernels
         from deeplearning4j_tpu.kernels import attention_dispatch
 
-        env = environment()
-        env.set_flash_min_seq(64)
-        try:
-            assert attention_dispatch(128) == "flash"
-            assert attention_dispatch(32) == "xla"
-        finally:
-            env.clear_property(SystemProperties.FLASH_MIN_SEQ)
+        monkeypatch.setattr(
+            kernels, "_flash_rule", lambda seq_len, head_dim:
+            ("flash", "") if seq_len >= 64 else ("xla", "seq_len<64"))
+        assert attention_dispatch(128) == "flash"
+        assert attention_dispatch(32) == "xla"
 
     def test_dispatch_counter(self, monkeypatch):
         from deeplearning4j_tpu.kernels import attention_dispatch

@@ -368,21 +368,16 @@ class TestCompileCounting:
 # ---------------------------------------------------------------------------
 
 class TestDecodeAttentionDispatch:
-    def test_seq_len_one_always_xla(self):
+    def test_seq_len_one_always_xla(self, flash_everywhere):
         from deeplearning4j_tpu.kernels import attention_dispatch
-        env = environment()
-        prev = env.flash_min_seq()
-        try:
-            # even a threshold that would send EVERYTHING to flash must
-            # not move the decode shape off the XLA path
-            env.set_flash_min_seq(1)
-            assert attention_dispatch(1) == "xla"
-            assert attention_dispatch(0) == "xla"
-            assert attention_dispatch(2) == "flash"
-        finally:
-            env.set_flash_min_seq(prev)
+        # even a rule that would send EVERYTHING to flash must not move
+        # the decode shape off the XLA path
+        assert attention_dispatch(1) == "xla"
+        assert attention_dispatch(0) == "xla"
+        assert attention_dispatch(2) == "flash"
 
-    def test_decode_shape_ticks_dispatch_counter(self, model):
+    def test_decode_shape_ticks_dispatch_counter(self, model,
+                                                 flash_everywhere):
         """Tracing the decode step records dl4j_attn_dispatch_total with
         path=xla (once per compiled executable)."""
         from deeplearning4j_tpu.kernels import attention_dispatch
@@ -392,20 +387,15 @@ class TestDecodeAttentionDispatch:
             "Attention path decisions for flash=True configs",
             labels=("path",))
         before = fam.labels(path="xla").value()
-        env = environment()
-        prev = env.flash_min_seq()
-        try:
-            env.set_flash_min_seq(1)  # adversarial: flash for everything
-            assert attention_dispatch(1) == "xla"
-        finally:
-            env.set_flash_min_seq(prev)
+        # adversarial rule: flash for everything
+        assert attention_dispatch(1) == "xla"
         assert fam.labels(path="xla").value() == before + 1
 
-    def test_paged_path_ticks_paged_label(self):
+    def test_paged_path_ticks_paged_label(self, flash_everywhere):
         """The block-table gather attention of paged_decode records its
         own path=paged label — paged and slab decode executables stay
         distinguishable in telemetry — and never takes the flash kernel,
-        whatever the query length or DL4J_TPU_FLASH_MIN_SEQ."""
+        whatever the query length or the rule."""
         from deeplearning4j_tpu.kernels import attention_dispatch
 
         fam = registry().counter(
@@ -413,14 +403,8 @@ class TestDecodeAttentionDispatch:
             "Attention path decisions for flash=True configs",
             labels=("path",))
         before = fam.labels(path="paged").value()
-        env = environment()
-        prev = env.flash_min_seq()
-        try:
-            env.set_flash_min_seq(1)
-            assert attention_dispatch(1, paged=True) == "paged"
-            assert attention_dispatch(512, paged=True) == "paged"
-        finally:
-            env.set_flash_min_seq(prev)
+        assert attention_dispatch(1, paged=True) == "paged"
+        assert attention_dispatch(512, paged=True) == "paged"
         assert fam.labels(path="paged").value() == before + 2
 
 

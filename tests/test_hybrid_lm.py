@@ -387,7 +387,7 @@ def test_grouped_query_attention_shares_kv_heads(inputs):
     c = program_config()
     p = as_f32(ref.nest(flat))["blocks"][3]
     u = jax.random.normal(jax.random.key(8), (1, 19, D["E"]))
-    got = hybrid_lm._attention(p, u, c, flash=False)
+    got = hybrid_lm._attention(p, u, c, path="xla")
     H, Hkv, Dh = D["heads"], D["kv_heads"], D["D"]
     q = (u @ p["wq"]).reshape(1, 19, H, Dh)
     k = (u @ p["wk"]).reshape(1, 19, Hkv, Dh)
@@ -408,8 +408,8 @@ def test_flash_path_matches_the_xla_core(inputs):
     c = program_config()
     p = as_f32(ref.nest(flat))["blocks"][3]
     u = jax.random.normal(jax.random.key(8), (1, 24, D["E"]))
-    np.testing.assert_allclose(hybrid_lm._attention(p, u, c, flash=True),
-                               hybrid_lm._attention(p, u, c, flash=False),
+    np.testing.assert_allclose(hybrid_lm._attention(p, u, c, path="flash"),
+                               hybrid_lm._attention(p, u, c, path="xla"),
                                rtol=2e-3, atol=2e-4)
 
 

@@ -88,10 +88,19 @@ class TestBucketLadder:
         assert pad_batch(x, 3) is x
 
 
+#: a batch-5 and a batch-8 compile of the same matmul + softmax are two
+#: programs: XLA's CPU backend may vectorise and order their sums apart,
+#: so the engine owes the padded rows' values, not their bits. Read on
+#: this CPU: 2 ulp (multilayer, n=3), 1 (graph, n=3), 8 (samediff's
+#: softmax); held to twice the largest. A row that padding reached would
+#: be wrong in its leading digits.
+_PADDED_MAX_ULP = 16
+
+
 class TestPaddedEquality:
     """Padded-bucket outputs must match exact-shape outputs after slicing."""
 
-    def test_multilayer_bitwise(self, _clean_env):
+    def test_multilayer_matches_exact(self, _clean_env):
         net = _mlp()
         for n in (1, 3, 5, 7, 11):
             x = _x(n)
@@ -100,9 +109,10 @@ class TestPaddedEquality:
             _clean_env.set_inference_bucketing(True)
             bucketed = np.asarray(net.output(x).jax())
             assert bucketed.shape == exact.shape
-            np.testing.assert_array_equal(bucketed, exact)
+            np.testing.assert_array_max_ulp(bucketed, exact,
+                                            maxulp=_PADDED_MAX_ULP)
 
-    def test_graph_bitwise(self, _clean_env):
+    def test_graph_matches_exact(self, _clean_env):
         net = _graph()
         for n in (3, 5, 9):
             x = _x(n)
@@ -110,9 +120,11 @@ class TestPaddedEquality:
             exact = np.asarray(net.output(x)[0].jax())
             _clean_env.set_inference_bucketing(True)
             bucketed = np.asarray(net.output(x)[0].jax())
-            np.testing.assert_array_equal(bucketed, exact)
+            assert bucketed.shape == exact.shape
+            np.testing.assert_array_max_ulp(bucketed, exact,
+                                            maxulp=_PADDED_MAX_ULP)
 
-    def test_samediff_bitwise(self, _clean_env):
+    def test_samediff_matches_exact(self, _clean_env):
         sd = SameDiff.create()
         x = sd.placeholder("x", (None, 4))
         w = sd.var("w", np.random.RandomState(0).randn(4, 3)
@@ -124,7 +136,8 @@ class TestPaddedEquality:
         _clean_env.set_inference_bucketing(True)
         bucketed = np.asarray(sd.output({"x": data}, [out])[out.name].jax())
         assert bucketed.shape == exact.shape
-        np.testing.assert_array_equal(bucketed, exact)
+        np.testing.assert_array_max_ulp(bucketed, exact,
+                                        maxulp=_PADDED_MAX_ULP)
 
     def test_samediff_batch_reduction_falls_back_exact(self, _clean_env):
         # a scalar (batch-reduced) output would change value under padding;

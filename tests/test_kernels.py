@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.kernels import (flash_attention,
+from deeplearning4j_tpu.kernels import (attention, flash_attention,
                                         flash_attention_with_lse)
 
 # the package re-exports the function under its module's name
@@ -318,6 +318,60 @@ class TestFlashTilesCounter:
 
 def _pallas_calls(fn, *args):
     return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
+
+
+class TestAttentionEntry:
+    """``kernels.attention``: the one full-sequence core the models call,
+    on either path, in either layout, with as many or fewer KV heads."""
+
+    B, T, H, D = 2, 48, 4, 16
+
+    @pytest.mark.parametrize("masking", ["none", "mask", "causal",
+                                         "mask+causal"])
+    @pytest.mark.parametrize("kv_heads", [4, 2, 1])
+    @pytest.mark.parametrize("layout", ["packed", "heads"])
+    @pytest.mark.parametrize("path", ["xla", "flash"])
+    def test_core(self, path, layout, kv_heads, masking):
+        """Against plain softmax over explicitly repeated KV heads, forward
+        and under ``jax.grad``; both layouts give the same context; "xla"
+        never reaches ``pallas_call``, "flash" always does."""
+        B, T, H, D = self.B, self.T, self.H, self.D
+        R = H // kv_heads
+        ks = jax.random.split(jax.random.key(kv_heads), 4)
+        q = jax.random.normal(ks[0], (B, T, H, D))
+        k = jax.random.normal(ks[1], (B, T, kv_heads, D))
+        v = jax.random.normal(ks[2], (B, T, kv_heads, D))
+        ct = jax.random.normal(ks[3], q.shape)
+        mask = None
+        if "mask" in masking:   # key 0 stays valid: no causal row is empty
+            mask = jnp.asarray(np.arange(T)[None, :] % (np.arange(B)[:, None]
+                                                        + 3) != 1, jnp.int32)
+        causal = "causal" in masking
+
+        def entry(q, k, v, layout=layout):
+            if layout == "packed":
+                q, k, v = (x.reshape(B, T, -1) for x in (q, k, v))
+            ctx = attention(q, k, v, path=path, head_dim=D, mask=mask,
+                            causal=causal)
+            assert ctx.shape == q.shape and ctx.dtype == q.dtype
+            return ctx.reshape(B, T, H, D)
+
+        def plain(q, k, v):
+            return _ref_attention(q, jnp.repeat(k, R, 2), jnp.repeat(v, R, 2),
+                                  mask, causal)
+
+        got, grads = jax.value_and_grad(
+            lambda *a: jnp.sum(entry(*a) * ct), argnums=(0, 1, 2))(q, k, v)
+        want, wgrads = jax.value_and_grad(
+            lambda *a: jnp.sum(plain(*a) * ct), argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+        for g, w in zip(grads, wgrads):
+            assert g.shape == w.shape
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+        other = "heads" if layout == "packed" else "packed"
+        np.testing.assert_array_equal(entry(q, k, v),
+                                      entry(q, k, v, layout=other))
+        assert (_pallas_calls(entry, q, k, v) > 0) == (path == "flash")
 
 
 class TestOneTilePath:

@@ -422,19 +422,15 @@ class TestSpecVerifyPathStability:
         assert len(paths) == 1
         assert paths <= {"paged", "paged_flash"}
 
-    def test_flash_min_seq_never_moves_paged(self):
-        """An adversarial DL4J_TPU_FLASH_MIN_SEQ=1 (flash for everything)
-        must not pull the paged read onto the slab flash kernel."""
-        env = environment()
-        prev = env.flash_min_seq()
+    def test_flash_rule_never_moves_paged(self, flash_everywhere):
+        """An adversarial rule (flash for everything) must not pull the
+        paged read onto the slab flash kernel."""
         restore = _paged_mode("off")
         try:
-            env.set_flash_min_seq(1)
             assert attention_dispatch(512, paged=True, head_dim=128,
                                       block_size=8) == "paged"
         finally:
             restore()
-            env.set_flash_min_seq(prev)
 
     def test_prefill_view_stays_on_gather(self):
         """Callers with no pool tile info (paged_prefill's contiguous
