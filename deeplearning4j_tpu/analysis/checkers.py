@@ -26,7 +26,9 @@ from . import Finding, Module, PACKAGE_ROOT
 #: hand-written-kernel family on ``dl4j_kernel_dispatch_total`` —
 #: attention|paged_decode|dequant_matmul — and the streaming flash pass,
 #: fwd|dq|dkv, on ``dl4j_flash_tiles_total``, whose kind is
-#: computed|skipped), a deploy-bounded identity
+#: computed|skipped; op is the fused Mamba-2 operation, conv_silu|gate_norm,
+#: on ``dl4j_ssm_fused_calls_total``, whose kind is fwd|bwd), a
+#: deploy-bounded identity
 #: (model/version/bucket/worker/name/replica — replica is a fleet
 #: member's URL, bounded by the router's configured replica set;
 #: block/expert — the expert blocks of a model's layer pattern and the
@@ -35,7 +37,7 @@ from . import Finding, Module, PACKAGE_ROOT
 #: must ride on exemplars or spans, never on labels.
 REGISTERED_LABELS: Set[str] = {
     "block", "bucket", "cache", "engine", "expert", "good", "kernel", "kind",
-    "mode", "model", "name", "outcome", "path", "priority", "reason",
+    "mode", "model", "name", "op", "outcome", "path", "priority", "reason",
     "replica", "site",
     "slo", "state", "tier", "version", "window", "worker", "jax_version",
     "jaxlib_version", "platform",

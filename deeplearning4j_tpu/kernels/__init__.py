@@ -22,6 +22,12 @@ cover the known gaps for the flagship workloads:
   block tables in-kernel (scalar-prefetch) with online-softmax
   accumulation, replacing the `jnp.take` gather read of
   `models.causal_lm.paged_decode` (gated by ``DL4J_TPU_PAGED_KERNEL``).
+- `ssm_fused.mamba_chain`: the elementwise chain of a Mamba-2 block
+  (`models.hybrid_lm`) around the caller's scan, as two fused operations
+  (conv + SiLU; skip + gate + grouped RMSNorm): each operand read once and
+  each result written once in the stored dtype, float32 in registers,
+  hand-written backwards, time as the minor axis. No dispatch: the model
+  calls it on every backend.
 
 A fused vocab-tiled softmax-xent kernel lived here through round 3 and was
 deleted after honest tuning kept it behind XLA at the BERT headline shape

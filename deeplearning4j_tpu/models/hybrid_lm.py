@@ -20,7 +20,9 @@ expert, ``*`` causal grouped-query attention.
                y_t = S_t . C_t + D x_t              (head i, group i // (H/G))
     out = W_out . GroupRMSNorm(y * silu(z))         (gate first, then norm
                                                      over d_inner / G, weight)
-  computed in the chunked (SSD) form, `ops.ssm_scan.ssd_chunked_scan`.
+  the scan in the chunked (SSD) form, `ops.ssm_scan.ssd_chunked_scan`;
+  what lies between it and the two projections (conv, silu, skip, gate,
+  group norm) in `kernels.ssm_fused.mamba_chain`'s two fused operations.
 ``E``:
     s = sigmoid(u_f32 . W_r);  top-k of s;  g_k = scale * s_k / (sum + 1e-20)
     out = sum_k g_k W2_{e_k} relu(W1_{e_k} u)^2  +  V2 relu(V1 u)^2
@@ -57,6 +59,7 @@ from jax import lax
 
 from ..common.tracing import model_scope
 from ..kernels import attention, attention_dispatch
+from ..kernels.ssm_fused import mamba_chain
 from ..ops import moe
 from ..ops.ssm_scan import ssd_chunked_scan
 from . import _optim
@@ -231,31 +234,26 @@ def _rms_norm(x, w, eps, groups: int = 1):
 
 def _mamba(p, u, c: HybridLMConfig):
     B, T, _ = u.shape
-    H, P, G, N = (c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
-                  c.ssm_state_size)
-    f32 = jnp.float32
-    with model_scope("ssm"):
-        zxbcdt = jnp.einsum("bte,ef->btf", u, p["in_proj"])
-        z, xBC, dt = jnp.split(zxbcdt, [c.d_inner, c.d_inner + c.conv_dim],
-                               axis=-1)
-        # causal depthwise conv: y_t = sum_k w[k] x_{t-(K-1)+k} + b
-        K = c.conv_kernel
-        padded = jnp.pad(xBC.astype(f32), [(0, 0), (K - 1, 0), (0, 0)])
-        conv = sum(padded[:, k:k + T] * p["conv_w"][k] for k in range(K))
-        xBC = jax.nn.silu(conv + p["conv_b"]).astype(u.dtype)
-        x, Bm, Cm = jnp.split(xBC, [c.d_inner, c.d_inner + G * N], axis=-1)
-        x = x.reshape(B, T, H, P)
-        dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"])
+    H, G = c.mamba_num_heads, c.n_groups
+    # the fused chain holds time as the minor axis, [B, channels, T]
+    steps_major = lambda a: jnp.swapaxes(a, 1, 2)
+
+    def scan(x, Bm, Cm, dt):
+        dt = jax.nn.softplus(steps_major(dt).astype(jnp.float32)
+                             + p["dt_bias"])
         A = -jnp.exp(p["A_log"])
-    with model_scope("ssm_scan"):
-        y = ssd_chunked_scan(x, dt, A, Bm.reshape(B, T, G, N),
-                             Cm.reshape(B, T, G, N), c.chunk_size)
+        x, Bm, Cm = (steps_major(a).reshape(B, T, n, -1)
+                     for a, n in ((x, H), (Bm, G), (Cm, G)))
+        with model_scope("ssm_scan"):
+            y = ssd_chunked_scan(x, dt, A, Bm, Cm, c.chunk_size)
+        return steps_major(y.reshape(B, T, c.d_inner))
+
     with model_scope("ssm"):
-        y = y.astype(f32) + p["D"][:, None] * x.astype(f32)
-        y = (y.reshape(B, T, c.d_inner)
-             * jax.nn.silu(z.astype(f32))).astype(u.dtype)
-        y = _rms_norm(y, p["gate_norm"], c.norm_eps, groups=G)
-        return jnp.einsum("btf,fe->bte", y, p["out_proj"])
+        zxbcdt = jnp.einsum("bte,ef->bft", u, p["in_proj"])
+        # conv + silu, the scan, then (y + D x) silu(z) and its group norm
+        y = mamba_chain(zxbcdt, p["conv_w"], p["conv_b"], p["D"],
+                        p["gate_norm"], c.norm_eps, G, scan)
+        return jnp.einsum("bft,fe->bte", y, p["out_proj"])
 
 
 def _relu2_mlp(u, w1, w2):

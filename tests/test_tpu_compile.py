@@ -24,6 +24,7 @@ from jax.sharding import (NamedSharding, PartitionSpec as P,
 # the package re-exports each kernel function under its module's name
 fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
 pfd = importlib.import_module("deeplearning4j_tpu.kernels.paged_flash_decode")
+sf = importlib.import_module("deeplearning4j_tpu.kernels.ssm_fused")
 
 
 @pytest.fixture(scope="module")
@@ -66,7 +67,7 @@ def no_persistent_cache():
 def compiled_kernels(monkeypatch, no_persistent_cache):
     """Steer the kernels to compile (not interpret) though the backend
     here is the CPU."""
-    for mod in (fa, pfd):  # pfd binds its own name for the same switch
+    for mod in (fa, pfd, sf):  # each binds its own name for the switch
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -113,6 +114,93 @@ def test_hybrid_causal_attention_compiles_for_v5e(shape, grad, one_chip,
     assert text.count('custom_call_target="tpu_custom_call"') == \
         (3 if grad else 1)
     assert compiled.memory_analysis().temp_size_in_bytes < B * H * S * S
+
+
+# the hybrid cell's Mamba-2 blocks: B = 1, T = 8,192, d_inner 4,096,
+# 8 groups x state 128, 64 heads; zxbcdt is [1, 10304, 8192], time minor
+MAMBA = dict(B=1, T=8192, d_inner=4096, n=1024, heads=64, groups=8)
+
+
+def _mamba_specs(one_chip):
+    m = MAMBA
+    conv_dim = m["d_inner"] + 2 * m["n"]
+    bf = lambda *shape: _sds(shape, jnp.bfloat16, one_chip)
+    f32 = lambda *shape: _sds(shape, jnp.float32, one_chip)
+    return dict(
+        zxbcdt=bf(m["B"], m["d_inner"] + conv_dim + m["heads"], m["T"]),
+        conv_w=f32(4, conv_dim), conv_b=f32(conv_dim),
+        y=bf(m["B"], m["d_inner"], m["T"]), x=bf(m["B"], m["d_inner"], m["T"]),
+        D=f32(m["heads"]), weight=f32(m["d_inner"]))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("op", ["conv_silu", "gate_norm"])
+def test_fused_mamba_ops_compile_for_v5e(op, grad, one_chip,
+                                         compiled_kernels):
+    """The two halves of `ssm_fused.mamba_chain` at the hybrid cell's
+    shapes, forward and backward: the conv's three windows (and their
+    three in-place backward calls), the gate norm's one call each way.
+    Compiled, not run: apart from its pair the gate's backward leaves the
+    rest of ``zg``'s cotangent undefined."""
+    m, sp = MAMBA, _mamba_specs(one_chip)
+    if op == "conv_silu":
+        specs = [sp["zxbcdt"], sp["conv_w"], sp["conv_b"]]
+        fn = lambda z, w, b: sf._conv_silu(z, w, b, m["d_inner"],
+                                          m["groups"])[1:]
+        calls = 3
+    else:
+        specs = [sp["y"], sp["x"], sp["zxbcdt"], sp["D"], sp["weight"]]
+        fn = lambda y, x, zg, D, w: (sf._gate_norm(y, x, zg, D, w, 1e-5,
+                                                   m["groups"]),)
+        calls = 1
+    if grad:
+        fwd, calls = fn, 2 * calls      # the loss reads the forward's values
+        fn = jax.grad(lambda *a: sum(
+            jnp.sum(o.astype(jnp.float32) ** 2) for o in fwd(*a)),
+            argnums=tuple(range(len(specs))))
+    _, text = _compile(fn, *specs)
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    if grad:
+        # one cotangent buffer for zxbcdt: nothing pads or adds pieces
+        assert "pad(" not in text.split("ENTRY")[1]
+
+
+def test_mamba_block_gradient_keeps_the_chain_out_of_f32_hbm(
+        one_chip, compiled_kernels):
+    """A whole Mamba-2 block's ``jax.grad`` at the cell's shapes: no
+    top-level instruction of the module yields the chain's float32
+    tensors ([T, conv_dim] or [T, d_inner], either axis minor). The scan
+    keeps float32 tensors of d_inner x T elements of its own (its
+    ``dt * x``), under its own scope and shapes."""
+    import re
+    from deeplearning4j_tpu.models import hybrid_lm
+    m = MAMBA
+    c = hybrid_lm.HybridLMConfig(
+        hidden_size=2688, mamba_num_heads=m["heads"], mamba_head_dim=64,
+        ssm_state_size=128, n_groups=m["groups"], chunk_size=128)
+    assert (c.d_inner, c.conv_dim) == (m["d_inner"], m["d_inner"] + 2 * m["n"])
+    p = {name: _sds(shape, jnp.bfloat16 if how in ("matrix", "residual_out")
+                    else jnp.float32, one_chip)
+         for name, (shape, how) in hybrid_lm._mixer_shapes(c, "M").items()}
+    u = _sds((m["B"], m["T"], c.hidden_size), jnp.bfloat16, one_chip)
+    _, text = _compile(jax.grad(lambda p, u: jnp.sum(
+        hybrid_lm._mamba(p, u, c).astype(jnp.float32) ** 2),
+        argnums=(0, 1)), p, u)
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    T, chain = m["T"], (c.conv_dim, c.d_inner)
+    for line in text.split("ENTRY")[1].splitlines():
+        made = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) [\w\-]+\(", line)
+        for dims in re.findall(r"f32\[([\d,]+)\]", made.group(1) if made
+                               else ""):
+            dims = [int(d) for d in dims.split(",")]
+            assert not (len(dims) == 3 and sorted(dims[1:]) in
+                        [sorted((T, w)) for w in chain]), line[:200]
+            scopes = re.findall(r"dl4j\.(\w+)", line)
+            if scopes[-1:] in (["ssm"], ["ln"]):
+                size = 1
+                for d in dims:
+                    size *= d
+                assert size not in [T * w for w in chain], line[:200]
 
 
 def test_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch,
