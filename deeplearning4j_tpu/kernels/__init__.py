@@ -29,6 +29,10 @@ cover the known gaps for the flagship workloads:
   hand-written backwards, time as the minor axis. No dispatch: the model
   calls it on every backend.
 
+Packed rows (several documents in a sequence, ``segment_ids``): the
+attention core, the flash kernels and `mamba_chain`'s conv take the ids
+and keep each document to itself; `boundary_pass` counts those passes.
+
 A fused vocab-tiled softmax-xent kernel lived here through round 3 and was
 deleted after honest tuning kept it behind XLA at the BERT headline shape
 (N=16384, V=30522, f32; best Pallas config tn=256 tv=2048): 0.93x forward,
@@ -49,7 +53,7 @@ from .paged_flash_decode import paged_flash_decode
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "paged_flash_decode", "attention", "attention_dispatch",
-           "kernel_dispatch", "dispatch_snapshot"]
+           "kernel_dispatch", "dispatch_snapshot", "boundary_pass"]
 
 _dispatch_logged = False
 
@@ -78,6 +82,24 @@ def kernel_dispatch(kernel: str, path: str, reason: str = "") -> str:
     except Exception:
         pass  # observability must never break a trace
     return path
+
+
+def boundary_pass(kernel: str, kind: str) -> None:
+    """Tick ``dl4j_boundary_kernel_passes_total{kernel,kind}``: one traced
+    pass of a kernel that takes document boundaries (packed rows): the fused
+    conv + SiLU of `ssm_fused` (kernel "conv_silu", kind fwd | bwd) and the
+    flash-attention kernels (kernel "flash", kind fwd | dq | dkv |
+    one_tile_fwd | one_tile_bwd). Beside ``dl4j_flash_tiles_total`` and
+    ``dl4j_ssm_fused_calls_total``, which count every pass."""
+    try:
+        from ..common.environment import environment
+        environment().metrics().counter(
+            "dl4j_boundary_kernel_passes_total",
+            "Kernel passes traced with document boundaries (packed rows), "
+            "counted at trace time",
+            labels=("kernel", "kind")).labels(kernel=kernel, kind=kind).inc()
+    except Exception:
+        pass  # observability must never break a trace
 
 
 def dispatch_snapshot() -> Dict[str, Dict[str, Optional[str]]]:
@@ -266,14 +288,22 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
 
 
 def attention(q, k, v, *, path: str, head_dim: int, mask=None,
-              causal: bool = False):
+              causal: bool = False, scale: Optional[float] = None,
+              segment_ids=None):
     """The full-sequence attention core on ``path`` ("flash" | "xla", what
     ``attention_dispatch`` answered for the traced model).
 
     ``q`` is ``[B, T, H*D]`` or ``[B, T, H, D]``; ``k`` and ``v`` likewise
     with ``Hkv <= H`` heads (``H % Hkv == 0``: query head ``i`` reads KV
     head ``i // (H // Hkv)``). ``mask``: optional ``[B, T]`` key validity
-    (1 = attend). Returns the context in ``q``'s layout and dtype.
+    (1 = attend). ``scale`` multiplies the scores ``q . k`` before the
+    softmax; None is ``head_dim ** -0.5`` (a model with a scalar attention
+    multiplier of its own hands it over here). ``segment_ids``: optional
+    ``[B, T]`` int32, the document each position of a packed row belongs
+    to: key ``j`` is visible to query ``i`` iff their ids are equal (and
+    ``j <= i`` under ``causal``, and ``mask[j]``); None is one document a
+    row, and traces to what it traced to before the argument existed.
+    Returns the context in ``q``'s layout and dtype.
 
     "flash" repeats the KV heads for the query heads that share them (the
     kernel's entry takes as many KV heads as query heads) and calls
@@ -288,13 +318,18 @@ def attention(q, k, v, *, path: str, head_dim: int, mask=None,
             k, v = (jnp.repeat(x.reshape(B, T, Hkv, D), R, axis=2)
                     for x in (k, v))
         return flash_attention(q, k, v, mask=mask, causal=causal,
-                               head_dim=D)
+                               head_dim=D, scale=scale,
+                               segment_ids=segment_ids)
     big_neg = jnp.finfo(jnp.float32).min
     k, v = (x.reshape(B, T, Hkv, D) for x in (k, v))
     s = jnp.einsum("btgrd,bsgd->bgrts", q.reshape(B, T, Hkv, R, D), k,
-                   preferred_element_type=jnp.float32) * D ** -0.5
+                   preferred_element_type=jnp.float32) * (
+                       D ** -0.5 if scale is None else scale)
     if mask is not None:
         s = jnp.where(mask[:, None, None, None, :].astype(bool), s, big_neg)
+    if segment_ids is not None:
+        same = segment_ids[:, :, None] == segment_ids[:, None, :]
+        s = jnp.where(same[:, None, None], s, big_neg)
     if causal:
         s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, big_neg)
     prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)

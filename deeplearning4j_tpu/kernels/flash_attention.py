@@ -40,6 +40,17 @@ steps are the trailing kv steps of fwd/dq and the leading q steps of dkv),
 ``dl4j_flash_tiles_total{kernel,kind}`` counts both kinds per traced pass,
 and a call without ``causal`` traces to kernels without any of this.
 
+**Packed rows.** With ``segment_ids`` (``[B, S]`` int32: the document a
+position belongs to) a key is visible only to the queries of its own
+document. The kernels take the ids twice, as a column ``[B, S, 1]`` for the
+rows of a score tile and as a row ``[B, 1, S]`` for its columns, and compare
+them in every tile they compute (the tiles a causal mask empties are
+skipped as before; a tile that lies wholly between two documents is still
+computed). A call without ``segment_ids`` traces to the kernels it traced
+to before: no operand, no compare.
+``dl4j_boundary_kernel_passes_total{kernel,kind}`` counts the passes traced
+with ids.
+
 Sequence lengths that don't divide the tiles are zero-padded to the tile
 boundary (padded keys masked off, padded query rows sliced away). A fully
 masked row degrades to a uniform softmax — identical to what the XLA
@@ -91,8 +102,16 @@ def _on_live_tile(step, iq, ik, tq, tk):
         lambda: step(True))
 
 
+def _same_document(s, seg):
+    """Scores with the pairs of different documents masked off; ``seg`` is
+    (the queries' ids [1, TQ, 1], the keys' ids [1, 1, TK]) refs or None."""
+    if seg is None:
+        return s
+    return jnp.where(seg[0][0] == seg[1][0], s, _NEG_INF)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                m_sc, l_sc, acc_sc, *, scale, causal, n_k, skip):
+                m_sc, l_sc, acc_sc, *, scale, causal, n_k, skip, seg=None):
     iq, ik = pl.program_id(1), pl.program_id(2)
     tq, tk = q_ref.shape[1], k_ref.shape[1]
 
@@ -110,6 +129,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                                 preferred_element_type=jnp.float32) * scale
         if mask_ref is not None:
             s = jnp.where(mask_ref[0][:, 0][None, :] != 0, s, _NEG_INF)
+        s = _same_document(s, seg)
         if masked:
             q_pos = iq * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
             k_pos = ik * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
@@ -141,6 +161,18 @@ def _fwd_kernel_nomask(q_ref, k_ref, v_ref, o_ref, lse_ref,
                        m_sc, l_sc, acc_sc, **kw):
     _fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref,
                 m_sc, l_sc, acc_sc, **kw)
+
+
+def _segmented(kernel, masked: bool):
+    """``kernel`` (one of the three streaming kernels) for a call with
+    document ids: after its first refs come [the key mask,] the queries'
+    ids and the keys' ids, then the rest as the kernel takes them."""
+    def run(*refs, n_in, **kw):
+        first, refs = refs[:n_in], refs[n_in:]
+        mask_ref = refs[0] if masked else None
+        seg = refs[masked:masked + 2]
+        kernel(*first, mask_ref, *refs[masked + 2:], seg=seg, **kw)
+    return run
 
 
 def _kv_block(iq, ik, tile_q, tile_k, skip):
@@ -180,8 +212,27 @@ def _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip):
         pass  # observability must never break a trace
 
 
+def _seg_specs(seg, heads, tile_q, tile_k, q_block, k_block):
+    """Specs and operands of a packed call's ids: ``seg`` = (column [B, S,
+    1], row [B, 1, S]), shared by the ``heads`` heads of a batch row;
+    ``q_block`` / ``k_block`` give a grid step's block along S."""
+    if seg is None:
+        return [], []
+    row = lambda bh: jax.lax.div(bh, heads)
+    return ([pl.BlockSpec((1, tile_q, 1),
+                          lambda bh, i, j: (row(bh), q_block(i, j), 0)),
+             pl.BlockSpec((1, 1, tile_k),
+                          lambda bh, i, j: (row(bh), 0, k_block(i, j)))],
+            list(seg))
+
+
+def _count_boundary_pass(kind):
+    from . import boundary_pass
+    boundary_pass("flash", kind)
+
+
 def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
-               skip_empty=True):
+               skip_empty=True, seg=None, heads=1):
     """``skip_empty=False`` (tests only) computes and masks every tile of
     a causal call, as the kernels did before they skipped."""
     BH, S, D = q.shape
@@ -202,9 +253,18 @@ def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
     if mask is not None:
         in_specs.append(pl.BlockSpec((1, tile_k, 1), kv))
         args.append(mask)
-    kern = functools.partial(
-        _fwd_kernel if mask is not None else _fwd_kernel_nomask,
-        scale=scale, causal=causal, n_k=n_k, skip=skip)
+    if seg is None:
+        kern = _fwd_kernel if mask is not None else _fwd_kernel_nomask
+    else:
+        _count_boundary_pass("fwd")
+        kern = functools.partial(_segmented(_fwd_kernel, mask is not None),
+                                 n_in=3)
+    specs, ids = _seg_specs(
+        seg, heads, tile_q, tile_k, lambda iq, ik: iq,
+        lambda iq, ik: _kv_block(iq, ik, tile_q, tile_k, skip))
+    in_specs, args = in_specs + specs, args + ids
+    kern = functools.partial(kern, scale=scale, causal=causal, n_k=n_k,
+                             skip=skip)
     return pl.pallas_call(
         kern,
         grid=grid,
@@ -231,13 +291,14 @@ def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
 # backward
 # ---------------------------------------------------------------------------
 
-def _p_tile(q, k, mask_row, lse, iq, ik, scale, causal):
+def _p_tile(q, k, mask_row, lse, iq, ik, scale, causal, seg=None):
     """Recompute the [TQ, TK] probability tile from saved lse."""
     tq, tk = q.shape[0], k.shape[0]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if mask_row is not None:
         s = jnp.where(mask_row[:, 0][None, :] != 0, s, _NEG_INF)
+    s = _same_document(s, seg)
     if causal:
         q_pos = iq * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
         k_pos = ik * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
@@ -246,7 +307,7 @@ def _p_tile(q, k, mask_row, lse, iq, ik, scale, causal):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
-               dq_ref, dq_sc, *, scale, causal, n_k, skip):
+               dq_ref, dq_sc, *, scale, causal, n_k, skip, seg=None):
     iq, ik = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -256,7 +317,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
     def _step(masked):
         q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
         mrow = mask_ref[0] if mask_ref is not None else None
-        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked)
+        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked, seg)
         dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [TQ, TK]
         ds = p * (dp - delta_ref[0])
@@ -280,7 +341,8 @@ def _dq_kernel_nomask(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
-                dk_ref, dv_ref, dk_sc, dv_sc, *, scale, causal, n_q, skip):
+                dk_ref, dv_ref, dk_sc, dv_sc, *, scale, causal, n_q, skip,
+                seg=None):
     ik, iq = pl.program_id(1), pl.program_id(2)
 
     @pl.when(iq == 0)
@@ -291,7 +353,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
     def _step(masked):
         q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
         mrow = mask_ref[0] if mask_ref is not None else None
-        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked)
+        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked, seg)
         dv_sc[...] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -320,7 +382,7 @@ def _dkv_kernel_nomask(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
-               lse_cot=None, skip_empty=True):
+               lse_cot=None, skip_empty=True, seg=None, heads=1):
     BH, S, D = q.shape
     cap = _bwd_tile_cap(causal)
     if tile_q > cap and S % cap == 0:
@@ -358,18 +420,33 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
     if mask is not None:
         in_specs.append(kspec(kv, 1))
         args.append(mask)
+
+    def kernel(masked, plain, **kw):
+        """The pass's kernel for this call's operands."""
+        if seg is None:
+            kern = masked if mask is not None else plain
+        else:
+            kern = functools.partial(_segmented(masked, mask is not None),
+                                     n_in=6)
+        return functools.partial(kern, scale=scale, causal=causal, skip=skip,
+                                 **kw)
+
+    if seg is not None:
+        _count_boundary_pass("dq")
+        _count_boundary_pass("dkv")
+    specs, ids = _seg_specs(
+        seg, heads, tile_q, tile_k, lambda iq, ik: iq,
+        lambda iq, ik: _kv_block(iq, ik, tile_q, tile_k, skip))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel if mask is not None else
-                          _dq_kernel_nomask,
-                          scale=scale, causal=causal, n_k=n_k, skip=skip),
+        kernel(_dq_kernel, _dq_kernel_nomask, n_k=n_k),
         grid=(BH, n_q, n_k),
-        in_specs=in_specs,
+        in_specs=in_specs + specs,
         out_specs=qspec(own_q),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((tile_q, D), jnp.float32)],
         compiler_params=_params(2),
         interpret=_interpret(),
-    )(*args)
+    )(*args, *ids)
 
     # dk/dv: stream q blocks for each kv block
     def own_kv(bh, ik, iq):
@@ -384,12 +461,14 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
     if mask is not None:
         in_specs.append(kspec(own_kv, 1))
         args.append(mask)
+    specs, _ = _seg_specs(
+        seg, heads, tile_q, tile_k,
+        lambda ik, iq: _q_block(ik, iq, tile_q, tile_k, skip),
+        lambda ik, iq: ik)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel if mask is not None else
-                          _dkv_kernel_nomask,
-                          scale=scale, causal=causal, n_q=n_q, skip=skip),
+        kernel(_dkv_kernel, _dkv_kernel_nomask, n_q=n_q),
         grid=(BH, n_k, n_q),
-        in_specs=in_specs,
+        in_specs=in_specs + specs,
         out_specs=[kspec(own_kv), kspec(own_kv)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
@@ -397,7 +476,7 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
                         pltpu.VMEM((tile_k, D), jnp.float32)],
         compiler_params=_params(2),
         interpret=_interpret(),
-    )(*args)
+    )(*args, *ids)
     return dq, dk, dv
 
 
@@ -443,6 +522,36 @@ def _flash_masked_b(scale, causal, tile_q, tile_k, res, g):
 
 
 _flash_masked.defvjp(_flash_masked_f, _flash_masked_b)
+
+
+# packed rows: document ids beside an optional key mask
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _flash_seg(q, k, v, mask, seg_col, seg_row, scale, causal, tile_q,
+               tile_k, heads):
+    """``mask`` [BH, S, 1] or None; ``seg_col`` [B, S, 1] and ``seg_row``
+    [B, 1, S] the document ids of the ``heads`` heads of each batch row."""
+    o, _ = _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
+                      seg=(seg_col, seg_row), heads=heads)
+    return o
+
+
+def _flash_seg_f(q, k, v, mask, seg_col, seg_row, scale, causal, tile_q,
+                 tile_k, heads):
+    o, lse = _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
+                        seg=(seg_col, seg_row), heads=heads)
+    return o, (q, k, v, mask, seg_col, seg_row, o, lse)
+
+
+def _flash_seg_b(scale, causal, tile_q, tile_k, heads, res, g):
+    q, k, v, mask, seg_col, seg_row, o, lse = res
+    dq, dk, dv = _flash_bwd(q, k, v, mask, o, lse, g, scale, causal,
+                            tile_q, tile_k, seg=(seg_col, seg_row),
+                            heads=heads)
+    return dq, dk, dv, None, None, None
+
+
+_flash_seg.defvjp(_flash_seg_f, _flash_seg_b)
 
 
 # (o, lse)-returning variant: the ring/SP path needs the per-block lse to
@@ -518,11 +627,17 @@ _NT = (((1,), (1,)), ((), ()))   # a · bᵀ
 _TN = (((0,), (0,)), ((), ()))   # aᵀ · b
 
 
-def _one_tile_fwd_kernel(*refs, scale, causal, masked, head_dim):
+def _and(keep, more):
+    return more if keep is None else keep & more
+
+
+def _one_tile_fwd_kernel(*refs, scale, causal, masked, segmented, head_dim):
     (q_ref, k_ref, v_ref), o_ref = refs[:3], refs[-1]
     q, k, v = q_ref[0], k_ref[0], v_ref[0]                      # [S, W]
     S = q.shape[0]
     keep = refs[3][0] != 0 if masked else None                  # [1, S]
+    if segmented:       # ids as a column [S, 1] and as a row [1, S]
+        keep = _and(keep, refs[-3][0] == refs[-2][0])
     if causal:
         tri = (jax.lax.broadcasted_iota(jnp.int32, (S, S), 0) >=
                jax.lax.broadcasted_iota(jnp.int32, (S, S), 1))
@@ -541,11 +656,13 @@ def _one_tile_fwd_kernel(*refs, scale, causal, masked, head_dim):
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _one_tile_bwd_kernel(*refs, scale, causal, masked, head_dim):
+def _one_tile_bwd_kernel(*refs, scale, causal, masked, segmented, head_dim):
     q, k, v, g = (r[0] for r in refs[:4])                        # [S, W]
     dq_ref, dk_ref, dv_ref = refs[-3:]
     S = q.shape[0]
     keep = refs[4][0] != 0 if masked else None                   # [S, 1]
+    if segmented:       # the keys' ids down sT's rows, the queries' across
+        keep = _and(keep, refs[-5][0] == refs[-4][0])
     if causal:                      # sT[k, q]: key row <= query column
         tri = (jax.lax.broadcasted_iota(jnp.int32, (S, S), 0) <=
                jax.lax.broadcasted_iota(jnp.int32, (S, S), 1))
@@ -586,18 +703,21 @@ def _block_lanes(H, D):
     return w if (H * D) % w == 0 else H * D
 
 
-def _one_tile_call(kernel, ins, mask, n_out, D, scale, causal):
+def _one_tile_call(kernel, ins, mask, seg, n_out, D, scale, causal):
+    """``mask``: the key mask laid out as the kernel reads it, or None;
+    ``seg``: (ids as a column [B, S, 1], as a row [B, 1, S]) or None."""
     B, S, HD = ins[0].shape
     W = _block_lanes(HD // D, D)
     blk = pl.BlockSpec((1, S, W), lambda b, j: (b, 0, j))
     in_specs, args = [blk] * len(ins), list(ins)
-    if mask is not None:
-        in_specs.append(pl.BlockSpec((1,) + mask.shape[1:],
+    for extra in ([] if mask is None else [mask]) + list(seg or ()):
+        in_specs.append(pl.BlockSpec((1,) + extra.shape[1:],
                                      lambda b, j: (b, 0, 0)))
-        args.append(mask)
+        args.append(extra)
     return pl.pallas_call(
         functools.partial(kernel, scale=scale, causal=causal,
-                          masked=mask is not None, head_dim=D),
+                          masked=mask is not None,
+                          segmented=seg is not None, head_dim=D),
         grid=(B, HD // W),
         in_specs=in_specs,
         out_specs=[blk] * n_out,
@@ -611,28 +731,38 @@ def _one_tile_call(kernel, ins, mask, n_out, D, scale, causal):
     )(*args)
 
 
-def _one_tile_fwd(q, k, v, mask, D, scale, causal):
+def _col_row(seg):
+    """[B, S] ids as the kernels read them, or None."""
+    return None if seg is None else (seg[:, :, None], seg[:, None, :])
+
+
+def _one_tile_fwd(q, k, v, mask, seg, D, scale, causal):
     mrow = None if mask is None else mask[:, None, :]
-    return _one_tile_call(_one_tile_fwd_kernel, (q, k, v), mrow, 1,
-                          D, scale, causal)[0]
+    if seg is not None:
+        _count_boundary_pass("one_tile_fwd")
+    return _one_tile_call(_one_tile_fwd_kernel, (q, k, v), mrow,
+                          _col_row(seg), 1, D, scale, causal)[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _one_tile(q, k, v, mask, D, scale, causal):
-    """q/k/v [B, S, H*D]; mask [B, S] int32 or None."""
-    return _one_tile_fwd(q, k, v, mask, D, scale, causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _one_tile(q, k, v, mask, seg, D, scale, causal):
+    """q/k/v [B, S, H*D]; mask, seg [B, S] int32 or None."""
+    return _one_tile_fwd(q, k, v, mask, seg, D, scale, causal)
 
 
-def _one_tile_f(q, k, v, mask, D, scale, causal):
-    return _one_tile_fwd(q, k, v, mask, D, scale, causal), (q, k, v, mask)
+def _one_tile_f(q, k, v, mask, seg, D, scale, causal):
+    return (_one_tile_fwd(q, k, v, mask, seg, D, scale, causal),
+            (q, k, v, mask, seg))
 
 
 def _one_tile_b(D, scale, causal, res, g):
-    q, k, v, mask = res
+    q, k, v, mask, seg = res
     mcol = None if mask is None else mask[:, :, None]
-    dq, dk, dv = _one_tile_call(_one_tile_bwd_kernel, (q, k, v, g), mcol, 3,
-                                D, scale, causal)
-    return dq, dk, dv, None
+    if seg is not None:
+        _count_boundary_pass("one_tile_bwd")
+    dq, dk, dv = _one_tile_call(_one_tile_bwd_kernel, (q, k, v, g), mcol,
+                                _col_row(seg), 3, D, scale, causal)
+    return dq, dk, dv, None, None
 
 
 _one_tile.defvjp(_one_tile_f, _one_tile_b)
@@ -642,7 +772,7 @@ def _padded_len(S):
     return S if S <= 128 else -(-S // 128) * 128
 
 
-def _flash_one_tile(q, k, v, mask, causal, scale, D):
+def _flash_one_tile(q, k, v, mask, seg, causal, scale, D):
     """q/k/v [B, S, H*D] -> [B, S, H*D]."""
     B, S, _ = q.shape
     S_pad = _padded_len(S)
@@ -654,7 +784,9 @@ def _flash_one_tile(q, k, v, mask, causal, scale, D):
         if mask is None:
             mask = jnp.ones((B, S), jnp.int32)
         mask = jnp.pad(mask, [(0, 0), (0, S_pad - S)])
-    out = _one_tile(q, k, v, mask, D, scale, causal)
+        if seg is not None:     # the padded keys are masked off already
+            seg = jnp.pad(seg, [(0, 0), (0, S_pad - S)], mode="edge")
+    out = _one_tile(q, k, v, mask, seg, D, scale, causal)
     return out[:, :S] if S_pad != S else out
 
 
@@ -732,12 +864,16 @@ def _prep(q, k, v, mask, scale, tile_q, tile_k, causal):
 
 def flash_attention(q, k, v, mask=None, causal: bool = False,
                     scale: float = None, tile_q: int = None,
-                    tile_k: int = None, head_dim: int = None):
+                    tile_k: int = None, head_dim: int = None,
+                    segment_ids=None):
     """Flash attention over [B, S, H, D] (BTHD, the framework convention)
     or, with ``head_dim`` given, over [B, S, H*D] as the q/k/v projections
     produce it (the result has the layout of the inputs).
 
-    mask: optional [B, S] key validity (1 = attend). Differentiable in
+    mask: optional [B, S] key validity (1 = attend). segment_ids: optional
+    [B, S] int32, the document of each position of a packed row: a key is
+    visible to the queries of its own document only. ``scale`` multiplies
+    the scores (default ``D ** -0.5``). Differentiable in
     q/k/v; O(S) HBM in both forward and backward (the probability matrix
     only ever exists as VMEM tiles).
     Any S is accepted: inputs are zero-padded to the tile boundary (padded
@@ -757,13 +893,22 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     # the one-tile kernels take heads packed, the streaming ones apart;
     # going from one to the other is a free reshape
     shape = q.shape
+    if segment_ids is not None:
+        segment_ids = segment_ids.astype(jnp.int32)
     if one_tile:
         q, k, v = (x.reshape(shape[:2] + (-1,)) for x in (q, k, v))
-        return _flash_one_tile(q, k, v, mask, causal, scale, D).reshape(shape)
+        return _flash_one_tile(q, k, v, mask, segment_ids, causal, scale,
+                               D).reshape(shape)
     q, k, v = (x.reshape(shape[:2] + (-1, D)) for x in (q, k, v))
     (qf, kf, vf, mf, scale, tile_q, tile_k,
      S, S_pad, B, H, D) = _prep(q, k, v, mask, scale, tile_q, tile_k, causal)
-    if mf is not None:
+    if segment_ids is not None:
+        if S_pad != S:          # the padded keys are masked off already
+            segment_ids = jnp.pad(segment_ids, [(0, 0), (0, S_pad - S)],
+                                  mode="edge")
+        out = _flash_seg(qf, kf, vf, mf, *_col_row(segment_ids), scale,
+                         causal, tile_q, tile_k, H)
+    elif mf is not None:
         out = _flash_masked(qf, kf, vf, mf, scale, causal, tile_q, tile_k)
     else:
         out = _flash(qf, kf, vf, scale, causal, tile_q, tile_k)

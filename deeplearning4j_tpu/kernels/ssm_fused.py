@@ -63,7 +63,20 @@ Shapes that do not tile (a group or a state width that is no multiple of
 tiled layout first and run through the same kernels; ``zg`` is then that
 padded copy. On the CPU the kernels run interpreted.
 
-``dl4j_ssm_fused_calls_total{op,kind}`` counts the passes at trace time.
+**Packed rows.** With ``segment_ids`` (``[B, T]`` int32, non-decreasing
+along a row) the conv's tap ``x_{t-j}`` counts only where step ``t - j``
+is of step ``t``'s document. One more operand says so, ``[B, 1, T]``: the
+steps since the document's first, clipped to ``K - 1``; tap ``j`` is live
+at ``t`` iff it is ``>= j``. The forward kernel weighs its shifted tiles
+by that, the backward the same on the output position ``t + j`` of each
+term of ``d x_t`` (it reads the operand's first steps of the following
+tile beside its own). The gate and its norm work step by step and need
+nothing. Without ``segment_ids`` no such operand exists and the kernels
+traced are the ones above.
+
+``dl4j_ssm_fused_calls_total{op,kind}`` counts the passes at trace time,
+``dl4j_boundary_kernel_passes_total{kernel,kind}`` those of them that
+take document boundaries.
 """
 from __future__ import annotations
 
@@ -82,6 +95,7 @@ _HALO = 128          # steps of the block read before a tile
 _ROWS = 32           # channels a pass of the conv's loop inside a tile
 _STEPS = 128         # steps a pass of the gate's loop inside a tile
 _SUB = 16            # channels a window starts and ends on (a bf16 tile)
+_GATE_TILE = 512 * _TILE   # most elements of a gate tile: a group x steps
 _VMEM_LIMIT = 64 * 1024 * 1024
 F32 = jnp.float32
 
@@ -101,6 +115,11 @@ def _count(op: str, kind: str) -> None:
             labels=("op", "kind")).labels(op=op, kind=kind).inc()
     except Exception:
         pass  # observability must never break a trace
+
+
+def _boundary_pass(kernel: str, kind: str) -> None:
+    from . import boundary_pass
+    boundary_pass(kernel, kind)
 
 
 def _up(n: int, m: int) -> int:
@@ -133,6 +152,16 @@ class _Layout:
     def tiled(self) -> bool:
         """The operands are in the tiled layout as they come."""
         return (self.gw, self.n, self.t) == (self.gwp, self.n_p, self.t_p)
+
+    @property
+    def gate_tile(self) -> int:
+        """Steps of a gate tile, which holds a whole group of channels: the
+        conv's tile, halved while a wider group than 512 channels would
+        make it larger than 512 channels of the conv's."""
+        tile = self.tile
+        while self.gwp * tile > _GATE_TILE and tile % (2 * _HALO) == 0:
+            tile //= 2
+        return tile
 
     @property
     def chunk(self) -> int:
@@ -280,6 +309,22 @@ def _taps(x, halo, has_before, k: int):
             for j in range(k)]
 
 
+def _live(since, k: int):
+    """``[1, steps]`` float32 weights of taps 1 .. k-1 from the steps
+    since the document's start: 1 where the tap's step is of the same
+    document, else 0."""
+    since = since.astype(F32)
+    return [jnp.where(since >= j, 1.0, 0.0) for j in range(1, k)]
+
+
+def _same_document(taps, live):
+    """The taps with those that reach before the document's start
+    zeroed (``live`` None: one document a row)."""
+    if live is None:
+        return taps
+    return taps[:1] + [t * m for t, m in zip(taps[1:], live)]
+
+
 def _pre(taps, w_ref, b_ref, at):
     k = w_ref.shape[1]
     pre = b_ref[at] + taps[0] * w_ref[at, k - 1:k]
@@ -288,23 +333,38 @@ def _pre(taps, w_ref, b_ref, at):
     return pre
 
 
-def _conv_kernel(x_ref, halo_ref, w_ref, b_ref, o_ref):
+def _conv_kernel(x_ref, halo_ref, w_ref, b_ref, *refs):
+    # refs: [the steps since the document's start,] the result
+    o_ref = refs[-1]
     has_before = pl.program_id(2) > 0
     k, ch = w_ref.shape[1], x_ref.shape[1]
+    live = _live(refs[0][0], k) if len(refs) > 1 else None
 
     def rows(at):
-        pre = _pre(_taps(x_ref[0, at], halo_ref[0, at], has_before, k),
-                   w_ref, b_ref, at)
+        pre = _pre(_same_document(
+            _taps(x_ref[0, at], halo_ref[0, at], has_before, k), live),
+            w_ref, b_ref, at)
         o_ref[0, at] = (pre * _sigmoid(pre)).astype(o_ref.dtype)
 
     _each(ch, math.gcd(ch, _ROWS), rows)
 
 
-def _conv_bwd_kernel(x_ref, halo_ref, *refs, n_t, n_g):
-    g_refs, (w_ref, b_ref, _, dx_ref, dw_ref, db_ref, carry) = (
-        refs[:n_g], refs[n_g:])
+def _conv_bwd_kernel(x_ref, halo_ref, *refs, n_t, n_g, packed):
+    g_refs, (w_ref, b_ref, _), refs = refs[:n_g], refs[n_g:n_g + 3], \
+        refs[n_g + 3:]
+    since_refs, (dx_ref, dw_ref, db_ref, carry) = refs[:-4], refs[-4:]
     b, i = pl.program_id(1), pl.program_id(2)     # i counts from the end
     k, (ch, tile) = w_ref.shape[1], x_ref.shape[1:]
+    live = after = None
+    if packed:
+        # the tile's own steps, then the first of the tile after it
+        since, since_after = (r[0].astype(F32) for r in since_refs)
+        live = _live(since, k)
+        ext_since = jnp.broadcast_to(
+            jnp.concatenate([since, since_after], axis=1), (8, tile + _HALO))
+        # whether d pre_{t+j} counts x_t: step t + j's tap j is live
+        after = [jnp.where(pltpu.roll(ext_since, tile + _HALO - j, 1)
+                           [:1, :tile] >= j, 1.0, 0.0) for j in range(1, k)]
 
     @pl.when(i == 0)
     def _last_tile():
@@ -316,7 +376,8 @@ def _conv_bwd_kernel(x_ref, halo_ref, *refs, n_t, n_g):
         db_ref[...] = jnp.zeros_like(db_ref)
 
     def rows(at):
-        taps = _taps(x_ref[0, at], halo_ref[0, at], i < n_t - 1, k)
+        taps = _same_document(
+            _taps(x_ref[0, at], halo_ref[0, at], i < n_t - 1, k), live)
         pre = _pre(taps, w_ref, b_ref, at)
         s = _sigmoid(pre)
         g = sum(g_ref[0, at].astype(F32) for g_ref in g_refs)
@@ -326,8 +387,10 @@ def _conv_bwd_kernel(x_ref, halo_ref, *refs, n_t, n_g):
         ext = jnp.concatenate([dpre, carry[at]], axis=1)
         dx = dpre * w_ref[at, k - 1:k]
         for j in range(1, k):
-            dx = dx + (pltpu.roll(ext, tile + _HALO - j, 1)[:, :tile]
-                       * w_ref[at, k - 1 - j:k - j])
+            term = pltpu.roll(ext, tile + _HALO - j, 1)[:, :tile]
+            if packed:
+                term = term * after[j - 1]
+            dx = dx + term * w_ref[at, k - 1 - j:k - j]
         dx_ref[0, at] = dx.astype(dx_ref.dtype)
         carry[at] = dpre[:, :_HALO]
         for j in range(k):
@@ -359,10 +422,26 @@ def _window_specs(lay: _Layout, k: int, src: int, w_src: int, reverse: bool):
     ]
 
 
-def _conv_window(zp, w_p, b_p, lay: _Layout, src, w_src, width):
+def _since_specs(lay: _Layout, reverse: bool):
+    """The ``[B, 1, T]`` operand of a packed row: [a tile's own steps,
+    the first steps of the tile after it (the backward's)]."""
+    tile = lay.tile
+    n_t, per = lay.t_p // tile, tile // _HALO
+    step = (lambda i: n_t - 1 - i) if reverse else (lambda i: i)
+    return [
+        pl.BlockSpec((1, 1, tile), lambda c, b, i: (b, 0, step(i))),
+        pl.BlockSpec((1, 1, _HALO), lambda c, b, i: (
+            b, 0, jnp.minimum((step(i) + 1) * per, n_t * per - 1))),
+    ]
+
+
+def _conv_window(zp, w_p, b_p, since, lay: _Layout, src, w_src, width):
     """conv + silu of the channels [src, src + width) of ``zp``."""
     bsz, tile, ch = zp.shape[0], lay.tile, lay.chunk
     _, in_specs = _window_specs(lay, w_p.shape[1], src, w_src, False)
+    packed = () if since is None else (since,)
+    if packed:
+        in_specs = in_specs + _since_specs(lay, False)[:1]
     return pl.pallas_call(
         _conv_kernel,
         grid=(width // ch, bsz, lay.t_p // tile),
@@ -370,11 +449,11 @@ def _conv_window(zp, w_p, b_p, lay: _Layout, src, w_src, width):
         out_specs=pl.BlockSpec((1, ch, tile), lambda c, b, i: (b, c, i)),
         out_shape=jax.ShapeDtypeStruct((bsz, width, lay.t_p), zp.dtype),
         compiler_params=_params(), interpret=_interpret(),
-    )(zp, zp, w_p, b_p)
+    )(zp, zp, w_p, b_p, *packed)
 
 
-def _conv_window_bwd(zp, gs, w_p, b_p, dbuf, lay: _Layout, src, w_src,
-                     width):
+def _conv_window_bwd(zp, gs, w_p, b_p, dbuf, since, lay: _Layout, src,
+                     w_src, width):
     """(``dbuf`` with the window's ``d xBC`` written into its channels in
     place, ``d w`` [width, K], ``d b`` [width, 1]); ``gs`` the window's
     cotangents, added up in the kernel."""
@@ -382,11 +461,14 @@ def _conv_window_bwd(zp, gs, w_p, b_p, dbuf, lay: _Layout, src, w_src,
     n_t = lay.t_p // tile
     at, in_specs = _window_specs(lay, k, src, w_src, True)
     acc = lambda lanes: pl.BlockSpec((ch, lanes), lambda c, b, i: (c, 0))
+    packed = () if since is None else (since, since)
     return pl.pallas_call(
-        functools.partial(_conv_bwd_kernel, n_t=n_t, n_g=len(gs)),
+        functools.partial(_conv_bwd_kernel, n_t=n_t, n_g=len(gs),
+                          packed=bool(packed)),
         grid=(width // ch, bsz, n_t),
         in_specs=in_specs[:2] + [at(0)] * len(gs) + in_specs[2:]
-        + [pl.BlockSpec(memory_space=pl.ANY)],
+        + [pl.BlockSpec(memory_space=pl.ANY)]
+        + (_since_specs(lay, True) if packed else []),
         out_specs=[at(src), acc(k), acc(1)],
         out_shape=[jax.ShapeDtypeStruct(dbuf.shape, dbuf.dtype),
                    jax.ShapeDtypeStruct((width, k), F32),
@@ -394,22 +476,23 @@ def _conv_window_bwd(zp, gs, w_p, b_p, dbuf, lay: _Layout, src, w_src,
         scratch_shapes=[pltpu.VMEM((ch, _HALO), F32)],
         input_output_aliases={4 + len(gs): 0},
         compiler_params=_params(), interpret=_interpret(),
-    )(zp, zp, *gs, w_p, b_p, dbuf)
+    )(zp, zp, *gs, w_p, b_p, dbuf, *packed)
 
 
 @_shared_pass("lay")
-def _conv_fwd(zp, conv_w, conv_b, lay: _Layout):
+def _conv_fwd(zp, conv_w, conv_b, lay: _Layout, since=None):
     w_p, b_p = _tile_conv(conv_w, lay), _tile_conv(conv_b[None], lay)
-    return tuple(_conv_window(zp, w_p, b_p, lay, *win)
+    return tuple(_conv_window(zp, w_p, b_p, since, lay, *win)
                  for win in lay.windows)
 
 
 @_shared_pass("lay")
-def _conv_bwd(zp, gs, gdt, dbuf, conv_w, conv_b, lay: _Layout):
+def _conv_bwd(zp, gs, gdt, dbuf, conv_w, conv_b, lay: _Layout, since=None):
     w_p, b_p = _tile_conv(conv_w, lay), _tile_conv(conv_b[None], lay)
     dws, dbs = [], []
     for g, win in zip(gs, lay.windows):
-        dbuf, dw, db = _conv_window_bwd(zp, g, w_p, b_p, dbuf, lay, *win)
+        dbuf, dw, db = _conv_window_bwd(zp, g, w_p, b_p, dbuf, since, lay,
+                                        *win)
         dws.append(dw)
         dbs.append(db)
     if lay.h:
@@ -419,8 +502,8 @@ def _conv_bwd(zp, gs, gdt, dbuf, conv_w, conv_b, lay: _Layout):
             _untile_conv(jnp.concatenate(dbs), lay)[0])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _conv_silu(zxbcdt, conv_w, conv_b, d_inner: int, groups: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _conv_silu(zxbcdt, conv_w, conv_b, since, d_inner: int, groups: int):
     """``(x [B,d_inner,T], x again, B [B,n,T], C [B,n,T], dt [B,h,T],
     zg)`` from ``zxbcdt`` [B, 2 d_inner + 2 n + h, T]: the conv and silu
     over the ``x | B | C`` channels; ``dt`` the last ``h`` channels as
@@ -429,12 +512,14 @@ def _conv_silu(zxbcdt, conv_w, conv_b, d_inner: int, groups: int):
     two readers (the scan and `_gate_norm`): their cotangents then reach
     the backward kernel apart and are added in its registers, not by a
     pass over HBM. ``groups`` is the gate norm's (it decides the padding
-    of a shape that does not tile).
+    of a shape that does not tile). ``since``: None, or for packed rows
+    the int32 ``[B, 1, T]`` steps since each step's document began,
+    clipped to ``K - 1`` (`_steps_since_start`).
 
     Half of a pair, for `mamba_chain` alone: its backward takes ``zg``'s
     cotangent as the buffer it completes in place, so ``zg`` has to have
     no reader, or `_gate_norm` as its one reader."""
-    return _conv_silu_f(zxbcdt, conv_w, conv_b, d_inner, groups)[0]
+    return _conv_silu_f(zxbcdt, conv_w, conv_b, since, d_inner, groups)[0]
 
 
 def _conv_layout(conv_w, d_inner, h, t, groups) -> _Layout:
@@ -443,28 +528,33 @@ def _conv_layout(conv_w, d_inner, h, t, groups) -> _Layout:
     return _layout(t, d_inner, (conv_w.shape[1] - d_inner) // 2, h, groups)
 
 
-def _conv_silu_f(zxbcdt, conv_w, conv_b, d_inner, groups):
+def _conv_silu_f(zxbcdt, conv_w, conv_b, since, d_inner, groups):
     _count("conv_silu", "fwd")
     f, t = zxbcdt.shape[1:]
     lay = _conv_layout(conv_w, d_inner, f - d_inner - conv_w.shape[1], t,
                        groups)
     zp = _tile_zxbcdt(zxbcdt, lay)
-    x, bm, cm = _conv_fwd(zp, conv_w, conv_b, lay)
+    if since is not None:
+        _boundary_pass("conv_silu", "fwd")
+        since = _pad_steps(since, lay)
+    x, bm, cm = _conv_fwd(zp, conv_w, conv_b, lay, since)
     x = _untile_x(x, lay)
     return ((x, x, _untile_bc(bm, lay), _untile_bc(cm, lay),
-             zxbcdt[:, f - lay.h:], zp), (zp, conv_w, conv_b))
+             zxbcdt[:, f - lay.h:], zp), (zp, conv_w, conv_b, since))
 
 
 def _conv_silu_b(d_inner, groups, res, cts):
     _count("conv_silu", "bwd")
-    zp, conv_w, conv_b = res
+    zp, conv_w, conv_b, since = res
     *gxs, gb, gc, gdt, dbuf = cts
     lay = _conv_layout(conv_w, d_inner, gdt.shape[1], gb.shape[2], groups)
     gs = (tuple(_tile_x(g, lay) for g in gxs), (_tile_bc(gb, lay),),
           (_tile_bc(gc, lay),))
-    dbuf, dw, db = _conv_bwd(zp, gs, gdt, dbuf, conv_w, conv_b, lay)
+    if since is not None:
+        _boundary_pass("conv_silu", "bwd")
+    dbuf, dw, db = _conv_bwd(zp, gs, gdt, dbuf, conv_w, conv_b, lay, since)
     return (_untile_zxbcdt(dbuf, lay), dw.astype(conv_w.dtype),
-            db.astype(conv_b.dtype))
+            db.astype(conv_b.dtype), None)
 
 
 _conv_silu.defvjp(_conv_silu_f, _conv_silu_b)
@@ -529,11 +619,12 @@ def _gate_call(kernel, lay: _Layout, eps, bsz, n_tiles, out_shape):
     """One group of channels a chunk: ``n_tiles`` tile operands, then the
     two per-channel columns ``D`` and ``weight``; a result is a tile or
     such a column by its rank."""
-    tile = pl.BlockSpec((1, lay.gwp, lay.tile), lambda c, b, i: (b, c, i))
+    tile = pl.BlockSpec((1, lay.gwp, lay.gate_tile),
+                        lambda c, b, i: (b, c, i))
     col = pl.BlockSpec((lay.gwp, 1), lambda c, b, i: (c, 0))
     return pl.pallas_call(
         functools.partial(kernel, eps=eps, gw=lay.gw),
-        grid=(lay.groups, bsz, lay.t_p // lay.tile),
+        grid=(lay.groups, bsz, lay.t_p // lay.gate_tile),
         in_specs=[tile] * n_tiles + [col] * 2,
         out_specs=[tile if len(s.shape) == 3 else col for s in out_shape],
         out_shape=out_shape,
@@ -602,8 +693,20 @@ _gate_norm.defvjp(_gate_norm_f, _gate_norm_b)
 
 # -- the chain --------------------------------------------------------------------
 
+def _steps_since_start(segment_ids, most: int):
+    """``[B, 1, T]`` int32: how many steps before step ``t`` are of its
+    document, clipped to ``most`` (ids non-decreasing along a row, so
+    ``d[t] = d[t - j]`` says that all between are the document's too)."""
+    d = segment_ids
+    since = jnp.zeros(d.shape, jnp.int32)
+    for j in range(1, most + 1):
+        same = jnp.pad(d[:, j:] == d[:, :-j], [(0, 0), (j, 0)])
+        since = since + same.astype(jnp.int32)
+    return since[:, None, :]
+
+
 def mamba_chain(zxbcdt, conv_w, conv_b, D, weight, eps: float, groups: int,
-                scan):
+                scan, segment_ids=None):
     """What a Mamba-2 block does between its two projections, [B, d_inner,
     T] in ``zxbcdt``'s dtype::
 
@@ -614,7 +717,10 @@ def mamba_chain(zxbcdt, conv_w, conv_b, D, weight, eps: float, groups: int,
     ``zxbcdt`` [B, 2 d_inner + 2 n + h, T] is the input projection's
     output, ``[z | x B C | dt]`` along the channels, time minor. The conv
     is causal and depthwise along T (``conv_w`` [K, d_inner + 2 n], tap
-    ``K - 1`` on the current step; ``conv_b``); ``D`` [heads] weighs head
+    ``K - 1`` on the current step; ``conv_b``); with ``segment_ids`` ([B,
+    T] int32, non-decreasing along a row: packed documents) a tap counts
+    only where its step is of the current step's document, and the caller's
+    ``scan`` has to reset its state there itself; ``D`` [heads] weighs head
     ``i``'s channels ``[i P, (i + 1) P)`` of ``x``; the norm runs over
     ``groups`` equal parts of the channels (``weight`` [d_inner]). Conv,
     ``D``, ``weight`` and every statistic are float32; ``x``, ``B``, ``C``
@@ -625,7 +731,9 @@ def mamba_chain(zxbcdt, conv_w, conv_b, D, weight, eps: float, groups: int,
     ``x`` [B, d_inner, T], ``B`` and ``C`` [B, n, T], ``dt`` [B, h, T] the
     last ``h`` channels of ``zxbcdt`` as they are, ``y`` like ``x``. It
     may read any of its operands any number of times, or not at all."""
-    x, x_again, bm, cm, dt, zg = _conv_silu(zxbcdt, conv_w, conv_b,
+    since = (None if segment_ids is None else
+             _steps_since_start(segment_ids, conv_w.shape[0] - 1))
+    x, x_again, bm, cm, dt, zg = _conv_silu(zxbcdt, conv_w, conv_b, since,
                                             weight.shape[0], groups)
     return _gate_norm(scan(x, bm, cm, dt), x_again, zg, D, weight, eps,
                       groups)

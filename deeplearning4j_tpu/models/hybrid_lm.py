@@ -1,16 +1,34 @@
 """Hybrid state-space / sparse-expert / grouped-query language model: the
 ``nemotron_h`` backbone (NVIDIA Nemotron-H family; here with the keys of
-``nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16``'s ``config.json``),
-on the training path.
+``nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16``'s ``config.json``)
+and, by the same blocks, the dense ``granitemoehybrid`` layer
+(``ibm-granite/granite-4.0-h-micro``), on the training path.
 
 A stack of pre-norm residual blocks whose mixers follow a pattern string,
 one letter a block: ``M`` Mamba-2, ``E`` sparse experts beside a shared
-expert, ``*`` causal grouped-query attention.
+expert, ``*`` causal grouped-query attention, ``-`` a dense MLP. Four
+scalar multipliers (each 1 unless the configuration says otherwise: ``m_e``
+``embedding_multiplier``, ``m_r`` ``residual_multiplier``, ``m_a``
+``attention_multiplier``, ``m_l`` ``logits_scaling``):
 
-    h_0 = W_emb[ids]
-    h  <- h + Mixer_c(RMSNorm(h; w, eps))        for each letter c
-    logits = RMSNorm(h; w, eps) . W_head          (head not tied)
-    loss = mean over positions of the next-token cross entropy
+    h_0 = m_e * W_emb[ids]
+    h  <- h + m_r * Mixer_c(RMSNorm(h; w, eps))  for each letter c
+    logits = (RMSNorm(h; w, eps) . W_head) / m_l  (W_head = W_emb^T where
+                                                   ``tie_word_embeddings``)
+    loss = mean of the next-token cross entropy over the predicting
+           positions: t < T - 1 and, in a packed row, d[t + 1] = d[t]
+
+A granite layer (a mixer and a gated MLP, each with its norm) is two
+letters, ``M-`` or ``*-``; granite-4.0-h-micro's period of ten layers is
+``M-M-M-M-M-*-M-M-M-M-`` with m_e 12, m_r 0.22, m_a 1/64, m_l 8.
+
+**Packed rows.** ``batch["segment_ids"]`` (``[B, T]`` int32, non-decreasing
+along a row; absent: one document a row) is ``d``, the document a position
+belongs to. A document then computes what it would compute alone: the
+scan's state is reset at its first step (``S_t = [d_t = d_{t-1}] exp(dt_t
+A) S_{t-1} + ...``), the conv's tap ``x_{t-k}`` counts only where ``d[t-k]
+= d[t]``, key ``j`` is visible to query ``i`` iff ``j <= i`` and ``d[j] =
+d[i]``, and a document's last token predicts nothing.
 
 ``M`` (d_inner = heads x head_dim, G groups, state N):
     [z | xBC | dt] = u . W_in
@@ -32,9 +50,16 @@ expert, ``*`` causal grouped-query attention.
   updates for load balance, never a parameter) is zero and not carried.
 ``*``:
     q = u W_q (H heads), k, v = u W_k, u W_v (H_kv heads), causal
-    softmax(q k^T / sqrt(d)) v with query head i on KV head i // (H/H_kv),
-    then W_o. No bias, no rotary embedding (the family's modelling code
-    applies none). The core goes where `kernels.attention_dispatch` says.
+    softmax(m_a q k^T) v with query head i on KV head i // (H/H_kv), then
+    W_o; m_a = 1 / sqrt(d) unless ``attention_multiplier`` is given. No
+    bias, no rotary or other positional embedding (neither family's
+    modelling code applies one here). The core goes where
+    `kernels.attention_dispatch` says.
+``-``:
+    ``mlp_hidden_act`` "silu":  [a | b] = u W_in (E -> 2 F);
+                                out = W_out (silu(a) * b)      (granite)
+    ``mlp_hidden_act`` "relu2": out = W_out relu(u W_in)^2     (nemotron_h)
+  no bias, F = ``intermediate_size``.
 
 Stored types as `models.bert`: bfloat16 matrices, float32 norm weights,
 router, ``A_log``, ``dt_bias``, ``D`` and conv; float32 Adam moments.
@@ -64,7 +89,7 @@ from ..ops import moe
 from ..ops.ssm_scan import ssd_chunked_scan
 from . import _optim
 
-MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
+MAMBA, EXPERTS, ATTENTION, MLP = "M", "E", "*", "-"
 
 
 @dataclasses.dataclass
@@ -99,6 +124,17 @@ class HybridLMConfig:
     # [first_expert, first_expert + experts_held); None holds them all
     first_expert: int = 0
     experts_held: Optional[int] = None
+    # the dense MLP of a ``-`` block: "relu2" (up, relu^2, down) or
+    # "silu" (gated: silu of one half of the up projection times the other)
+    intermediate_size: int = 1856
+    mlp_hidden_act: str = "relu2"
+    # scalar multipliers (``granitemoehybrid``'s names); 1 and None trace
+    # nothing. ``attention_multiplier`` None: head_dim ** -0.5
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = False
     # depth of the whole model, for the residual-output scaling of the
     # initialisation (``rescale_prenorm_residual``); None: the pattern's
     rescale_layers: Optional[int] = None
@@ -122,8 +158,11 @@ class HybridLMConfig:
                 else self.experts_held)
 
     @staticmethod
-    def tiny(**kw) -> "HybridLMConfig":
-        """For tests: every kind of block, 8 of 16 experts held, top 2."""
+    def tiny(granite: bool = False, **kw) -> "HybridLMConfig":
+        """For tests: every kind of ``nemotron_h`` block, 8 of 16 experts
+        held, top 2; or, ``granite``, the dense granite layer's shape: a
+        mixer and a gated-SiLU MLP a layer, one group, the four
+        multipliers, the tied head."""
         base = dict(
             vocab_size=96, hidden_size=32, hybrid_override_pattern="MEM*E",
             mamba_num_heads=4, mamba_head_dim=8, ssm_state_size=16,
@@ -131,6 +170,13 @@ class HybridLMConfig:
             num_key_value_heads=2, head_dim=8, n_routed_experts=16,
             num_experts_per_tok=2, moe_intermediate_size=24,
             moe_shared_expert_intermediate_size=48, experts_held=8)
+        if granite:
+            base.update(
+                hybrid_override_pattern="M-*-M-", n_groups=1,
+                intermediate_size=48, mlp_hidden_act="silu",
+                embedding_multiplier=12.0, residual_multiplier=0.22,
+                attention_multiplier=0.125, logits_scaling=8.0,
+                tie_word_embeddings=True)
         base.update(kw)
         return HybridLMConfig(**base)
 
@@ -162,6 +208,13 @@ def _mixer_shapes(c: HybridLMConfig, kind: str) -> Dict[str, Tuple]:
         kv = c.num_key_value_heads * c.head_dim
         return {"wq": ((E, q), "matrix"), "wk": ((E, kv), "matrix"),
                 "wv": ((E, kv), "matrix"), "wo": ((q, E), "residual_out")}
+    if kind == MLP:
+        if c.mlp_hidden_act not in ("silu", "relu2"):
+            raise ValueError(f"unknown mlp_hidden_act {c.mlp_hidden_act!r}")
+        F = c.intermediate_size
+        wide = 2 * F if c.mlp_hidden_act == "silu" else F
+        return {"mlp_in": ((E, wide), "matrix"),
+                "mlp_out": ((F, E), "residual_out")}
     raise ValueError(f"unknown block kind {kind!r} in the layer pattern")
 
 
@@ -205,12 +258,15 @@ def init_params(key, config: HybridLMConfig) -> Dict:
                 jax.random.fold_in(jax.random.fold_in(k_blocks, i), j),
                 shape, how, c)
         blocks.append(block)
-    return {
+    params = {
         "embed": _draw(k_emb, (c.vocab_size, c.hidden_size), "matrix", c),
         "blocks": blocks,
         "final_norm": jnp.ones((c.hidden_size,), jnp.float32),
-        "head": _draw(k_head, (c.hidden_size, c.vocab_size), "matrix", c),
     }
+    if not c.tie_word_embeddings:
+        params["head"] = _draw(k_head, (c.hidden_size, c.vocab_size),
+                               "matrix", c)
+    return params
 
 
 def init_opt_state(params):
@@ -232,7 +288,7 @@ def _rms_norm(x, w, eps, groups: int = 1):
         return (x32.reshape(shape) * w).astype(x.dtype)
 
 
-def _mamba(p, u, c: HybridLMConfig):
+def _mamba(p, u, c: HybridLMConfig, segment_ids=None):
     B, T, _ = u.shape
     H, G = c.mamba_num_heads, c.n_groups
     # the fused chain holds time as the minor axis, [B, channels, T]
@@ -245,14 +301,16 @@ def _mamba(p, u, c: HybridLMConfig):
         x, Bm, Cm = (steps_major(a).reshape(B, T, n, -1)
                      for a, n in ((x, H), (Bm, G), (Cm, G)))
         with model_scope("ssm_scan"):
-            y = ssd_chunked_scan(x, dt, A, Bm, Cm, c.chunk_size)
+            y = ssd_chunked_scan(x, dt, A, Bm, Cm, c.chunk_size,
+                                 segment_ids)
         return steps_major(y.reshape(B, T, c.d_inner))
 
     with model_scope("ssm"):
         zxbcdt = jnp.einsum("bte,ef->bft", u, p["in_proj"])
-        # conv + silu, the scan, then (y + D x) silu(z) and its group norm
+        # conv + silu, the scan, then (y + D x) silu(z) and its group norm;
+        # a packed row's ids go to the conv and (above) to the scan
         y = mamba_chain(zxbcdt, p["conv_w"], p["conv_b"], p["D"],
-                        p["gate_norm"], c.norm_eps, G, scan)
+                        p["gate_norm"], c.norm_eps, G, scan, segment_ids)
         return jnp.einsum("bft,fe->bte", y, p["out_proj"])
 
 
@@ -277,48 +335,68 @@ def _experts(p, u, c: HybridLMConfig):
     return out.reshape(B, T, E), counts
 
 
-def _attention(p, u, c: HybridLMConfig, path: str):
+def _attention(p, u, c: HybridLMConfig, path: str, segment_ids=None):
     with model_scope("attn"):
         q = jnp.einsum("bte,ef->btf", u, p["wq"])
         k = jnp.einsum("bte,ef->btf", u, p["wk"])
         v = jnp.einsum("bte,ef->btf", u, p["wv"])
         with model_scope("attn_core"):
             ctx = attention(q, k, v, path=path, head_dim=c.head_dim,
-                            causal=True)
+                            causal=True, scale=c.attention_multiplier,
+                            segment_ids=segment_ids)
         return jnp.einsum("btf,fe->bte", ctx, p["wo"])
 
 
-def _block(p, h, kind: str, c: HybridLMConfig, path: str):
+def _mlp(p, u, c: HybridLMConfig):
+    with model_scope("mlp"):
+        h = jnp.einsum("bte,ef->btf", u, p["mlp_in"]).astype(jnp.float32)
+        if c.mlp_hidden_act == "silu":
+            a, b = jnp.split(h, 2, axis=-1)
+            h = jax.nn.silu(a) * b
+        else:
+            h = jnp.square(jax.nn.relu(h))
+        return jnp.einsum("btf,fe->bte", h.astype(u.dtype), p["mlp_out"])
+
+
+def _block(p, h, kind: str, c: HybridLMConfig, path: str, segment_ids=None):
     """One pre-norm residual block: (h, expert_tokens or None)."""
     u = _rms_norm(h, p["norm"], c.norm_eps)
     counts = None
     if kind == MAMBA:
-        out = _mamba(p, u, c)
+        out = _mamba(p, u, c, segment_ids)
     elif kind == EXPERTS:
         out, counts = _experts(p, u, c)
+    elif kind == MLP:
+        out = _mlp(p, u, c)
     else:
-        out = _attention(p, u, c, path)
+        out = _attention(p, u, c, path, segment_ids)
+    if c.residual_multiplier != 1.0:
+        out = out * jnp.asarray(c.residual_multiplier, out.dtype)
     return h + out, counts
 
 
 # -- forward, loss, step ------------------------------------------------------
 
 def hidden_states(params, input_ids, config: HybridLMConfig,
-                  remat: bool = False):
+                  remat: bool = False, segment_ids=None):
     """(final hidden states [B, T, E] before the last norm, expert_tokens
-    int32 [n_expert_blocks, held])."""
+    int32 [n_expert_blocks, held]). ``segment_ids`` [B, T] int32: the
+    documents of packed rows (the module's docstring)."""
     c = config
     if len(params["blocks"]) != len(c.pattern):
         raise ValueError(f"{len(params['blocks'])} blocks of parameters for "
                          f"the pattern {c.pattern!r}")
     with model_scope("embed"):
         h = jnp.take(params["embed"], input_ids, axis=0).astype(c.dtype)
+        if c.embedding_multiplier != 1.0:
+            h = h * jnp.asarray(c.embedding_multiplier, h.dtype)
     # asked once per trace, and only by a model that has attention blocks
     path = (attention_dispatch(input_ids.shape[1], head_dim=c.head_dim)
             if ATTENTION in c.pattern else None)
     counts = []
     for p, kind in zip(params["blocks"], c.pattern):
-        block = lambda p, h, kind=kind: _block(p, h, kind, c, path)
+        block = lambda p, h, kind=kind: _block(p, h, kind, c, path,
+                                               segment_ids)
         if remat:
             block = jax.checkpoint(block)
         h, n = block(p, h)
@@ -328,25 +406,46 @@ def hidden_states(params, input_ids, config: HybridLMConfig,
                else jnp.zeros((0, c.held), jnp.int32))
 
 
-def forward(params, input_ids, config: HybridLMConfig, remat: bool = False):
+def forward(params, input_ids, config: HybridLMConfig, remat: bool = False,
+            segment_ids=None):
     """Token ids [B, T] -> float32 logits [B, T, V] over the rows of the
     vocabulary held."""
-    h, _ = hidden_states(params, input_ids, config, remat)
+    h, _ = hidden_states(params, input_ids, config, remat, segment_ids)
     return _logits(params, h, config)
 
 
 def _logits(params, h, c: HybridLMConfig):
     h = _rms_norm(h, params["final_norm"], c.norm_eps)
     with model_scope("head"):
-        return jnp.einsum("bte,ev->btv", h, params["head"],
-                          preferred_element_type=jnp.float32)
+        if c.tie_word_embeddings:
+            logits = jnp.einsum("bte,ve->btv", h, params["embed"],
+                                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.einsum("bte,ev->btv", h, params["head"],
+                                preferred_element_type=jnp.float32)
+        if c.logits_scaling != 1.0:
+            logits = logits * (1.0 / c.logits_scaling)
+        return logits
 
 
 def lm_loss(params, batch, config: HybridLMConfig, remat: bool = False):
-    """(mean next-token cross entropy over the B x (T - 1) predicted
-    positions, expert_tokens). batch: ``input_ids`` [B, T]."""
+    """(mean next-token cross entropy over the predicting positions,
+    expert_tokens). batch: ``input_ids`` [B, T] and, for packed rows,
+    ``segment_ids`` [B, T] int32 (non-decreasing along a row). Without
+    them the B x (T - 1) positions before a row's last predict; with them
+    a document's last token predicts nothing either, and the mean is over
+    the positions that are left."""
+    loss, (counts, _) = _loss_terms(params, batch, config, remat)
+    return loss, counts
+
+
+def _loss_terms(params, batch, config: HybridLMConfig, remat: bool):
+    """`lm_loss` with each position's term beside it: (loss,
+    (expert_tokens, float32 [B, T] cross entropy of each predicting
+    position, 0 elsewhere))."""
     ids = batch["input_ids"]
-    h, counts = hidden_states(params, ids, config, remat)
+    seg = batch.get("segment_ids")
+    h, counts = hidden_states(params, ids, config, remat, seg)
     logits = _logits(params, h, config)
     with model_scope("loss"):
         B, T = ids.shape
@@ -355,8 +454,14 @@ def lm_loss(params, batch, config: HybridLMConfig, remat: bool = False):
         labels = jnp.roll(ids, -1, axis=1)
         lsm = jax.nn.log_softmax(logits, axis=-1)
         per_tok = -jnp.take_along_axis(lsm, labels[..., None], axis=-1)[..., 0]
-        per_tok = jnp.where(jnp.arange(T) < T - 1, per_tok, 0.0)
-        return jnp.sum(per_tok) / (B * (T - 1)), counts
+        predicts = jnp.arange(T) < T - 1
+        if seg is None:
+            per_tok = jnp.where(predicts, per_tok, 0.0)
+            return jnp.sum(per_tok) / (B * (T - 1)), (counts, per_tok)
+        predicts = predicts & (jnp.roll(seg, -1, axis=1) == seg)
+        per_tok = jnp.where(predicts, per_tok, 0.0)
+        return (jnp.sum(per_tok) / jnp.maximum(jnp.sum(predicts), 1),
+                (counts, per_tok))
 
 
 def make_train_step(config: HybridLMConfig, mesh=None,
@@ -364,7 +469,9 @@ def make_train_step(config: HybridLMConfig, mesh=None,
     """Single jitted train step, built as `bert.make_train_step`:
     ``(params, opt_state, batch, iteration) -> (params, opt_state, aux)``
     with params and state donated, ``aux = {"loss", "expert_tokens":
-    int32 [n_expert_blocks, held]}``. ``remat`` recomputes each block in
+    int32 [n_expert_blocks, held]}`` and, for a batch of packed rows
+    (``segment_ids``), ``"token_loss"``: float32 [B, T], each predicting
+    position's cross entropy (what a job logs by document). ``remat`` recomputes each block in
     the backward pass (`jax.checkpoint` around one block).
     ``learning_rate`` is a number or a schedule ``iteration -> rate`` (as
     `learning.Schedule`), traced into the step. Nothing here balances the
@@ -380,16 +487,19 @@ def make_train_step(config: HybridLMConfig, mesh=None,
     from ..runtime.inference import counted_jit
 
     def loss_fn(params, batch):
-        return lm_loss(params, batch, config, remat)
+        return _loss_terms(params, batch, config, remat)
 
     def step(params, opt_state, batch, iteration):
-        (loss, counts), grads = jax.value_and_grad(
+        (loss, (counts, per_tok)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, batch)
         rate = (learning_rate(iteration) if callable(learning_rate)
                 else learning_rate)
         new_params, opt_state = _optim.adam_apply(
             params, grads, opt_state, rate, iteration)
-        return new_params, opt_state, {"loss": loss, "expert_tokens": counts}
+        aux = {"loss": loss, "expert_tokens": counts}
+        if "segment_ids" in batch:
+            aux["token_loss"] = per_tok
+        return new_params, opt_state, aux
 
     return counted_jit(step, tag=f"hybrid_lm_train:{id(step)}",
                        donate_argnums=(0, 1))
@@ -431,3 +541,26 @@ def observe(aux, config: HybridLMConfig, tokens: int) -> float:
                   "Assignments of the fullest held expert in the last "
                   "observed step").set(int(counts.max()))
     return float(loss)
+
+
+def observe_packed(lengths) -> None:
+    """Feed the packing counters from a step's own rows, on the host, with
+    no device read: ``lengths`` is one sequence of document lengths a row
+    (what the batch's ``segment_ids`` were made from).
+    ``dl4j_packed_rows_total``, ``dl4j_packed_documents_total`` and
+    ``dl4j_packed_attended_pairs_total``: the (query, key) pairs of one
+    head's causal attention inside documents, a row's sum of
+    ``len (len + 1) / 2``."""
+    from ..common.environment import environment
+    reg = environment().metrics()
+    reg.counter("dl4j_packed_rows_total",
+                "Packed rows trained on").inc(len(lengths))
+    reg.counter("dl4j_packed_documents_total",
+                "Documents (and the pieces a row's end cut them into) in "
+                "the packed rows trained on").inc(
+                    sum(len(row) for row in lengths))
+    reg.counter("dl4j_packed_attended_pairs_total",
+                "Same-document causal (query, key) pairs of one attention "
+                "head over the packed rows trained on").inc(
+                    sum(int(n) * (int(n) + 1) // 2
+                        for row in lengths for n in row))

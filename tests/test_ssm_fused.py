@@ -26,14 +26,23 @@ def steps_major(a):
     return jnp.swapaxes(a, 1, 2)
 
 
-def plain_chain(zxbcdt, conv_w, conv_b, D, weight, scan, d_inner, G):
+def plain_chain(zxbcdt, conv_w, conv_b, D, weight, scan, d_inner, G,
+                segment_ids=None):
     """`_mamba`'s lines between ``in_proj`` and ``out_proj`` as they were
-    before the fused operations: [B, T, F] in, [B, T, d_inner] out."""
+    before the fused operations: [B, T, F] in, [B, T, d_inner] out. With
+    ``segment_ids`` [B, T] a tap counts only inside its step's document."""
     B, T, _ = zxbcdt.shape
     H, K, conv_dim = D.shape[0], conv_w.shape[0], conv_w.shape[1]
     z, xBC, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
     padded = jnp.pad(xBC.astype(F32), [(0, 0), (K - 1, 0), (0, 0)])
-    conv = sum(padded[:, k:k + T] * conv_w[k] for k in range(K))
+    if segment_ids is None:
+        conv = sum(padded[:, k:k + T] * conv_w[k] for k in range(K))
+    else:
+        before = jnp.pad(segment_ids, [(0, 0), (K - 1, 0)],
+                         constant_values=-1)
+        conv = sum(padded[:, k:k + T] * conv_w[k]
+                   * (before[:, k:k + T] == segment_ids)[..., None]
+                   for k in range(K))
     xBC = jax.nn.silu(conv + conv_b).astype(zxbcdt.dtype)
     n = (conv_dim - d_inner) // 2
     x, Bm, Cm = jnp.split(xBC, [d_inner, d_inner + n], axis=-1)
@@ -46,10 +55,12 @@ def plain_chain(zxbcdt, conv_w, conv_b, D, weight, scan, d_inner, G):
     return (y32.reshape(B, T, d_inner) * weight).astype(zxbcdt.dtype)
 
 
-def fused_chain(zxbcdt, conv_w, conv_b, D, weight, scan, d_inner, G):
+def fused_chain(zxbcdt, conv_w, conv_b, D, weight, scan, d_inner, G,
+                segment_ids=None):
     return steps_major(ssm_fused.mamba_chain(
         steps_major(zxbcdt), conv_w, conv_b, D, weight, EPS, G,
-        lambda *a: steps_major(scan(*map(steps_major, a)))))
+        lambda *a: steps_major(scan(*map(steps_major, a))),
+        segment_ids=segment_ids))
 
 
 # (B, T, d_inner, n, heads, groups, time tile or None for the module's)
@@ -134,10 +145,10 @@ def test_every_gradient_matches_the_plain_chain(case, dtype, tiles):
     assert_same_gradients(args, static, ct, dtype)
 
 
-def assert_same_gradients(args, static, ct, dtype):
+def assert_same_gradients(args, static, ct, dtype, **packed):
     def grads(chain):
         return jax.grad(lambda *a: jnp.sum(
-            chain(*a, *static).astype(F32) * ct),
+            chain(*a, *static, **packed).astype(F32) * ct),
             argnums=(0, 1, 2, 3, 4))(*args)
 
     want, got = grads(plain_chain), grads(fused_chain)
@@ -149,6 +160,74 @@ def assert_same_gradients(args, static, ct, dtype):
         tol = tolerance(dtype)
         np.testing.assert_allclose(g / scale, w / scale, err_msg=name,
                                    rtol=tol["rtol"], atol=tol["atol"])
+
+
+# -- packed rows: the conv stops at a document's first step ---------------------
+
+# case -> per row, the steps at which a document starts (beside step 0)
+PACKED = {
+    # boundaries around the tile edges 128 and 256: the step before, on and
+    # after; a document of one step; one that starts on the row's last step
+    "ragged-tail": ([5, 127, 128, 129, 256, 257],),
+    "boundary-in-halo": ([128, 256, 257], [1, 2, 3]),
+    "short-odd-widths": ([2, 3],),
+    "tiny-config": ([10, 11], [36]),
+}
+
+
+def segment_ids(case):
+    B, T = CASES[case][:2]
+    rows = []
+    for starts in PACKED[case]:
+        first = np.zeros(T, np.int32)
+        first[starts] = 1
+        rows.append(np.cumsum(first, dtype=np.int32))
+    assert len(rows) == B
+    return jnp.asarray(np.stack(rows))
+
+
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(PACKED))
+def test_packed_values_and_gradients_match_the_plain_chain(case, dtype,
+                                                           tiles):
+    """The interpreted Pallas forward and backward with document
+    boundaries against the ``jnp`` chain that compares the ids tap by
+    tap."""
+    args, static, tile, ct = operands(case, dtype)
+    tiles(tile)
+    seg = segment_ids(case)
+    want = plain_chain(*args, *static, segment_ids=seg)
+    got = fused_chain(*args, *static, segment_ids=seg)
+    np.testing.assert_allclose(got.astype(F32), want.astype(F32),
+                               **tolerance(dtype))
+    # the boundaries are felt
+    apart = fused_chain(*args, *static)
+    assert float(jnp.abs(apart.astype(F32) - got.astype(F32)).max()) > 1e-2
+    assert_same_gradients(args, static, ct, dtype, segment_ids=seg)
+
+
+def test_each_document_convolves_as_it_does_alone(tiles):
+    args, (scan, d_inner, G), tile, _ = operands("ragged-tail", F32)
+    tiles(tile)
+    seg = segment_ids("ragged-tail")
+    # a scan that mixes nothing along time: the chain is then step-local
+    # but for the conv
+    local = lambda x, Bm, Cm, dt: x * 0.5
+    packed = fused_chain(*args, local, d_inner, G, segment_ids=seg)
+    cuts = [0] + PACKED["ragged-tail"][0] + [CASES["ragged-tail"][1]]
+    for lo, hi in zip(cuts[:-1], cuts[1:]):
+        alone = fused_chain(args[0][:, lo:hi], *args[1:], local, d_inner, G)
+        np.testing.assert_allclose(packed[:, lo:hi], alone, rtol=2e-5,
+                                   atol=2e-5)
+
+
+def test_one_document_a_row_said_or_unsaid(tiles):
+    args, static, tile, _ = operands("ragged-tail", F32)
+    tiles(tile)
+    zeros = jnp.zeros(args[0].shape[:2], jnp.int32)
+    np.testing.assert_array_equal(
+        fused_chain(*args, *static, segment_ids=zeros),
+        fused_chain(*args, *static))
 
 
 @pytest.mark.parametrize("reads", ["x", "x-twice", "B-C", "dt", "nothing"])
@@ -222,7 +301,7 @@ def test_the_model_holds_no_second_copy_of_the_chain():
                 "os.environ.get", "jax.default_backend"} & set(calls)
     assert not [n for n in ast.walk(tree)
                 if isinstance(n, (ast.If, ast.IfExp, ast.Try))]
-    assert "jax.nn.silu" not in inspect.getsource(hybrid_lm)
+    assert "jax.nn.silu" not in inspect.getsource(hybrid_lm._mamba)
     # the pre-norms' and the final norm's, as it was
     assert list(inspect.signature(hybrid_lm._rms_norm).parameters) == [
         "x", "w", "eps", "groups"]

@@ -116,6 +116,76 @@ def test_hybrid_causal_attention_compiles_for_v5e(shape, grad, one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < B * H * S * S
 
 
+# (B, H, S, D): the packed granite cell's attention layer (32 query heads of
+# 64, the 8 KV heads repeated, scores times 1/64), and a one-tile length
+PACKED_SHAPES = [(1, 32, 16384, 64), (2, 4, 512, 64)]
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("shape", PACKED_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_packed_causal_attention_compiles_for_v5e(shape, grad, one_chip,
+                                                  compiled_kernels):
+    """The kernels with document ids: the ids' column and row blocks (one
+    lane wide, one sublane high) beside the tiles, shared by a batch row's
+    heads through the block index."""
+    B, H, S, D = shape
+    spec = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    ids = _sds((B, S), jnp.int32, one_chip)
+    core = lambda q, k, v, seg: fa.flash_attention(
+        q, k, v, causal=True, scale=1 / 64, segment_ids=seg)
+    if grad:
+        fn = jax.grad(lambda q, k, v, seg: jnp.sum(
+            core(q, k, v, seg).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    else:
+        fn = core
+    compiled, text = _compile(fn, spec, spec, spec, ids)
+    one_tile = S <= 512
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        ((2 if one_tile else 3) if grad else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < B * H * S * S
+
+
+# the packed granite cell's Mamba-2 layers: B = 1, T = 16,384, d_inner
+# 4,096 in ONE group of state 128 (a gate tile holds the whole group),
+# 64 heads; zxbcdt is [1, 8512, 16384]
+GRANITE = dict(B=1, T=16384, d_inner=4096, n=128, heads=64, groups=1)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("op", ["conv_silu", "gate_norm"])
+def test_packed_mamba_ops_compile_for_v5e(op, grad, one_chip,
+                                          compiled_kernels):
+    """`ssm_fused.mamba_chain`'s halves at the granite cell's shapes: the
+    conv with the steps-since-the-document's-start operand (forward and
+    the in-place backward), the gate norm over one group of 4,096
+    channels."""
+    m = GRANITE
+    conv_dim = m["d_inner"] + 2 * m["n"]
+    bf = lambda *shape: _sds(shape, jnp.bfloat16, one_chip)
+    f32 = lambda *shape: _sds(shape, jnp.float32, one_chip)
+    zxbcdt = bf(m["B"], m["d_inner"] + conv_dim + m["heads"], m["T"])
+    x = bf(m["B"], m["d_inner"], m["T"])
+    if op == "conv_silu":
+        specs = [zxbcdt, f32(4, conv_dim), f32(conv_dim),
+                 _sds((m["B"], 1, m["T"]), jnp.int32, one_chip)]
+        fn = lambda z, w, b, since: sf._conv_silu(
+            z, w, b, since, m["d_inner"], m["groups"])[1:]
+        calls, wrt = 3, (0, 1, 2)
+    else:
+        specs = [x, x, zxbcdt, f32(m["heads"]), f32(m["d_inner"])]
+        fn = lambda y, x, zg, D, w: (sf._gate_norm(y, x, zg, D, w, 1e-5,
+                                                   m["groups"]),)
+        calls, wrt = 1, (0, 1, 2, 3, 4)
+    if grad:
+        fwd, calls = fn, 2 * calls
+        fn = jax.grad(lambda *a: sum(
+            jnp.sum(o.astype(jnp.float32) ** 2) for o in fwd(*a)),
+            argnums=wrt)
+    _, text = _compile(fn, *specs)
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+
+
 # the hybrid cell's Mamba-2 blocks: B = 1, T = 8,192, d_inner 4,096,
 # 8 groups x state 128, 64 heads; zxbcdt is [1, 10304, 8192], time minor
 MAMBA = dict(B=1, T=8192, d_inner=4096, n=1024, heads=64, groups=8)
@@ -145,7 +215,7 @@ def test_fused_mamba_ops_compile_for_v5e(op, grad, one_chip,
     m, sp = MAMBA, _mamba_specs(one_chip)
     if op == "conv_silu":
         specs = [sp["zxbcdt"], sp["conv_w"], sp["conv_b"]]
-        fn = lambda z, w, b: sf._conv_silu(z, w, b, m["d_inner"],
+        fn = lambda z, w, b: sf._conv_silu(z, w, b, None, m["d_inner"],
                                           m["groups"])[1:]
         calls = 3
     else:
