@@ -27,7 +27,8 @@ from . import Finding, Module, PACKAGE_ROOT
 #: attention|paged_decode|dequant_matmul — and the streaming flash pass,
 #: fwd|dq|dkv, on ``dl4j_flash_tiles_total``, whose kind is
 #: computed|skipped; op is the fused Mamba-2 operation, conv_silu|gate_norm,
-#: on ``dl4j_ssm_fused_calls_total``, whose kind is fwd|bwd), a
+#: on ``dl4j_ssm_fused_calls_total``, whose kind is fwd|bwd, as is
+#: ``dl4j_ssm_scan_passes_total``'s), a
 #: deploy-bounded identity
 #: (model/version/bucket/worker/name/replica — replica is a fleet
 #: member's URL, bounded by the router's configured replica set;

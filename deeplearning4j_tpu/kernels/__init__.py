@@ -28,10 +28,16 @@ cover the known gaps for the flagship workloads:
   each result written once in the stored dtype, float32 in registers,
   hand-written backwards, time as the minor axis. No dispatch: the model
   calls it on every backend.
+- `ssd_scan`: the scan between those two operations, Mamba-2's chunked
+  (SSD) recurrence, as a forward and a hand-written backward kernel that
+  walk a row's chunks with the ``[Q, Q]`` decay, score and weight tiles
+  and the carried state in VMEM; `ops.ssm_scan.ssd_chunked_scan` is its
+  entry. No dispatch either.
 
 Packed rows (several documents in a sequence, ``segment_ids``): the
-attention core, the flash kernels and `mamba_chain`'s conv take the ids
-and keep each document to itself; `boundary_pass` counts those passes.
+attention core, the flash kernels, `mamba_chain`'s conv and the scan take
+the ids and keep each document to itself; `boundary_pass` counts those
+passes.
 
 A fused vocab-tiled softmax-xent kernel lived here through round 3 and was
 deleted after honest tuning kept it behind XLA at the BERT headline shape
@@ -87,7 +93,8 @@ def kernel_dispatch(kernel: str, path: str, reason: str = "") -> str:
 def boundary_pass(kernel: str, kind: str) -> None:
     """Tick ``dl4j_boundary_kernel_passes_total{kernel,kind}``: one traced
     pass of a kernel that takes document boundaries (packed rows): the fused
-    conv + SiLU of `ssm_fused` (kernel "conv_silu", kind fwd | bwd) and the
+    conv + SiLU of `ssm_fused` (kernel "conv_silu", kind fwd | bwd), the
+    chunked scan (kernel "ssm_scan", kind fwd | bwd) and the
     flash-attention kernels (kernel "flash", kind fwd | dq | dkv |
     one_tile_fwd | one_tile_bwd). Beside ``dl4j_flash_tiles_total`` and
     ``dl4j_ssm_fused_calls_total``, which count every pass."""

@@ -38,9 +38,13 @@ d[i]``, and a document's last token predicts nothing.
                y_t = S_t . C_t + D x_t              (head i, group i // (H/G))
     out = W_out . GroupRMSNorm(y * silu(z))         (gate first, then norm
                                                      over d_inner / G, weight)
-  the scan in the chunked (SSD) form, `ops.ssm_scan.ssd_chunked_scan`;
-  what lies between it and the two projections (conv, silu, skip, gate,
-  group norm) in `kernels.ssm_fused.mamba_chain`'s two fused operations.
+  the scan in the chunked (SSD) form, `ops.ssm_scan.ssd_chunked_scan`: a
+  Pallas kernel pair (`kernels.ssd_scan`) that keeps a chunk's decay and
+  weight tiles and the carried state in VMEM; what lies between it and
+  the two projections (conv, silu, skip, gate, group norm) in
+  `kernels.ssm_fused.mamba_chain`'s two fused operations. All three hold
+  time as the minor axis, ``[B, channels, T]``, from the input projection's
+  result to the output projection's operand.
 ``E``:
     s = sigmoid(u_f32 . W_r);  top-k of s;  g_k = scale * s_k / (sum + 1e-20)
     out = sum_k g_k W2_{e_k} relu(W1_{e_k} u)^2  +  V2 relu(V1 u)^2
@@ -291,19 +295,18 @@ def _rms_norm(x, w, eps, groups: int = 1):
 def _mamba(p, u, c: HybridLMConfig, segment_ids=None):
     B, T, _ = u.shape
     H, G = c.mamba_num_heads, c.n_groups
-    # the fused chain holds time as the minor axis, [B, channels, T]
-    steps_major = lambda a: jnp.swapaxes(a, 1, 2)
 
+    # the fused chain and the scan hold time as the minor axis, [B,
+    # channels, T]: the scan takes the chain's operands as they come
     def scan(x, Bm, Cm, dt):
-        dt = jax.nn.softplus(steps_major(dt).astype(jnp.float32)
-                             + p["dt_bias"])
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"][:, None])
         A = -jnp.exp(p["A_log"])
-        x, Bm, Cm = (steps_major(a).reshape(B, T, n, -1)
+        x, Bm, Cm = (a.reshape(B, n, -1, T)
                      for a, n in ((x, H), (Bm, G), (Cm, G)))
         with model_scope("ssm_scan"):
             y = ssd_chunked_scan(x, dt, A, Bm, Cm, c.chunk_size,
                                  segment_ids)
-        return steps_major(y.reshape(B, T, c.d_inner))
+        return y.reshape(B, c.d_inner, T)
 
     with model_scope("ssm"):
         zxbcdt = jnp.einsum("bte,ef->bft", u, p["in_proj"])

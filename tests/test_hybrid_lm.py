@@ -19,12 +19,20 @@ if ROOT not in sys.path:
 from benchmark.reference import nemotron_h as ref  # noqa: E402
 from deeplearning4j_tpu.models import hybrid_lm  # noqa: E402
 from deeplearning4j_tpu.ops import moe  # noqa: E402
-from deeplearning4j_tpu.ops.ssm_scan import ssd_chunked_scan  # noqa: E402
+from deeplearning4j_tpu.ops import ssm_scan  # noqa: E402
 
 with open(os.path.join(ROOT, "benchmark/tests/configs/nemotron-tiny.json")) as f:
     CFG = json.load(f)
 D = ref.dims(CFG)
 F32 = jnp.float32
+
+
+def ssd_chunked_scan(x, dt, A, B, C, chunk, segment_ids=None):
+    """The scan on the references' steps-major operands (the entry's are
+    time minor)."""
+    tm = lambda v: jnp.moveaxis(v, 1, -1)
+    return jnp.moveaxis(ssm_scan.ssd_chunked_scan(
+        tm(x), tm(dt), A, tm(B), tm(C), chunk, segment_ids), -1, 1)
 
 
 def program_config(dtype=F32, **kw):

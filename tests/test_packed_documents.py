@@ -21,12 +21,20 @@ from benchmark.reference import granite_hybrid as ref  # noqa: E402
 from deeplearning4j_tpu.common.metrics import registry  # noqa: E402
 from deeplearning4j_tpu.kernels import attention, flash_attention  # noqa: E402
 from deeplearning4j_tpu.models import hybrid_lm  # noqa: E402
-from deeplearning4j_tpu.ops.ssm_scan import ssd_chunked_scan  # noqa: E402
+from deeplearning4j_tpu.ops import ssm_scan  # noqa: E402
 
 with open(os.path.join(ROOT, "benchmark/tests/configs/granite-tiny.json")) as f:
     CFG = json.load(f)
 D = ref.dims(CFG)
 F32 = jnp.float32
+
+
+def ssd_chunked_scan(x, dt, A, B, C, chunk, segment_ids=None):
+    """The scan on the references' steps-major operands (the entry's are
+    time minor)."""
+    tm = lambda v: jnp.moveaxis(v, 1, -1)
+    return jnp.moveaxis(ssm_scan.ssd_chunked_scan(
+        tm(x), tm(dt), A, tm(B), tm(C), chunk, segment_ids), -1, 1)
 
 
 def segments(t, *starts):
@@ -113,14 +121,15 @@ def test_each_document_scans_as_it_does_alone():
 
 
 def test_scan_without_ids_is_the_scan_it_was():
-    """One document a row, said or unsaid, to the bit; and no mask is
-    traced where no ids are given."""
+    """One document a row, said or unsaid, to the bit; and no boundary
+    operand or mask is traced where no ids are given."""
     args = scan_inputs(40)
     plain = ssd_chunked_scan(*args, 8)
     one = ssd_chunked_scan(*args, 8, segment_ids=jnp.zeros((2, 40), jnp.int32))
     np.testing.assert_array_equal(plain, one)
     text = str(jax.make_jaxpr(lambda *a: ssd_chunked_scan(*a, 8))(*args))
-    assert " eq " not in text and "i32[2" not in text
+    assert "i32[2" not in text and "= ge " not in text
+    assert "= gt " not in text
 
 
 # -- the attention core -------------------------------------------------------
@@ -458,6 +467,7 @@ def test_a_packed_step_counts_its_boundary_kernel_passes(flash_everywhere):
     after = _boundary_passes()
     got = {k: after[k] - before.get(k, 0) for k in after}
     assert got[("conv_silu", "fwd")] == 4 and got[("conv_silu", "bwd")] == 2
+    assert got[("ssm_scan", "fwd")] == 4 and got[("ssm_scan", "bwd")] == 2
     assert got[("flash", "one_tile_fwd")] == 2
     assert got[("flash", "one_tile_bwd")] == 1
 

@@ -25,6 +25,7 @@ from jax.sharding import (NamedSharding, PartitionSpec as P,
 fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
 pfd = importlib.import_module("deeplearning4j_tpu.kernels.paged_flash_decode")
 sf = importlib.import_module("deeplearning4j_tpu.kernels.ssm_fused")
+ssd = importlib.import_module("deeplearning4j_tpu.kernels.ssd_scan")
 
 
 @pytest.fixture(scope="module")
@@ -67,7 +68,7 @@ def no_persistent_cache():
 def compiled_kernels(monkeypatch, no_persistent_cache):
     """Steer the kernels to compile (not interpret) though the backend
     here is the CPU."""
-    for mod in (fa, pfd, sf):  # each binds its own name for the switch
+    for mod in (fa, pfd, sf, ssd):  # each binds its own name for the switch
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -186,6 +187,44 @@ def test_packed_mamba_ops_compile_for_v5e(op, grad, one_chip,
     assert text.count('custom_call_target="tpu_custom_call"') == calls
 
 
+# (B, T, heads, head_dim, groups, state, chunk, packed): the scan of the
+# nemotron cell's Mamba-2 blocks and of the packed granite cell's layers
+SCAN_SHAPES = {"nemotron": (1, 8192, 64, 64, 8, 128, 128, False),
+               "granite-packed": (1, 16384, 64, 64, 1, 128, 256, True)}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("cell", sorted(SCAN_SHAPES))
+def test_ssd_scan_compiles_for_v5e(cell, grad, one_chip, compiled_kernels):
+    """The chunked scan's kernels at the two cells' shapes: a grid step
+    holds one chunk of one group's heads (8 of 64 on nemotron, all 64 on
+    granite: 2 MB operand blocks, the float32 state and the step's
+    scratch) inside the VMEM limit the call asks for; transposed-operand
+    products, a head's dynamic rows and the one-hot column accepted by
+    Mosaic; and no temporary of [chunks, heads, Q, Q] elements."""
+    B, T, H, P, G, N, Q, packed = SCAN_SHAPES[cell]
+    bf = lambda *shape: _sds(shape, jnp.bfloat16, one_chip)
+    specs = [bf(B, H, P, T), _sds((B, H, T), jnp.float32, one_chip),
+             _sds((H,), jnp.float32, one_chip), bf(B, G, N, T),
+             bf(B, G, N, T)]
+    if packed:
+        specs.append(_sds((B, T), jnp.int32, one_chip))
+    fn = lambda x, dt, A, Bm, Cm, seg=None: ssd.ssd_chunked_scan(
+        x, dt, A, Bm, Cm, Q, seg)
+    if grad:
+        fwd = fn
+        fn = jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32) ** 2),
+                      argnums=(0, 1, 2, 3, 4))
+    compiled, text = _compile(fn, *specs)
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (2 if grad else 1)
+    # forward: nothing but the running sums; backward: the entering states
+    # (float32, 134 MB) and d a. The einsum form's float32 [chunks, heads,
+    # Q, Q] tensors were 268 MB (nemotron) and 1.07 GB (granite) each
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        3e8 if grad else 2e7)
+
+
 # the hybrid cell's Mamba-2 blocks: B = 1, T = 8,192, d_inner 4,096,
 # 8 groups x state 128, 64 heads; zxbcdt is [1, 10304, 8192], time minor
 MAMBA = dict(B=1, T=8192, d_inner=4096, n=1024, heads=64, groups=8)
@@ -239,9 +278,10 @@ def test_mamba_block_gradient_keeps_the_chain_out_of_f32_hbm(
         one_chip, compiled_kernels):
     """A whole Mamba-2 block's ``jax.grad`` at the cell's shapes: no
     top-level instruction of the module yields the chain's float32
-    tensors ([T, conv_dim] or [T, d_inner], either axis minor). The scan
-    keeps float32 tensors of d_inner x T elements of its own (its
-    ``dt * x``), under its own scope and shapes."""
+    tensors ([T, conv_dim] or [T, d_inner], either axis minor), none a
+    float32 [chunks, heads, Q, Q] tensor of the scan's (they stay in its
+    kernels' VMEM), and under the scan's scope no transpose or copy of a
+    [T, d_inner] operand."""
     import re
     from deeplearning4j_tpu.models import hybrid_lm
     m = MAMBA
@@ -256,8 +296,11 @@ def test_mamba_block_gradient_keeps_the_chain_out_of_f32_hbm(
     _, text = _compile(jax.grad(lambda p, u: jnp.sum(
         hybrid_lm._mamba(p, u, c).astype(jnp.float32) ** 2),
         argnums=(0, 1)), p, u)
-    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    # the chain's 8 calls and the scan's 2 (its forward that keeps the
+    # entering states, its backward)
+    assert text.count('custom_call_target="tpu_custom_call"') == 10
     T, chain = m["T"], (c.conv_dim, c.d_inner)
+    Q = c.chunk_size
     for line in text.split("ENTRY")[1].splitlines():
         made = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) [\w\-]+\(", line)
         for dims in re.findall(r"f32\[([\d,]+)\]", made.group(1) if made
@@ -266,11 +309,23 @@ def test_mamba_block_gradient_keeps_the_chain_out_of_f32_hbm(
             assert not (len(dims) == 3 and sorted(dims[1:]) in
                         [sorted((T, w)) for w in chain]), line[:200]
             scopes = re.findall(r"dl4j\.(\w+)", line)
+            size = 1
+            for d in dims:
+                size *= d
             if scopes[-1:] in (["ssm"], ["ln"]):
-                size = 1
-                for d in dims:
-                    size *= d
                 assert size not in [T * w for w in chain], line[:200]
+            # the scan's decay, score and weight tiles stay in VMEM
+            assert size != T // Q * m["heads"] * Q * Q, line[:200]
+        # and the scan takes the chain's operands as they lie: nothing of
+        # T x d_inner elements is transposed or copied under its scope
+        if re.findall(r"dl4j\.(\w+)", line)[-1:] == ["ssm_scan"] and made:
+            moved = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                             r"(transpose|copy)\(", line)
+            if moved:
+                size = 1
+                for d in moved.group(1).split(","):
+                    size *= int(d)
+                assert size < T * c.d_inner, line[:200]
 
 
 def test_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch,
