@@ -16,6 +16,7 @@ sequence of 8,192, 32 heads of 128).
     chiprun -- python attn_sweep.py                    # the table
     chiprun -- python attn_sweep.py --only 512x64x0    # one row, 24 layers
     chiprun -- python attn_sweep.py --only 8192x128x1 --tokens 8192 --hidden 4096 --layers 2
+    chiprun -- python attn_sweep.py --only 8192x192x1 --v-head-dim 128 --tokens 16384 --hidden 4096 --layers 2
     python attn_sweep.py --kernel _parent/deeplearning4j_tpu/kernels/flash_attention.py
 
 Prints one JSON line per row and writes them to
@@ -52,23 +53,30 @@ def _load_flash(path):
 
 
 def time_core(core, T, D, causal, layers, reps, seed, packed=False,
-              tokens=TOKENS, hidden=HIDDEN):
+              tokens=TOKENS, hidden=HIDDEN, v_dim=None):
     """(forward ms, forward+backward ms) per layer. ``packed``: q/k/v are
-    [B, T, H*D] as the model's flash path keeps them, else [B, T, H, D]."""
+    [B, T, H*D] as the model's flash path keeps them, else [B, T, H, D].
+    ``v_dim``: the values' width where it is not D (latent attention; H is
+    then ``hidden // v_dim``, operands [B, T, H, .]); a layer's output
+    takes the place of the first ``v_dim`` columns of the next query."""
     import jax
     import jax.numpy as jnp
-    B, H = tokens // T, hidden // D
+    B, H = tokens // T, hidden // (v_dim or D)
     keys = jax.random.split(jax.random.key(seed), 4)
     shape = (B, T, H * D) if packed else (B, T, H, D)
     q, k, v, ct = (jax.random.normal(kk, shape, jnp.float32)
                    .astype(DTYPE) for kk in keys)
+    if v_dim:
+        v, ct = v[..., :v_dim], ct[..., :v_dim]
     mask = None if causal else jnp.ones((B, T), jnp.int32)
 
     def chain(q, k, v):
         x = q
         for _ in range(layers):
             x = core(x, k, v, mask, causal)
-        return x
+            if v_dim:
+                x = jnp.concatenate([x, q[..., v_dim:]], axis=-1)
+        return x[..., :v_dim] if v_dim else x
 
     fwd = jax.jit(chain)
     both = jax.jit(jax.grad(
@@ -102,6 +110,10 @@ def main(argv=None):
                     help="B x T of every row")
     ap.add_argument("--hidden", type=int, default=HIDDEN,
                     help="H x D of every row")
+    ap.add_argument("--v-head-dim", type=int, default=None,
+                    help="the values' width where it is not D (latent "
+                         "attention: --only 8192x192x1 --v-head-dim 128 "
+                         "--tokens 16384 --hidden 4096); flash only")
     ap.add_argument("--out", default="chiprun_out/attn_sweep.jsonl")
     args = ap.parse_args(argv)
 
@@ -114,7 +126,9 @@ def main(argv=None):
     flash = _load_flash(args.kernel)
     # the tree's kernel takes the packed layout; the parent's has no such
     # argument and gets [B, T, H, D]
-    packed = "head_dim" in inspect.signature(flash).parameters
+    packed = ("head_dim" in inspect.signature(flash).parameters
+              and not args.v_head_dim)
+    wide = {"v_head_dim": args.v_head_dim} if args.v_head_dim else {}
 
     if args.only:
         rows = [tuple(int(x) for x in r.split("x"))
@@ -125,21 +139,24 @@ def main(argv=None):
     with open(args.out, "a") as f:
         for T, D, causal in rows:
             rec = {"T": T, "head_dim": D, "causal": bool(causal),
-                   "B": args.tokens // T, "H": args.hidden // D,
+                   "B": args.tokens // T,
+                   "H": args.hidden // (args.v_head_dim or D), **wide,
                    "layers": args.layers, "kernel": args.kernel or "tree",
                    "device_kind": dev.device_kind}
             def flash_core(q, k, v, mask, causal):
-                return flash(q, k, v, mask=mask, causal=causal,
+                return flash(q, k, v, mask=mask, causal=causal, **wide,
                              **({"head_dim": D} if packed else {}))
 
             for name, core in (("xla", xla_core), ("flash", flash_core)):
-                if name not in args.paths.split(","):
+                if name not in args.paths.split(",") or (
+                        wide and name == "xla"):
                     continue
                 try:
                     fw, fb = time_core(core, T, D, bool(causal),
                                        args.layers, args.reps, args.seed,
                                        packed and name == "flash",
-                                       args.tokens, args.hidden)
+                                       args.tokens, args.hidden,
+                                       args.v_head_dim)
                     rec[f"{name}_fwd_ms"] = round(fw, 4)
                     rec[f"{name}_fwd_bwd_ms"] = round(fb, 4)
                 except Exception as e:  # an OOM at long T is a reading too

@@ -205,6 +205,16 @@ def _flash_rule(seq_len, head_dim):
 
          8192  128  yes    |    out of memory |      5.236   19.188 | 12.791 39.615
 
+    And latent attention's core (``models.hybrid_lm``'s ``L`` blocks: two
+    rows of 8,192, 32 heads whose queries and keys are 192 wide and whose
+    values are 128 wide, B·T = 16,384; ``attn_sweep.py --only 8192x192x1
+    --v-head-dim 128 --tokens 16384 --hidden 4096 --layers 2``, chip run of
+    PR 35; XLA's path does not fit), beside the same two rows at equal
+    widths of 128; the 192-wide contraction costs the MXU what 256 costs:
+
+         8192  192/128  yes |  out of memory |     15.207   58.236
+         8192  128/128  yes |  out of memory |     10.763   38.769
+
     The crossover lies between 128 and 256 for both head sizes, causal or
     not, so the rule takes no ``causal``: at 128 XLA wins everything
     (a grid step's fixed cost, about 0.4 µs, is most of a [128, 128] tile's
@@ -296,7 +306,7 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
 
 def attention(q, k, v, *, path: str, head_dim: int, mask=None,
               causal: bool = False, scale: Optional[float] = None,
-              segment_ids=None):
+              segment_ids=None, v_head_dim: Optional[int] = None):
     """The full-sequence attention core on ``path`` ("flash" | "xla", what
     ``attention_dispatch`` answered for the traced model).
 
@@ -310,25 +320,30 @@ def attention(q, k, v, *, path: str, head_dim: int, mask=None,
     to: key ``j`` is visible to query ``i`` iff their ids are equal (and
     ``j <= i`` under ``causal``, and ``mask[j]``); None is one document a
     row, and traces to what it traced to before the argument existed.
-    Returns the context in ``q``'s layout and dtype.
+    ``v_head_dim`` (None: ``head_dim``) is the values' width where it is
+    not the queries' and keys' (latent attention: 192-wide scores,
+    128-wide values); ``v`` is then ``[B, T, Hkv*Dv]`` or ``[B, T, Hkv,
+    Dv]``. Returns the context in ``q``'s layout and dtype, ``Dv`` wide a
+    head.
 
     "flash" repeats the KV heads for the query heads that share them (the
     kernel's entry takes as many KV heads as query heads) and calls
     ``flash_attention`` in the layout given. "xla" is the plain core:
     f32 scores, masks, f32 softmax, weighted sum."""
     D = head_dim
+    Dv = D if v_head_dim is None else v_head_dim
     B, T = q.shape[:2]
     H, Hkv = q.size // (B * T * D), k.size // (B * T * D)
     R = H // Hkv
     if path == "flash":
         if R > 1:
-            k, v = (jnp.repeat(x.reshape(B, T, Hkv, D), R, axis=2)
-                    for x in (k, v))
+            k, v = (jnp.repeat(x.reshape(B, T, Hkv, d), R, axis=2)
+                    for x, d in ((k, D), (v, Dv)))
         return flash_attention(q, k, v, mask=mask, causal=causal,
                                head_dim=D, scale=scale,
-                               segment_ids=segment_ids)
+                               segment_ids=segment_ids, v_head_dim=v_head_dim)
     big_neg = jnp.finfo(jnp.float32).min
-    k, v = (x.reshape(B, T, Hkv, D) for x in (k, v))
+    k, v = (x.reshape(B, T, Hkv, d) for x, d in ((k, D), (v, Dv)))
     s = jnp.einsum("btgrd,bsgd->bgrts", q.reshape(B, T, Hkv, R, D), k,
                    preferred_element_type=jnp.float32) * (
                        D ** -0.5 if scale is None else scale)
@@ -340,4 +355,5 @@ def attention(q, k, v, *, path: str, head_dim: int, mask=None,
     if causal:
         s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, big_neg)
     prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bgrts,bsgd->btgrd", prob, v).reshape(q.shape)
+    return jnp.einsum("bgrts,bsgd->btgrd", prob, v).reshape(
+        q.shape[:2] + ((H, Dv) if q.ndim == 4 else (H * Dv,)))

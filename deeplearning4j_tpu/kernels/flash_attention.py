@@ -231,14 +231,35 @@ def _count_boundary_pass(kind):
     boundary_pass("flash", kind)
 
 
+def _count_split_pass(kernel, D, Dv):
+    """Tick ``dl4j_flash_split_width_passes_total{kernel,kind}`` for a
+    streaming pass whose values are not as wide as its queries and keys
+    (latent attention's 192 / 128); ``kind`` is ``"<D>x<Dv>"``. A call of
+    equal widths counts nothing."""
+    if D == Dv:
+        return
+    try:
+        from ..common.environment import environment
+        environment().metrics().counter(
+            "dl4j_flash_split_width_passes_total",
+            "Streaming flash-attention passes traced with a value width "
+            "other than the query/key width, counted at trace time",
+            labels=("kernel", "kind")).labels(
+                kernel=kernel, kind=f"{D}x{Dv}").inc()
+    except Exception:
+        pass  # observability must never break a trace
+
+
 def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
                skip_empty=True, seg=None, heads=1):
     """``skip_empty=False`` (tests only) computes and masks every tile of
     a causal call, as the kernels did before they skipped."""
     BH, S, D = q.shape
+    Dv = v.shape[-1]            # the values' width; D where a call names none
     n_q, n_k = S // tile_q, S // tile_k
     skip = causal and skip_empty
     _count_tiles("fwd", n_q, n_k, tile_q, tile_k, skip)
+    _count_split_pass("fwd", D, Dv)
     grid = (BH, n_q, n_k)
 
     def kv(bh, iq, ik):
@@ -247,7 +268,7 @@ def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
     in_specs = [
         pl.BlockSpec((1, tile_q, D), lambda bh, iq, ik: (bh, iq, 0)),
         pl.BlockSpec((1, tile_k, D), kv),
-        pl.BlockSpec((1, tile_k, D), kv),
+        pl.BlockSpec((1, tile_k, Dv), kv),
     ]
     args = [q, k, v]
     if mask is not None:
@@ -270,17 +291,17 @@ def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, tile_q, D), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, tile_q, Dv), lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec((1, tile_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((BH, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((tile_q, 1), jnp.float32),
             pltpu.VMEM((tile_q, 1), jnp.float32),
-            pltpu.VMEM((tile_q, D), jnp.float32),
+            pltpu.VMEM((tile_q, Dv), jnp.float32),
         ],
         compiler_params=_params(2),
         interpret=_interpret(),
@@ -384,6 +405,7 @@ def _dkv_kernel_nomask(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
                lse_cot=None, skip_empty=True, seg=None, heads=1):
     BH, S, D = q.shape
+    Dv = v.shape[-1]
     cap = _bwd_tile_cap(causal)
     if tile_q > cap and S % cap == 0:
         tile_q = cap
@@ -393,6 +415,7 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
     skip = causal and skip_empty
     for kernel in ("dq", "dkv"):
         _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip)
+        _count_split_pass(kernel, D, Dv)
     delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1,
                     keepdims=True)  # [BH, S, 1]
     if lse_cot is not None:
@@ -414,7 +437,8 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
     def kv(bh, iq, ik):
         return bh, _kv_block(iq, ik, tile_q, tile_k, skip), 0
 
-    in_specs = [qspec(own_q), kspec(kv), kspec(kv), qspec(own_q),   # q k v g
+    in_specs = [qspec(own_q), kspec(kv), kspec(kv, Dv),             # q k v
+                qspec(own_q, Dv),                                   # g
                 qspec(own_q, 1), qspec(own_q, 1)]                   # lse delta
     args = [q, k, v, g, lse, delta]
     if mask is not None:
@@ -455,7 +479,7 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
     def qs(bh, ik, iq):
         return bh, _q_block(ik, iq, tile_q, tile_k, skip), 0
 
-    in_specs = [qspec(qs), kspec(own_kv), kspec(own_kv), qspec(qs),
+    in_specs = [qspec(qs), kspec(own_kv), kspec(own_kv, Dv), qspec(qs, Dv),
                 qspec(qs, 1), qspec(qs, 1)]
     args = [q, k, v, g, lse, delta]
     if mask is not None:
@@ -469,11 +493,11 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
         kernel(_dkv_kernel, _dkv_kernel_nomask, n_q=n_q),
         grid=(BH, n_k, n_q),
         in_specs=in_specs + specs,
-        out_specs=[kspec(own_kv), kspec(own_kv)],
+        out_specs=[kspec(own_kv), kspec(own_kv, Dv)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((tile_k, D), jnp.float32),
-                        pltpu.VMEM((tile_k, D), jnp.float32)],
+                        pltpu.VMEM((tile_k, Dv), jnp.float32)],
         compiler_params=_params(2),
         interpret=_interpret(),
     )(*args, *ids)
@@ -831,6 +855,7 @@ def _prep(q, k, v, mask, scale, tile_q, tile_k, causal):
     kernels' [B*H, S_pad, D] layout. Returns (qf, kf, vf, mf, scale,
     tile_q, tile_k, S, S_pad, B, H, D)."""
     B, S, H, D = q.shape
+    Dv = v.shape[-1]
     scale = scale if scale is not None else D ** -0.5
     if tile_q is None or tile_k is None:
         if S <= 128:
@@ -856,7 +881,7 @@ def _prep(q, k, v, mask, scale, tile_q, tile_k, causal):
         mask = jnp.pad(mask, [(0, 0), (0, S_pad - S)])
     qf = jnp.moveaxis(q, 2, 1).reshape(B * H, S_pad, D)
     kf = jnp.moveaxis(k, 2, 1).reshape(B * H, S_pad, D)
-    vf = jnp.moveaxis(v, 2, 1).reshape(B * H, S_pad, D)
+    vf = jnp.moveaxis(v, 2, 1).reshape(B * H, S_pad, Dv)
     mf = (jnp.repeat(mask.astype(jnp.int32), H, axis=0)[..., None]
           if mask is not None else None)
     return qf, kf, vf, mf, scale, tile_q, tile_k, S, S_pad, B, H, D
@@ -865,10 +890,16 @@ def _prep(q, k, v, mask, scale, tile_q, tile_k, causal):
 def flash_attention(q, k, v, mask=None, causal: bool = False,
                     scale: float = None, tile_q: int = None,
                     tile_k: int = None, head_dim: int = None,
-                    segment_ids=None):
+                    segment_ids=None, v_head_dim: int = None):
     """Flash attention over [B, S, H, D] (BTHD, the framework convention)
     or, with ``head_dim`` given, over [B, S, H*D] as the q/k/v projections
     produce it (the result has the layout of the inputs).
+
+    ``v_head_dim`` (None: ``head_dim``) is the values' width where it is
+    not the queries' and keys': ``v`` is then [B, S, H, Dv] or [B, S,
+    H*Dv], and so is the result (latent attention scores over 192 and sums
+    values of 128). Such a call always streams; the kernels are the same,
+    with the value, output and their gradients' blocks ``Dv`` wide.
 
     mask: optional [B, S] key validity (1 = attend). segment_ids: optional
     [B, S] int32, the document of each position of a packed row: a key is
@@ -887,8 +918,9 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     divisors of the padded length). Times on the chip:
     ``kernels._flash_rule``."""
     D = head_dim if head_dim is not None else q.shape[-1]
+    Dv = D if v_head_dim is None else v_head_dim
     scale = scale if scale is not None else D ** -0.5
-    one_tile = (tile_q is None and tile_k is None
+    one_tile = (tile_q is None and tile_k is None and Dv == D
                 and _padded_len(q.shape[1]) <= _ONE_TILE_MAX)
     # the one-tile kernels take heads packed, the streaming ones apart;
     # going from one to the other is a free reshape
@@ -899,7 +931,8 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
         q, k, v = (x.reshape(shape[:2] + (-1,)) for x in (q, k, v))
         return _flash_one_tile(q, k, v, mask, segment_ids, causal, scale,
                                D).reshape(shape)
-    q, k, v = (x.reshape(shape[:2] + (-1, D)) for x in (q, k, v))
+    q, k = (x.reshape(shape[:2] + (-1, D)) for x in (q, k))
+    v = v.reshape(shape[:2] + (-1, Dv))
     (qf, kf, vf, mf, scale, tile_q, tile_k,
      S, S_pad, B, H, D) = _prep(q, k, v, mask, scale, tile_q, tile_k, causal)
     if segment_ids is not None:
@@ -912,8 +945,11 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
         out = _flash_masked(qf, kf, vf, mf, scale, causal, tile_q, tile_k)
     else:
         out = _flash(qf, kf, vf, scale, causal, tile_q, tile_k)
-    out = jnp.moveaxis(out.reshape(B, H, S_pad, D), 1, 2)
-    return (out[:, :S] if S_pad != S else out).reshape(shape)
+    out = jnp.moveaxis(out.reshape(B, H, S_pad, Dv), 1, 2)
+    out = out[:, :S] if S_pad != S else out
+    # the inputs' layout, Dv wide a head
+    return out.reshape(shape[:2] + ((H, Dv) if len(shape) == 4
+                                    else (H * Dv,)))
 
 
 def flash_attention_with_lse(q, k, v, mask=None, causal: bool = False,

@@ -1,12 +1,16 @@
-"""Hybrid state-space / sparse-expert / grouped-query language model: the
-``nemotron_h`` backbone (NVIDIA Nemotron-H family; here with the keys of
-``nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16``'s ``config.json``)
-and, by the same blocks, the dense ``granitemoehybrid`` layer
-(``ibm-granite/granite-4.0-h-micro``), on the training path.
+"""Hybrid state-space / sparse-expert / grouped-query / latent-attention
+language model: the ``nemotron_h`` backbone (NVIDIA Nemotron-H family; here
+with the keys of ``nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16``'s
+``config.json``), by the same blocks the dense ``granitemoehybrid`` layer
+(``ibm-granite/granite-4.0-h-micro``), and the DeepSeek-V3 family's decoder
+(``jdopensource/JoyAI-LLM-Flash``: latent attention with a rotary part,
+gated-SiLU experts, one multi-token-prediction module), on the training
+path.
 
 A stack of pre-norm residual blocks whose mixers follow a pattern string,
 one letter a block: ``M`` Mamba-2, ``E`` sparse experts beside a shared
-expert, ``*`` causal grouped-query attention, ``-`` a dense MLP. Four
+expert, ``*`` causal grouped-query attention, ``L`` causal latent
+attention, ``-`` a dense MLP. Four
 scalar multipliers (each 1 unless the configuration says otherwise: ``m_e``
 ``embedding_multiplier``, ``m_r`` ``residual_multiplier``, ``m_a``
 ``attention_multiplier``, ``m_l`` ``logits_scaling``):
@@ -47,11 +51,31 @@ d[i]``, and a document's last token predicts nothing.
   result to the output projection's operand.
 ``E``:
     s = sigmoid(u_f32 . W_r);  top-k of s;  g_k = scale * s_k / (sum + 1e-20)
-    out = sum_k g_k W2_{e_k} relu(W1_{e_k} u)^2  +  V2 relu(V1 u)^2
+    ``moe_hidden_act`` "relu2" (nemotron_h):
+        out = sum_k g_k W2_{e_k} relu(W1_{e_k} u)^2  +  V2 relu(V1 u)^2
+    ``moe_hidden_act`` "silu" (DeepSeek-V3 family), routed and shared alike:
+        out = sum_k g_k Down_e(silu(Gate_e u) * Up_e u) + Shared(u)
+        (``w1`` holds [Gate_e ; Up_e] as one [2 F, E] matrix an expert)
   with only the terms of the experts HELD here computed (`ops.moe`): the
   config says which contiguous range that is; the router keeps all its
   outputs. ``e_score_correction_bias`` (a buffer the published training
-  updates for load balance, never a parameter) is zero and not carried.
+  updates for load balance, never a parameter; selection is by ``s + b``)
+  is zero, never updated and not carried; ``n_group`` = ``topk_group`` = 1,
+  so group-limited selection is the identity.
+``L`` (latent attention, H heads; DeepSeek-V3, arXiv:2412.19437 §2.1; the
+  training, non-absorbed form: no latent cache, no absorbed decode):
+    c_q  = RMSNorm(u W_qa; w_q)                  W_qa: E x q_lora_rank
+    q    = c_q W_qb -> H heads of [q_nope d_n | q_rope d_r]
+    [c_kv | k_rope] = u W_kva                    W_kva: E x (kv_lora_rank + d_r)
+    c_kv = RMSNorm(c_kv; w_kv)                   k_rope ONE head, shared by all H
+    [k_nope | v] = c_kv W_kvb -> H heads of (d_n + d_v)
+    q_rope, k_rope <- RoPE at positions 0..T-1: the pair (x_2i, x_2i+1)
+        turned by pos * theta^(-2i/d_r) (``rope_interleave``: the stored
+        order is interleaved), float32 angles, no scaling factor
+    k_h = [k_nope_h | k_rope];  o_h = softmax(causal(q_h . k_h / sqrt(d_n +
+    d_r))) v_h  (192-wide scores, 128-wide values);  out = concat_h(o_h) W_o
+  no bias. The core is `kernels.attention` with ``v_head_dim``; ``k_rope``
+  is repeated for the H heads and concatenated here.
 ``*``:
     q = u W_q (H heads), k, v = u W_k, u W_v (H_kv heads), causal
     softmax(m_a q k^T) v with query head i on KV head i // (H/H_kv), then
@@ -71,8 +95,22 @@ bfloat16 matmuls with float32 accumulation; float32 for RMSNorm
 statistics, the router, softmax, the scan's decays and state, softplus
 and the loss.
 
-Not built (the published model has them, its ``config.json`` has no key
-for them): the second, denoising tower and block-diffusion decoding.
+**Multi-token prediction** (``num_nextn_predict_layers`` 1; arXiv:2412.19437
+§2.2, eq. 21-25; one module of depth 1, parameters under ``params["mtp"]``).
+With ``h_t`` the main model's last hidden state at position t AFTER its
+final norm:
+
+    m_t  = W_eh [ RMSNorm(Emb(x_{t+1}); w_e) ; RMSNorm(h_t; w_h) ]  W_eh: 2E x E
+    m'_t = Layer_MTP(m_t)      one more ``L`` block and one more ``E`` block
+    p_t  = Head(RMSNorm(m'_t; w_n))   the main model's embedding and head
+    L    = L_main + lambda L_mtp;  L_mtp: mean over t < T-2 of CE(p_t, x_{t+2})
+
+``lambda`` is ``mtp_loss_weight``. 0 modules trace nothing.
+
+Not built (the published models have them): nemotron's second, denoising
+tower and block-diffusion decoding (its ``config.json`` has no key for
+them); the latent paged cache and the absorbed decode form of ``L``; MTP in
+the decode scheduler; the update of the router's bias.
 Training only: no cache, no recurrent state for `DecodeEngine`, no
 sharding rules (``make_train_step`` takes ``mesh=None``).
 """
@@ -84,6 +122,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..common.tracing import model_scope
@@ -93,7 +132,9 @@ from ..ops import moe
 from ..ops.ssm_scan import ssd_chunked_scan
 from . import _optim
 
-MAMBA, EXPERTS, ATTENTION, MLP = "M", "E", "*", "-"
+MAMBA, EXPERTS, ATTENTION, MLP, LATENT = "M", "E", "*", "-", "L"
+#: the one layer of a multi-token-prediction module, in pattern letters
+MTP_PATTERN = LATENT + EXPERTS
 
 
 @dataclasses.dataclass
@@ -124,6 +165,9 @@ class HybridLMConfig:
     moe_intermediate_size: int = 1856
     moe_shared_expert_intermediate_size: int = 3712
     routed_scaling_factor: float = 2.5
+    # the experts' activation, routed and shared alike: "relu2" (two
+    # matrices an expert) or "silu" (gated: [Gate ; Up] and Down)
+    moe_hidden_act: str = "relu2"
     # this chip's share of each expert layer: experts
     # [first_expert, first_expert + experts_held); None holds them all
     first_expert: int = 0
@@ -139,6 +183,16 @@ class HybridLMConfig:
     attention_multiplier: Optional[float] = None
     logits_scaling: float = 1.0
     tie_word_embeddings: bool = False
+    # latent attention (``L``), the DeepSeek-V3 family's key names
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 10000.0
+    # multi-token prediction: modules (0 or 1) and the weight of their loss
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
     # depth of the whole model, for the residual-output scaling of the
     # initialisation (``rescale_prenorm_residual``); None: the pattern's
     rescale_layers: Optional[int] = None
@@ -161,12 +215,19 @@ class HybridLMConfig:
         return (self.n_routed_experts if self.experts_held is None
                 else self.experts_held)
 
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
     @staticmethod
-    def tiny(granite: bool = False, **kw) -> "HybridLMConfig":
+    def tiny(granite: bool = False, latent: bool = False,
+             **kw) -> "HybridLMConfig":
         """For tests: every kind of ``nemotron_h`` block, 8 of 16 experts
         held, top 2; or, ``granite``, the dense granite layer's shape: a
         mixer and a gated-SiLU MLP a layer, one group, the four
-        multipliers, the tied head."""
+        multipliers, the tied head; or, ``latent``, the DeepSeek-V3
+        family's shape: a dense latent-attention layer, two with
+        gated-SiLU experts (4 of 16 held, top 4), one MTP module."""
         base = dict(
             vocab_size=96, hidden_size=32, hybrid_override_pattern="MEM*E",
             mamba_num_heads=4, mamba_head_dim=8, ssm_state_size=16,
@@ -181,6 +242,17 @@ class HybridLMConfig:
                 embedding_multiplier=12.0, residual_multiplier=0.22,
                 attention_multiplier=0.125, logits_scaling=8.0,
                 tie_word_embeddings=True)
+        if latent:
+            base.update(
+                hybrid_override_pattern="L-LELE", norm_eps=1e-6,
+                num_attention_heads=4, q_lora_rank=24, kv_lora_rank=16,
+                qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+                rope_theta=3.2e7, intermediate_size=48,
+                mlp_hidden_act="silu", moe_hidden_act="silu",
+                num_experts_per_tok=4, experts_held=4,
+                moe_intermediate_size=24,
+                moe_shared_expert_intermediate_size=24,
+                num_nextn_predict_layers=1)
         base.update(kw)
         return HybridLMConfig(**base)
 
@@ -201,12 +273,25 @@ def _mixer_shapes(c: HybridLMConfig, kind: str) -> Dict[str, Tuple]:
                 "D": ((H,), "one"), "gate_norm": ((c.d_inner,), "one"),
                 "out_proj": ((c.d_inner, E), "residual_out")}
     if kind == EXPERTS:
+        if c.moe_hidden_act not in ("silu", "relu2"):
+            raise ValueError(f"unknown moe_hidden_act {c.moe_hidden_act!r}")
         F, Fs = c.moe_intermediate_size, c.moe_shared_expert_intermediate_size
+        up = 2 if c.moe_hidden_act == "silu" else 1     # [Gate ; Up]
         return {"router": ((E, c.n_routed_experts), "router"),
-                "w1": ((c.held, F, E), "matrix"),
+                "w1": ((c.held, up * F, E), "matrix"),
                 "w2": ((c.held, F, E), "residual_out"),
-                "shared_w1": ((E, Fs), "matrix"),
+                "shared_w1": ((E, up * Fs), "matrix"),
                 "shared_w2": ((Fs, E), "residual_out")}
+    if kind == LATENT:
+        H, r = c.num_attention_heads, c.kv_lora_rank
+        return {"wq_a": ((E, c.q_lora_rank), "matrix"),
+                "q_norm": ((c.q_lora_rank,), "one"),
+                "wq_b": ((c.q_lora_rank, H * c.qk_head_dim), "matrix"),
+                "wkv_a": ((E, r + c.qk_rope_head_dim), "matrix"),
+                "kv_norm": ((r,), "one"),
+                "wkv_b": ((r, H * (c.qk_nope_head_dim + c.v_head_dim)),
+                          "matrix"),
+                "wo": ((H * c.v_head_dim, E), "residual_out")}
     if kind == ATTENTION:
         q = c.num_attention_heads * c.head_dim
         kv = c.num_key_value_heads * c.head_dim
@@ -253,23 +338,38 @@ def init_params(key, config: HybridLMConfig) -> Dict:
     U(+-1/sqrt(conv_kernel))."""
     c = config
     k_emb, k_head, k_blocks = jax.random.split(key, 3)
-    blocks = []
-    for i, kind in enumerate(c.pattern):
-        block = {"norm": jnp.ones((c.hidden_size,), jnp.float32)}
-        shapes = _mixer_shapes(c, kind)
-        for j, (name, (shape, how)) in enumerate(sorted(shapes.items())):
-            block[name] = _draw(
-                jax.random.fold_in(jax.random.fold_in(k_blocks, i), j),
-                shape, how, c)
-        blocks.append(block)
+    ones = lambda: jnp.ones((c.hidden_size,), jnp.float32)
+
+    def draw_blocks(key, pattern):
+        blocks = []
+        for i, kind in enumerate(pattern):
+            block = {"norm": ones()}
+            shapes = _mixer_shapes(c, kind)
+            for j, (name, (shape, how)) in enumerate(sorted(shapes.items())):
+                block[name] = _draw(
+                    jax.random.fold_in(jax.random.fold_in(key, i), j),
+                    shape, how, c)
+            blocks.append(block)
+        return blocks
+
     params = {
         "embed": _draw(k_emb, (c.vocab_size, c.hidden_size), "matrix", c),
-        "blocks": blocks,
-        "final_norm": jnp.ones((c.hidden_size,), jnp.float32),
+        "blocks": draw_blocks(k_blocks, c.pattern),
+        "final_norm": ones(),
     }
     if not c.tie_word_embeddings:
         params["head"] = _draw(k_head, (c.hidden_size, c.vocab_size),
                                "matrix", c)
+    if c.num_nextn_predict_layers:
+        if c.num_nextn_predict_layers != 1:
+            raise ValueError("one multi-token-prediction module at most")
+        k_mtp = jax.random.fold_in(key, 3)
+        params["mtp"] = {
+            "embed_norm": ones(), "hidden_norm": ones(),
+            "merge": _draw(jax.random.fold_in(k_mtp, 0),
+                           (2 * c.hidden_size, c.hidden_size), "matrix", c),
+            "blocks": draw_blocks(jax.random.fold_in(k_mtp, 1), MTP_PATTERN),
+            "final_norm": ones()}
     return params
 
 
@@ -323,6 +423,13 @@ def _relu2_mlp(u, w1, w2):
     return jnp.einsum("tf,fe->te", h, w2, preferred_element_type=jnp.float32)
 
 
+def _gated_mlp(u, w1, w2):
+    h = jnp.einsum("te,ef->tf", u, w1, preferred_element_type=jnp.float32)
+    a, b = jnp.split(h, 2, axis=-1)
+    h = (jax.nn.silu(a) * b).astype(u.dtype)
+    return jnp.einsum("tf,fe->te", h, w2, preferred_element_type=jnp.float32)
+
+
 def _experts(p, u, c: HybridLMConfig):
     """(output [B, T, E], expert_tokens [held] int32)."""
     B, T, E = u.shape
@@ -331,11 +438,61 @@ def _experts(p, u, c: HybridLMConfig):
         idx, gates = moe.route(u, p["router"], c.num_experts_per_tok,
                                c.routed_scaling_factor)
     routed, counts = moe.routed_experts(
-        u, p["w1"], p["w2"], idx, gates, c.first_expert, c.n_routed_experts)
+        u, p["w1"], p["w2"], idx, gates, c.first_expert, c.n_routed_experts,
+        act=c.moe_hidden_act)
+    shared_mlp = _gated_mlp if c.moe_hidden_act == "silu" else _relu2_mlp
     with model_scope("moe_shared"):
-        shared = _relu2_mlp(u, p["shared_w1"], p["shared_w2"])
+        shared = shared_mlp(u, p["shared_w1"], p["shared_w2"])
         out = (routed + shared).astype(u.dtype)
     return out.reshape(B, T, E), counts
+
+
+def rotary(x, theta: float):
+    """Interleaved rotary embedding of ``x`` [B, T, ..., d] at positions
+    0..T-1: the pair ``(x_2i, x_2i+1)`` turned by ``pos * theta^(-2i/d)``,
+    float32 angles and arithmetic, the result in ``x``'s dtype. The pair's
+    partner is fetched by a product with the ``d x d`` signed permutation
+    (exact in any dtype: one +-1 a column), which keeps the lanes where
+    they are."""
+    with model_scope("rope"):
+        d, T = x.shape[-1], x.shape[1]
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+        shape = (1, T) + (1,) * (x.ndim - 3) + (d,)
+        cos, sin = (jnp.repeat(f(ang), 2, axis=-1).reshape(shape)
+                    for f in (jnp.cos, jnp.sin))
+        # (x P)_2i = -x_2i+1, (x P)_2i+1 = x_2i
+        swap = np.zeros((d, d), np.float32)
+        i = np.arange(0, d, 2)
+        swap[i + 1, i], swap[i, i + 1] = -1.0, 1.0
+        partner = jnp.einsum("...d,de->...e", x, jnp.asarray(swap, x.dtype))
+        return (x.astype(jnp.float32) * cos
+                + partner.astype(jnp.float32) * sin).astype(x.dtype)
+
+
+def _latent_attention(p, u, c: HybridLMConfig, path: str):
+    B, T, _ = u.shape
+    H, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
+                     c.qk_rope_head_dim, c.v_head_dim)
+    r = c.kv_lora_rank
+    with model_scope("attn"):
+        cq = _rms_norm(jnp.einsum("bte,ef->btf", u, p["wq_a"]),
+                       p["q_norm"], c.norm_eps)
+        q = jnp.einsum("btf,fg->btg", cq, p["wq_b"]).reshape(B, T, H, dn + dr)
+        kv_a = jnp.einsum("bte,ef->btf", u, p["wkv_a"])
+        ckv = _rms_norm(kv_a[..., :r], p["kv_norm"], c.norm_eps)
+        kv = jnp.einsum("btf,fg->btg", ckv, p["wkv_b"]).reshape(
+            B, T, H, dn + dv)
+        q_rope = rotary(q[..., dn:], c.rope_theta)
+        k_rope = rotary(kv_a[..., r:], c.rope_theta)           # [B, T, dr]
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn],
+             jnp.broadcast_to(k_rope[:, :, None, :], (B, T, H, dr))], axis=-1)
+        with model_scope("attn_core"):
+            ctx = attention(q, k, kv[..., dn:], path=path, head_dim=dn + dr,
+                            v_head_dim=dv, causal=True)
+        return jnp.einsum("btf,fe->bte", ctx.reshape(B, T, H * dv), p["wo"])
 
 
 def _attention(p, u, c: HybridLMConfig, path: str, segment_ids=None):
@@ -371,6 +528,12 @@ def _block(p, h, kind: str, c: HybridLMConfig, path: str, segment_ids=None):
         out, counts = _experts(p, u, c)
     elif kind == MLP:
         out = _mlp(p, u, c)
+    elif kind == LATENT:
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "latent attention takes one document a row: rotary "
+                "positions are a row's 0..T-1")
+        out = _latent_attention(p, u, c, path)
     else:
         out = _attention(p, u, c, path, segment_ids)
     if c.residual_multiplier != 1.0:
@@ -389,15 +552,51 @@ def hidden_states(params, input_ids, config: HybridLMConfig,
     if len(params["blocks"]) != len(c.pattern):
         raise ValueError(f"{len(params['blocks'])} blocks of parameters for "
                          f"the pattern {c.pattern!r}")
+    h, counts, _ = _trunk(params, input_ids, c, remat, segment_ids)
+    return h, _stacked(counts, c)
+
+
+def _trunk(params, input_ids, c: HybridLMConfig, remat, segment_ids):
+    """(h after the pattern's blocks, [expert_tokens of each ``E`` block],
+    the attention path the trace was given)."""
+    path = _attention_path(c, input_ids.shape[1])
+    h, counts = _blocks(params["blocks"], c.pattern,
+                        _embed(params, input_ids, c), c, path, remat,
+                        segment_ids)
+    return h, counts, path
+
+
+def _stacked(counts, c: HybridLMConfig):
+    return (jnp.stack(counts) if counts
+            else jnp.zeros((0, c.held), jnp.int32))
+
+
+def _embed(params, input_ids, c: HybridLMConfig):
     with model_scope("embed"):
         h = jnp.take(params["embed"], input_ids, axis=0).astype(c.dtype)
         if c.embedding_multiplier != 1.0:
             h = h * jnp.asarray(c.embedding_multiplier, h.dtype)
-    # asked once per trace, and only by a model that has attention blocks
-    path = (attention_dispatch(input_ids.shape[1], head_dim=c.head_dim)
-            if ATTENTION in c.pattern else None)
+        return h
+
+
+def _attention_path(c: HybridLMConfig, seq_len: int):
+    """Asked once per trace, and only by a model that has attention
+    blocks; a model has ``*`` blocks or ``L`` blocks, not both."""
+    latent = LATENT in c.pattern or c.num_nextn_predict_layers
+    if latent and ATTENTION in c.pattern:
+        raise ValueError("a pattern with both `*` and `L` blocks would ask "
+                         "for the attention path twice")
+    if latent:
+        return attention_dispatch(seq_len, head_dim=c.qk_head_dim)
+    if ATTENTION in c.pattern:
+        return attention_dispatch(seq_len, head_dim=c.head_dim)
+    return None
+
+
+def _blocks(blocks, pattern, h, c: HybridLMConfig, path, remat, segment_ids):
+    """(h after the blocks, [expert_tokens of each ``E`` block])."""
     counts = []
-    for p, kind in zip(params["blocks"], c.pattern):
+    for p, kind in zip(blocks, pattern):
         block = lambda p, h, kind=kind: _block(p, h, kind, c, path,
                                                segment_ids)
         if remat:
@@ -405,8 +604,7 @@ def hidden_states(params, input_ids, config: HybridLMConfig,
         h, n = block(p, h)
         if n is not None:
             counts.append(n)
-    return h, (jnp.stack(counts) if counts
-               else jnp.zeros((0, c.held), jnp.int32))
+    return h, counts
 
 
 def forward(params, input_ids, config: HybridLMConfig, remat: bool = False,
@@ -418,7 +616,11 @@ def forward(params, input_ids, config: HybridLMConfig, remat: bool = False,
 
 
 def _logits(params, h, c: HybridLMConfig):
-    h = _rms_norm(h, params["final_norm"], c.norm_eps)
+    return _head(params, _rms_norm(h, params["final_norm"], c.norm_eps), c)
+
+
+def _head(params, h, c: HybridLMConfig):
+    """Float32 logits of normed hidden states."""
     with model_scope("head"):
         if c.tie_word_embeddings:
             logits = jnp.einsum("bte,ve->btv", h, params["embed"],
@@ -438,33 +640,75 @@ def lm_loss(params, batch, config: HybridLMConfig, remat: bool = False):
     them the B x (T - 1) positions before a row's last predict; with them
     a document's last token predicts nothing either, and the mean is over
     the positions that are left."""
-    loss, (counts, _) = _loss_terms(params, batch, config, remat)
-    return loss, counts
+    loss, aux = _loss_terms(params, batch, config, remat)
+    return loss, aux[0]
+
+
+def _token_ce(logits, ids, ahead: int):
+    """Each position's cross entropy against the id ``ahead`` positions on
+    (the ids rolled: a row's last ``ahead`` positions read its first ids
+    and are for the caller to weight 0)."""
+    labels = jnp.roll(ids, -ahead, axis=1)
+    lsm = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.take_along_axis(lsm, labels[..., None], axis=-1)[..., 0]
+
+
+def _mtp_loss(params, hn, ids, c: HybridLMConfig, path, remat: bool):
+    """The multi-token-prediction module over the main model's normed last
+    hidden states ``hn``: (mean over t < T - 2 of CE(p_t, x_{t+2}), each
+    position's term [B, T] (0 from T - 2 on), [expert_tokens of its ``E``
+    block]). Position t takes the embedding of
+    x_{t+1} (the ids rolled by one: what the rows' last position takes is
+    seen by no position that is counted)."""
+    p = params["mtp"]
+    B, T = ids.shape
+    ahead = _embed(params, jnp.roll(ids, -1, axis=1), c)
+    with model_scope("mtp"):
+        both = jnp.concatenate(
+            [_rms_norm(ahead, p["embed_norm"], c.norm_eps),
+             _rms_norm(hn, p["hidden_norm"], c.norm_eps)], axis=-1)
+        m = jnp.einsum("btf,fe->bte", both, p["merge"])
+    m, counts = _blocks(p["blocks"], MTP_PATTERN, m, c, path, remat, None)
+    logits = _head(params, _rms_norm(m, p["final_norm"], c.norm_eps), c)
+    with model_scope("loss"):
+        per_tok = jnp.where(jnp.arange(T) < T - 2,
+                            _token_ce(logits, ids, 2), 0.0)
+        return jnp.sum(per_tok) / (B * (T - 2)), per_tok, counts
 
 
 def _loss_terms(params, batch, config: HybridLMConfig, remat: bool):
     """`lm_loss` with each position's term beside it: (loss,
     (expert_tokens, float32 [B, T] cross entropy of each predicting
-    position, 0 elsewhere))."""
+    position, 0 elsewhere)). With a multi-token-prediction module the loss
+    is ``L_main + mtp_loss_weight * L_mtp``, ``expert_tokens`` has the
+    module's row last, and the auxiliary tuple two more members: ``L_mtp``
+    and each position's term of it, float32 [B, T]."""
+    c = config
     ids = batch["input_ids"]
     seg = batch.get("segment_ids")
-    h, counts = hidden_states(params, ids, config, remat, seg)
-    logits = _logits(params, h, config)
+    if c.num_nextn_predict_layers and seg is not None:
+        raise NotImplementedError("multi-token prediction over packed rows")
+    h, counts, path = _trunk(params, ids, c, remat, seg)
+    hn = _rms_norm(h, params["final_norm"], c.norm_eps)
+    logits = _head(params, hn, c)
     with model_scope("loss"):
         B, T = ids.shape
         # position t predicts ids[t + 1]; the last position predicts
         # nothing and is weighted 0, so the logits are never sliced
-        labels = jnp.roll(ids, -1, axis=1)
-        lsm = jax.nn.log_softmax(logits, axis=-1)
-        per_tok = -jnp.take_along_axis(lsm, labels[..., None], axis=-1)[..., 0]
+        per_tok = _token_ce(logits, ids, 1)
         predicts = jnp.arange(T) < T - 1
         if seg is None:
             per_tok = jnp.where(predicts, per_tok, 0.0)
-            return jnp.sum(per_tok) / (B * (T - 1)), (counts, per_tok)
-        predicts = predicts & (jnp.roll(seg, -1, axis=1) == seg)
-        per_tok = jnp.where(predicts, per_tok, 0.0)
-        return (jnp.sum(per_tok) / jnp.maximum(jnp.sum(predicts), 1),
-                (counts, per_tok))
+            loss = jnp.sum(per_tok) / (B * (T - 1))
+        else:
+            predicts = predicts & (jnp.roll(seg, -1, axis=1) == seg)
+            per_tok = jnp.where(predicts, per_tok, 0.0)
+            loss = jnp.sum(per_tok) / jnp.maximum(jnp.sum(predicts), 1)
+    if not c.num_nextn_predict_layers:
+        return loss, (_stacked(counts, c), per_tok)
+    mtp, mtp_per_tok, more = _mtp_loss(params, hn, ids, c, path, remat)
+    return (loss + c.mtp_loss_weight * mtp,
+            (_stacked(counts + more, c), per_tok, mtp, mtp_per_tok))
 
 
 def make_train_step(config: HybridLMConfig, mesh=None,
@@ -474,7 +718,11 @@ def make_train_step(config: HybridLMConfig, mesh=None,
     with params and state donated, ``aux = {"loss", "expert_tokens":
     int32 [n_expert_blocks, held]}`` and, for a batch of packed rows
     (``segment_ids``), ``"token_loss"``: float32 [B, T], each predicting
-    position's cross entropy (what a job logs by document). ``remat`` recomputes each block in
+    position's cross entropy (what a job logs by document). With a
+    multi-token-prediction module ``loss`` is ``L_main + mtp_loss_weight *
+    L_mtp``, ``aux["mtp_loss"]`` is ``L_mtp``, ``aux["mtp_token_loss"]``
+    each position's term of it (float32 [B, T], 0 from T - 2 on) and
+    ``expert_tokens`` has the module's ``E`` block as its last row. ``remat`` recomputes each block in
     the backward pass (`jax.checkpoint` around one block).
     ``learning_rate`` is a number or a schedule ``iteration -> rate`` (as
     `learning.Schedule`), traced into the step. Nothing here balances the
@@ -493,15 +741,17 @@ def make_train_step(config: HybridLMConfig, mesh=None,
         return _loss_terms(params, batch, config, remat)
 
     def step(params, opt_state, batch, iteration):
-        (loss, (counts, per_tok)), grads = jax.value_and_grad(
+        (loss, terms), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, batch)
         rate = (learning_rate(iteration) if callable(learning_rate)
                 else learning_rate)
         new_params, opt_state = _optim.adam_apply(
             params, grads, opt_state, rate, iteration)
-        aux = {"loss": loss, "expert_tokens": counts}
+        aux = {"loss": loss, "expert_tokens": terms[0]}
         if "segment_ids" in batch:
-            aux["token_loss"] = per_tok
+            aux["token_loss"] = terms[1]
+        if config.num_nextn_predict_layers:
+            aux["mtp_loss"], aux["mtp_token_loss"] = terms[2], terms[3]
         return new_params, opt_state, aux
 
     return counted_jit(step, tag=f"hybrid_lm_train:{id(step)}",
@@ -513,16 +763,23 @@ def make_train_step(config: HybridLMConfig, mesh=None,
 def observe(aux, config: HybridLMConfig, tokens: int) -> float:
     """Read a step's loss on the host and feed the expert-load counters
     from its ``aux`` (blocks until that step is done; ``tokens`` is the
-    step's B x T):
+    step's B x T; a step with a multi-token-prediction module also feeds
+    ``dl4j_mtp_positions_total``, the B x (T - 2) positions that loss was
+    taken over, from the shape of ``aux["mtp_token_loss"]``):
     ``dl4j_moe_assignments_total`` (tokens x top-k routed, over all
     experts), ``dl4j_moe_held_assignments_total`` (those that fell on
     experts held here), ``dl4j_moe_expert_tokens_total{block,expert}`` and
     the gauge ``dl4j_moe_max_expert_tokens`` (the fullest held expert of
     the step). Returns the loss."""
-    import numpy as np
     from ..common.environment import environment
     loss, counts = jax.device_get((aux["loss"], aux["expert_tokens"]))
     counts = np.asarray(counts)
+    if "mtp_token_loss" in aux:
+        B, T = aux["mtp_token_loss"].shape
+        environment().metrics().counter(
+            "dl4j_mtp_positions_total",
+            "Positions the multi-token-prediction loss was taken over "
+            "(B x (T - 2) a step)").inc(B * max(T - 2, 0))
     if counts.size:
         reg = environment().metrics()
         held = int(counts.sum())

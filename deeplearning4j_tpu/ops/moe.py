@@ -6,8 +6,10 @@ A chip of an expert-parallel job holds a contiguous range of the layer's
 experts. Every token is routed over all ``n_experts`` (the router keeps
 its published width, its experts per token and its normalisation over all
 of them); the assignments that fall on held experts are sorted by expert,
-the held experts' two matrices are applied to them by a grouped matrix
-product (group ``e`` is the rows sorted to expert ``e``; jax's Pallas
+the held experts' matrices (two an expert, ``W2 relu(W1 u)^2``, or the
+gated three, ``Down (silu(Gate u) * Up u)`` with ``[Gate ; Up]`` held as
+one matrix so that an expert is still two grouped products) are applied to
+them by a grouped matrix product (group ``e`` is the rows sorted to expert ``e``; jax's Pallas
 ``megablox.gmm``, which visits only the row tiles that hold rows — XLA's
 own lowering of ``lax.ragged_dot`` on the TPU computes every group over
 every row, 8 to 45 times the work at 8 experts, measured), and the
@@ -47,11 +49,18 @@ def _megablox():
 
 
 def _tile(width):
-    """The tile of one of a product's two wide dimensions: the largest
-    multiple of 128 from 384 to 1,024 that divides it (2,688 -> 896),
-    else the whole width while that fits the kernel's memory (1,856,
-    which no multiple of 128 divides), else 512 with a partial last
-    tile."""
+    """The tile of one of a product's two wide dimensions. One pass over
+    the dimension, the whole width as the tile, while the kernel's three
+    blocks fit VMEM beside the other dimensions' tiles: up to 1,536 (read
+    on the chip at 768 and at the gated experts' [Gate ; Up] of 2 x 768
+    only, PR 35; no cell has another width under 1,536, so 1,152 and 1,280
+    are unread). Above that, the largest multiple of 128 from 384 to 1,024
+    that divides it (2,688 -> 896 read, PR 27; 2,048 -> 1,024 read, PR 35:
+    whole, it does not fit), else the whole width up to 2,048 (1,856, which
+    no multiple of 128 divides; read, PR 27), else 512 with a partial last
+    tile (unread)."""
+    if width <= 1536:
+        return width
     whole = [t for t in range(1024, 383, -128) if width % t == 0]
     return whole[0] if whole else width if width <= 2048 else 512
 
@@ -63,7 +72,11 @@ def _tiling(m, k, n, interpret):
     least. On the chip at the published widths, one expert layer's two
     products forward and backward over 4,143 rows in 8 runs: 2.70 ms at
     (128, 896, 1856) against 4.09 ms at (512, 512, 512) (chip run, PR
-    27)."""
+    27). The gated experts' (2,048 -> 2 x 768 -> 2,048), 8,192 rows in 16
+    runs of a 32,768-row buffer: 3.08 ms with the 1,536 of [Gate ; Up] as
+    one tile against 3.53 at 768, 3.81 at 512-wide contractions, 3.19 and
+    3.39 at row tiles of 256 and 512; every dimension whole does not fit
+    VMEM (chip run, PR 35)."""
     if interpret:
         return (128, 128, 128)
     return (128, _tile(k), _tile(n))
@@ -124,7 +137,8 @@ def route(u, router_w, top_k: int, scaling: float):
     return idx.astype(jnp.int32), gates
 
 
-def _buffer_full(u, w1, w2, order, gates, group_sizes, load, capacity, i):
+def _buffer_full(u, w1, w2, order, gates, group_sizes, load, capacity, i,
+                 act):
     """What the sorted rows ``[i * capacity, (i + 1) * capacity)`` add:
     the held experts' outputs, weighted by their gates and summed per
     token. float32 [t, e]."""
@@ -143,7 +157,11 @@ def _buffer_full(u, w1, w2, order, gates, group_sizes, load, capacity, i):
         rows = jnp.where(real[:, None], u[token], 0)
     with model_scope("moe_experts"):
         h = grouped_matmul(rows, w1, sizes, True)
-        h = jnp.square(jax.nn.relu(h)).astype(u.dtype)
+        if act == "silu":       # w1 = [Gate ; Up]: one product for both
+            a, b = jnp.split(h, 2, axis=-1)
+            h = (jax.nn.silu(a) * b).astype(u.dtype)
+        else:
+            h = jnp.square(jax.nn.relu(h)).astype(u.dtype)
         y = grouped_matmul(h, w2, sizes)
     with model_scope("moe_route"):
         gate = jnp.where(real, gates.reshape(-1)[slots], 0.0)
@@ -152,17 +170,23 @@ def _buffer_full(u, w1, w2, order, gates, group_sizes, load, capacity, i):
 
 
 def routed_experts(u, w1, w2, idx, gates, first_expert: int,
-                   n_experts: int) -> Tuple[jax.Array, jax.Array]:
+                   n_experts: int, act: str = "relu2"
+                   ) -> Tuple[jax.Array, jax.Array]:
     """(``out`` [t, e] float32, ``expert_tokens`` [held] int32): the sum
     over the assignments ``(token, k)`` with ``first_expert <= idx <
-    first_expert + held`` of ``gates * W2_e relu(W1_e u)^2``, and how many
-    assignments each held expert received.
+    first_expert + held`` of ``gates * W2_e relu(W1_e u)^2`` (``act``
+    "relu2") or ``gates * Down_e (silu(Gate_e u) * Up_e u)`` (``act``
+    "silu"), and how many assignments each held expert received.
 
     u: [t, e] tokens; w1, w2: both [held, f, e], the held experts' two
     matrices with the hidden width ``f`` second (``W1_e`` as a model file
     holds it, ``W2_e`` transposed: the minor dimension is then the one
     that fills the chip's 128-wide tiles at the published widths), expert
-    ``first_expert + i`` at index ``i``; idx, gates: ``route``'s."""
+    ``first_expert + i`` at index ``i``; under "silu" ``w1`` is [held, 2 f,
+    e], ``Gate_e``'s rows above ``Up_e``'s, and ``w2`` is ``Down_e``
+    transposed; idx, gates: ``route``'s."""
+    if act not in ("relu2", "silu"):
+        raise ValueError(f"unknown expert activation {act!r}")
     t, top_k = idx.shape
     held = w1.shape[0]
     worst = t * min(top_k, held)
@@ -181,7 +205,7 @@ def routed_experts(u, w1, w2, idx, gates, first_expert: int,
             axis=0, dtype=jnp.int32)
         load = jnp.sum(group_sizes)
     one = lambda i: _buffer_full(u, w1, w2, order, gates, group_sizes, load,
-                                 capacity, i)
+                                 capacity, i, act)
     out = one(0)
     if buffers > 1:
         def rest():

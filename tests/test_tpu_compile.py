@@ -117,6 +117,34 @@ def test_hybrid_causal_attention_compiles_for_v5e(shape, grad, one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < B * H * S * S
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_latent_attention_core_compiles_for_v5e(grad, one_chip,
+                                                compiled_kernels):
+    """Latent attention's core as the JoyAI cell runs it: two rows of
+    8,192, 32 heads whose queries and keys are 192 wide (128 + 64 rotary,
+    not a multiple of the 128 lanes) and whose values are 128 wide, causal,
+    through the same streaming kernels with the value, output and their
+    gradients' blocks 128 wide."""
+    B, H, S, D, Dv = 2, 32, 8192, 192, 128
+    qk = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    v = _sds((B, S, H, Dv), jnp.bfloat16, one_chip)
+    core = lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                              v_head_dim=Dv)
+    if grad:
+        fn = jax.grad(lambda q, k, v: jnp.sum(
+            core(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    else:
+        fn = core
+    compiled, text = _compile(fn, qk, qk, v)
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (3 if grad else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < B * H * S * S
+    shapes = [o.shape for o in jax.tree_util.tree_leaves(
+        jax.eval_shape(fn, qk, qk, v))]
+    assert shapes == ([(B, S, H, D), (B, S, H, D), (B, S, H, Dv)] if grad
+                      else [(B, S, H, Dv)])
+
+
 # (B, H, S, D): the packed granite cell's attention layer (32 query heads of
 # 64, the 8 KV heads repeated, scores times 1/64), and a one-tile length
 PACKED_SHAPES = [(1, 32, 16384, 64), (2, 4, 512, 64)]
@@ -350,6 +378,33 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch,
     _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), rows, w1, w2, sizes)
     # the first product forward (the gradient needs no value of the
     # second), two for the rows and two for the weights back
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+
+
+def test_gated_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch,
+                                               no_persistent_cache):
+    """The JoyAI cell's expert layer: a buffer of 32,768 sorted rows (four
+    times the 8,192 expected of 16,384 tokens x 8 over 16 of 256 experts),
+    16 held experts whose [Gate ; Up] is one [1,536, 2,048] matrix and
+    whose Down is [768, 2,048] transposed, gated SiLU between the two
+    grouped products, forward and backward."""
+    from deeplearning4j_tpu.ops import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe._tiling(32768, 2048, 1536, False) == (128, 1024, 1536)
+    assert moe._tiling(32768, 768, 2048, False) == (128, 768, 1024)
+    # the nemotron cell's tiles are where PR 27 read them
+    assert moe._tiling(12288, 2688, 1856, False) == (128, 896, 1856)
+    rows = _sds((32768, 2048), jnp.bfloat16, one_chip)
+    w1 = _sds((16, 1536, 2048), jnp.bfloat16, one_chip)      # [g, 2 F, E]
+    w2 = _sds((16, 768, 2048), jnp.bfloat16, one_chip)       # [g, F, E]
+    sizes = _sds((16,), jnp.int32, one_chip)
+
+    def loss(rows, w1, w2, sizes):
+        a, b = jnp.split(moe.grouped_matmul(rows, w1, sizes, True), 2, -1)
+        h = (jax.nn.silu(a) * b).astype(rows.dtype)
+        return jnp.sum(moe.grouped_matmul(h, w2, sizes))
+
+    _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), rows, w1, w2, sizes)
     assert text.count('custom_call_target="tpu_custom_call"') == 5
 
 
