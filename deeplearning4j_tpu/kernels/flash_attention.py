@@ -30,6 +30,15 @@ while f32 accumulators persist in VMEM scratch across the sequential steps:
 
 delta = rowsum(o ⊙ do) is precomputed with plain XLA (one elementwise pass).
 
+The forward rules keep o and lse for the backward under two names,
+``SAVED_OUT`` and ``SAVED_LSE`` (``jax.ad_checkpoint.checkpoint_name``; lse
+as the lane-dense ``[BH, S]`` view, the column restored in the backward
+rule). A ``jax.checkpoint`` whose policy saves those names
+(``models.hybrid_lm._blocks``) then recomputes the rest of its body in the
+backward but not the forward kernel; without such a policy the names do
+nothing. The one-tile kernels and the (o, lse)-returning variant name
+nothing.
+
 Under ``causal=True`` the streaming kernels do work only for the tiles that
 hold an unmasked (query, key) pair. A grid step whose tile lies wholly above
 the diagonal runs no matmul, no exp and no accumulator update, and its
@@ -72,10 +81,18 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+
+#: what the streaming forward rules (`_flash`, `_flash_masked`, `_flash_seg`)
+#: name their output and log-sum-exp: a ``jax.checkpoint`` policy that saves
+#: these two (``hybrid_lm._blocks``) keeps a rematerialised backward from
+#: launching the forward kernel again
+SAVED_OUT = "dl4j_flash_out"
+SAVED_LSE = "dl4j_flash_lse"
 
 
 def _interpret() -> bool:
@@ -508,6 +525,19 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
 # custom_vjp plumbing (mask variants split so mask=None stays cheap)
 # ---------------------------------------------------------------------------
 
+def _named(o, lse):
+    """The forward's (o, lse) under `SAVED_OUT` / `SAVED_LSE`, the lse as
+    ``[BH, S]``. A rule returns this ``o`` as its primal output AND keeps
+    it as a residual: the block's recomputed forward reads the primal, so
+    a name on a copy that only the residuals hold would leave the kernel
+    recomputed. The kernel writes lse as ``[BH, S, 1]``, whose minor 1 the
+    TPU pads to 128 lanes: saved so, each latent layer's lse would hold
+    268 MB where 2 MB are data, so the residual is the lane-dense view and
+    the backward rule restores the column."""
+    return (checkpoint_name(o, SAVED_OUT),
+            checkpoint_name(lse[..., 0], SAVED_LSE))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, scale, causal, tile_q, tile_k):
     o, _ = _flash_fwd(q, k, v, None, scale, causal, tile_q, tile_k)
@@ -515,13 +545,15 @@ def _flash(q, k, v, scale, causal, tile_q, tile_k):
 
 
 def _flash_f(q, k, v, scale, causal, tile_q, tile_k):
-    o, lse = _flash_fwd(q, k, v, None, scale, causal, tile_q, tile_k)
+    o, lse = _named(*_flash_fwd(q, k, v, None, scale, causal, tile_q,
+                                tile_k))
     return o, (q, k, v, o, lse)
 
 
 def _flash_b(scale, causal, tile_q, tile_k, res, g):
     q, k, v, o, lse = res
-    return _flash_bwd(q, k, v, None, o, lse, g, scale, causal, tile_q, tile_k)
+    return _flash_bwd(q, k, v, None, o, lse[..., None], g, scale, causal,
+                      tile_q, tile_k)
 
 
 _flash.defvjp(_flash_f, _flash_b)
@@ -534,14 +566,15 @@ def _flash_masked(q, k, v, mask, scale, causal, tile_q, tile_k):
 
 
 def _flash_masked_f(q, k, v, mask, scale, causal, tile_q, tile_k):
-    o, lse = _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k)
+    o, lse = _named(*_flash_fwd(q, k, v, mask, scale, causal, tile_q,
+                                tile_k))
     return o, (q, k, v, mask, o, lse)
 
 
 def _flash_masked_b(scale, causal, tile_q, tile_k, res, g):
     q, k, v, mask, o, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, mask, o, lse, g, scale, causal,
-                            tile_q, tile_k)
+    dq, dk, dv = _flash_bwd(q, k, v, mask, o, lse[..., None], g, scale,
+                            causal, tile_q, tile_k)
     return dq, dk, dv, None
 
 
@@ -562,15 +595,15 @@ def _flash_seg(q, k, v, mask, seg_col, seg_row, scale, causal, tile_q,
 
 def _flash_seg_f(q, k, v, mask, seg_col, seg_row, scale, causal, tile_q,
                  tile_k, heads):
-    o, lse = _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
-                        seg=(seg_col, seg_row), heads=heads)
+    o, lse = _named(*_flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
+                                seg=(seg_col, seg_row), heads=heads))
     return o, (q, k, v, mask, seg_col, seg_row, o, lse)
 
 
 def _flash_seg_b(scale, causal, tile_q, tile_k, heads, res, g):
     q, k, v, mask, seg_col, seg_row, o, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, mask, o, lse, g, scale, causal,
-                            tile_q, tile_k, seg=(seg_col, seg_row),
+    dq, dk, dv = _flash_bwd(q, k, v, mask, o, lse[..., None], g, scale,
+                            causal, tile_q, tile_k, seg=(seg_col, seg_row),
                             heads=heads)
     return dq, dk, dv, None, None, None
 
@@ -814,6 +847,14 @@ def _flash_one_tile(q, k, v, mask, seg, causal, scale, D):
     return out[:, :S] if S_pad != S else out
 
 
+def streams(seq_len, head_dim, v_head_dim=None) -> bool:
+    """Whether `flash_attention` called with no tiles takes the streaming
+    kernels, whose forward rules name their output and log-sum-exp
+    (`SAVED_OUT`, `SAVED_LSE`), rather than the one-tile kernels."""
+    return (v_head_dim not in (None, head_dim)
+            or _padded_len(seq_len) > _ONE_TILE_MAX)
+
+
 def _fit_tile(want, s_pad):
     """Largest multiple of 128 ≤ want that divides s_pad (s_pad is a
     multiple of 128)."""
@@ -920,8 +961,8 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     D = head_dim if head_dim is not None else q.shape[-1]
     Dv = D if v_head_dim is None else v_head_dim
     scale = scale if scale is not None else D ** -0.5
-    one_tile = (tile_q is None and tile_k is None and Dv == D
-                and _padded_len(q.shape[1]) <= _ONE_TILE_MAX)
+    one_tile = (tile_q is None and tile_k is None
+                and not streams(q.shape[1], D, Dv))
     # the one-tile kernels take heads packed, the streaming ones apart;
     # going from one to the other is a free reshape
     shape = q.shape

@@ -127,6 +127,7 @@ from jax import lax
 
 from ..common.tracing import model_scope
 from ..kernels import attention, attention_dispatch
+from ..kernels.flash_attention import SAVED_LSE, SAVED_OUT, streams
 from ..kernels.ssm_fused import mamba_chain
 from ..ops import moe
 from ..ops.ssm_scan import ssd_chunked_scan
@@ -593,18 +594,60 @@ def _attention_path(c: HybridLMConfig, seq_len: int):
     return None
 
 
+#: what a rematerialised block keeps from its first forward: the streaming
+#: flash core's output and log-sum-exp, and nothing else
+_SAVE_CORES = jax.checkpoint_policies.save_only_these_names(SAVED_OUT,
+                                                            SAVED_LSE)
+
+
 def _blocks(blocks, pattern, h, c: HybridLMConfig, path, remat, segment_ids):
-    """(h after the blocks, [expert_tokens of each ``E`` block])."""
+    """(h after the blocks, [expert_tokens of each ``E`` block]).
+
+    Under ``remat`` each block is one `jax.checkpoint` whose policy keeps
+    the output and log-sum-exp of a streaming flash core (the two names
+    `kernels.flash_attention` gives them): the backward recomputes the
+    block's forward up to q, k and v for the backward kernels, reads the
+    core's results from the first forward and does not launch the forward
+    kernel again. A block with no such core (``M``, ``E``, ``-``, or a core
+    on XLA's path or the one-tile kernel) holds neither name and saves
+    nothing, as a bare checkpoint does."""
     counts = []
     for p, kind in zip(blocks, pattern):
         block = lambda p, h, kind=kind: _block(p, h, kind, c, path,
                                                segment_ids)
         if remat:
-            block = jax.checkpoint(block)
+            block = jax.checkpoint(block, policy=_SAVE_CORES)
+            if _saves_core(kind, c, path, h.shape[1]):
+                _count_saved_core(kind)
         h, n = block(p, h)
         if n is not None:
             counts.append(n)
     return h, counts
+
+
+def _saves_core(kind, c: HybridLMConfig, path, seq_len) -> bool:
+    """Whether a block of ``kind`` holds a streaming flash core, whose
+    output and log-sum-exp `_SAVE_CORES` keeps."""
+    if path != "flash" or kind not in (ATTENTION, LATENT):
+        return False
+    if kind == LATENT:
+        return streams(seq_len, c.qk_head_dim, c.v_head_dim)
+    return streams(seq_len, c.head_dim)
+
+
+def _count_saved_core(kind) -> None:
+    """Tick ``dl4j_remat_saved_cores_total{kind}`` (``kind`` the block's
+    letter) at trace time: one rematerialised block whose core's results
+    the policy keeps."""
+    try:
+        from ..common.environment import environment
+        environment().metrics().counter(
+            "dl4j_remat_saved_cores_total",
+            "Rematerialised blocks whose streaming flash core's output and "
+            "log-sum-exp the checkpoint policy keeps, counted at trace time",
+            labels=("kind",)).labels(kind=kind).inc()
+    except Exception:
+        pass  # observability must never break a trace
 
 
 def forward(params, input_ids, config: HybridLMConfig, remat: bool = False,
@@ -723,7 +766,9 @@ def make_train_step(config: HybridLMConfig, mesh=None,
     L_mtp``, ``aux["mtp_loss"]`` is ``L_mtp``, ``aux["mtp_token_loss"]``
     each position's term of it (float32 [B, T], 0 from T - 2 on) and
     ``expert_tokens`` has the module's ``E`` block as its last row. ``remat`` recomputes each block in
-    the backward pass (`jax.checkpoint` around one block).
+    the backward pass (`jax.checkpoint` around one block), all but a
+    streaming flash core's output and log-sum-exp, which it keeps from the
+    first forward so that the forward kernel runs once (`_blocks`).
     ``learning_rate`` is a number or a schedule ``iteration -> rate`` (as
     `learning.Schedule`), traced into the step. Nothing here balances the
     experts' load (no update of the router's bias, no auxiliary loss): an

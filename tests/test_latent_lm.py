@@ -446,13 +446,20 @@ def test_latent_blocks_refuse_what_they_cannot_do(inputs):
 #: sha256 (first 16 hex digits) of the lowered text of the PARENT commit
 #: (c3be9ee, PR 34), read with jax 0.9.0 on the CPU by the same expressions
 #: as below: the new arguments (`v_head_dim`, `act`, the MTP switch, the
-#: latent letters) change nothing that a model without them lowers to
+#: latent letters) change nothing that a model without them lowers to.
+#: "attention-stream" was read again in PR 36 (the parent's was
+#: 855b47610231eceb): the streaming forward rule now keeps its lse as the
+#: lane-dense ``[BH, S]`` view, one slice and one reshape more in the
+#: forward and one broadcast back to ``[BH, S, 1]`` in the backward, and
+#: its two `checkpoint_name`s (which lower to nothing) advance MLIR's symbol
+#: uniquifier by one; every other op is the parent's. The two models lower
+#: on the XLA path, where no name exists, and keep their hashes
 PARENT_TEXT = {
     "nemotron": "5c3d8f14c6dc93f2",
     "granite": "d921ac3e6b0e3ca4",
     "attention-flash": "2d6ebe082e35b381",
     "attention-xla": "52bf4823f5911f18",
-    "attention-stream": "855b47610231eceb",
+    "attention-stream": "618f81acaffb5d58",
 }
 
 
