@@ -28,7 +28,8 @@ from . import Finding, Module, PACKAGE_ROOT
 #: fwd|dq|dkv, on ``dl4j_flash_tiles_total``, whose kind is
 #: computed|skipped; op is the fused Mamba-2 operation, conv_silu|gate_norm,
 #: on ``dl4j_ssm_fused_calls_total``, whose kind is fwd|bwd, as is
-#: ``dl4j_ssm_scan_passes_total``'s), a
+#: ``dl4j_ssm_scan_passes_total``'s; phase is trace|lower|compile on
+#: ``dl4j_compile_phase_seconds_total``), a
 #: deploy-bounded identity
 #: (model/version/bucket/worker/name/replica — replica is a fleet
 #: member's URL, bounded by the router's configured replica set;
@@ -38,7 +39,8 @@ from . import Finding, Module, PACKAGE_ROOT
 #: must ride on exemplars or spans, never on labels.
 REGISTERED_LABELS: Set[str] = {
     "block", "bucket", "cache", "engine", "expert", "good", "kernel", "kind",
-    "mode", "model", "name", "op", "outcome", "path", "priority", "reason",
+    "mode", "model", "name", "op", "outcome", "path", "phase", "priority",
+    "reason",
     "replica", "site",
     "slo", "state", "tier", "version", "window", "worker", "jax_version",
     "jaxlib_version", "platform",

@@ -33,11 +33,27 @@ Primitives:
   traced function, so every device operation of a jitted step carries
   the component it belongs to in its ``op_name`` (the profiler's
   ``tf_op``). Trace-time only: the compiled program is unchanged.
+- ``build_span(kind)`` — one executable build (``counted_jit``'s first
+  call of a signature): a span ``compile/<kind>`` whose children are
+  jax's own compile phases, heard through ``jax.monitoring``
+  (``watch_compiles``) as spans ``jax/trace``, ``jax/lower`` and
+  ``jax/compile`` (``fun_name``, and ``cache`` = hit / miss where jax's
+  persistent cache answered). A build feeds
+  ``dl4j_compile_phase_seconds_total{kind,phase}`` with each phase's self
+  time and ``dl4j_jax_cache_requests_total{kind,outcome}``; phases heard
+  outside a build (a plain ``jax.jit``, once a first build registered the
+  listeners) are root spans with no ``kind`` and feed no counter.
 
 Export (``tracer().export(path)``) writes exactly the format
 `load_trace`/`aggregate` consume — atomically (tmp + rename, parent dirs
 created), so a run can be diffed against a previous one with
 `profile_analyzer.compare` and a crash never leaves a truncated file.
+
+One clock: ``ts`` is microseconds of the wall clock (``time.time()``),
+the clock the profiler stamps its host lines with (an ``.xplane.pb``'s
+``profile_start_time`` plus an event's offset) and jax stamps its compile
+phases with. Callers keep passing ``time.perf_counter`` seconds; the
+tracer adds one offset taken when it is made.
 
 When a jax device profile is active (`jax.profiler.start_trace`), each
 span additionally enters a `jax.profiler.TraceAnnotation` so the host
@@ -53,6 +69,7 @@ from __future__ import annotations
 
 import contextvars
 import gzip
+import heapq
 import json
 import os
 import threading
@@ -189,6 +206,12 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs):
+        pass
+
+    def count_phases(self):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -232,6 +255,10 @@ class _Span:
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **attrs):
+        """Add args to the span before it closes."""
+        self.args.update(attrs)
+
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
         if self._annotation is not None:
@@ -242,7 +269,8 @@ class _Span:
         if self._token is not None:
             _CTX.reset(self._token)
         ev = {"name": self.name, "ph": "X",
-              "ts": self._t0 * 1e6, "dur": (t1 - self._t0) * 1e6,
+              "ts": (self._t0 + self._tracer.epoch) * 1e6,
+              "dur": (t1 - self._t0) * 1e6,
               "pid": self._tracer.pid, "tid": threading.get_ident()}
         args = self.args
         if exc_type is not None:
@@ -273,6 +301,8 @@ class Tracer:
         self.capacity = max(int(capacity), 1)
         self.pid = os.getpid()
         self._events: deque = deque(maxlen=self.capacity)
+        # perf_counter seconds + epoch = wall-clock seconds (module doc)
+        self.epoch = time.time() - time.perf_counter()
 
     def span(self, name: str, **attrs):
         """Context manager timing one region; a no-op singleton when
@@ -297,7 +327,7 @@ class Tracer:
         like a failing ``span()``."""
         if not registry().enabled:
             return None
-        ev = {"name": name, "ph": "X", "ts": t0 * 1e6,
+        ev = {"name": name, "ph": "X", "ts": (t0 + self.epoch) * 1e6,
               "dur": max(t1 - t0, 0.0) * 1e6, "pid": self.pid,
               "tid": threading.get_ident()}
         args = dict(attrs)
@@ -319,8 +349,9 @@ class Tracer:
     def events_for(self, trace_id: str) -> List[dict]:
         """Every buffered event tagged with ``trace_id``, oldest first
         (a linear scan of the ring — debug/flight-recorder use, not the
-        request hot path)."""
-        return [e for e in self._events
+        request hot path). The scan runs over a copy: other threads keep
+        appending (jax's compile phases among them)."""
+        return [e for e in self.events()
                 if e.get("args", {}).get("trace_id") == trace_id]
 
     def clear(self):
@@ -496,3 +527,172 @@ def span(name: str, **attrs):
 def export(path: str) -> int:
     """Module-level convenience: `tracing.export(path)`."""
     return tracer().export(path)
+
+
+# ---------------------------------------------------------------------------
+# executable builds: jax's compile phases as spans and counters
+# ---------------------------------------------------------------------------
+
+#: jax.monitoring's time-span events -> the phase each times. jax stamps
+#: them with ``time.time()``; ``backend_compile`` covers a persistent-cache
+#: read as well as a real XLA compile
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+#: jax.monitoring's persistent-cache events -> outcome
+CACHE_OUTCOMES = {"/jax/compilation_cache/cache_hits": "hit",
+                  "/jax/compilation_cache/cache_misses": "miss"}
+
+_BUILD: "contextvars.ContextVar[Optional[_Build]]" = \
+    contextvars.ContextVar("dl4j_tpu_build", default=None)
+# the cache outcome jax announced for the compile span about to close on
+# this thread (both events fire inside ``backend_compile``'s span)
+_CACHE_OUTCOME = threading.local()
+_WATCHING = False
+_WATCH_LOCK = threading.Lock()
+
+
+def phase_self_seconds(spans, lo: float, hi: float) -> Dict[str, float]:
+    """``[(phase, t0, t1)]`` -> ``{phase: seconds}``, each instant of
+    ``[lo, hi]`` counted once, for the innermost span over it (the latest
+    start). jax emits a phase for every nested ``jit`` — an inner pass
+    traced inside the step's trace, an eager ``jit`` compiled at trace
+    time — so a phase's seconds are the union of its spans less what
+    spans of another phase nested inside them cover, and the phases
+    together never exceed ``hi - lo``."""
+    out = {p: 0.0 for p in COMPILE_PHASES.values()}
+    order = sorted((max(a, lo), min(b, hi), p) for p, a, b in spans
+                   if min(b, hi) > max(a, lo))
+    cuts = sorted({t for a, b, _ in order for t in (a, b)})
+    active, i = [], 0
+    for x0, x1 in zip(cuts, cuts[1:]):
+        while i < len(order) and order[i][0] <= x0:
+            a, b, p = order[i]
+            heapq.heappush(active, (-a, b, p))   # latest start on top
+            i += 1
+        while active and active[0][1] <= x0:
+            heapq.heappop(active)
+        if active:
+            out[active[0][2]] += x1 - x0
+    return out
+
+
+class _Build:
+    """One executable build in progress: its span, and the phase spans
+    jax reported while it ran."""
+    __slots__ = ("kind", "spans", "_span", "_t0", "_t1", "_tokens")
+
+    def __init__(self, kind: str):
+        self.kind = kind
+        self.spans: List[tuple] = []
+        self._t0 = self._t1 = 0.0
+
+    def __enter__(self):
+        # the span needs a trace context for its children to name it as
+        # their parent: the caller's, else a tree of its own
+        parent = _CTX.get()
+        self._tokens = (_CTX.set(parent or TraceContext(new_trace_id())),
+                        _BUILD.set(self))
+        self._span = tracer().span("compile/" + self.kind).__enter__()
+        self._t0 = time.time()
+        return self
+
+    def __exit__(self, *exc):
+        self._t1 = time.time()
+        try:
+            return self._span.__exit__(*exc)
+        finally:
+            _BUILD.reset(self._tokens[1])
+            _CTX.reset(self._tokens[0])
+
+    def set(self, **attrs):
+        self._span.set(**attrs)
+
+    def count_phases(self):
+        """Feed ``dl4j_compile_phase_seconds_total`` with this build's
+        self time a phase (every phase, 0 included). The caller does so
+        where it observes the build's ``dl4j_compile_seconds``, so the
+        phases of a kind never exceed that histogram's sum."""
+        try:
+            fam = registry().counter(
+                "dl4j_compile_phase_seconds_total",
+                "Self seconds of jax's trace, lower and compile phases in "
+                "executable builds", labels=("kind", "phase"))
+            for phase, s in phase_self_seconds(self.spans, self._t0,
+                                               self._t1).items():
+                fam.labels(kind=self.kind, phase=phase).inc(s)
+        except Exception:
+            pass  # observability must never break the dispatch path
+
+
+def build_span(kind: str):
+    """``with build_span(kind) as b: ...`` around one executable build:
+    the span ``compile/<kind>`` (``b.set(cache=...)`` adds args), jax's
+    phases as its children, ``b.count_phases()`` for the counter. A no-op
+    while telemetry is off."""
+    if not registry().enabled:
+        return _NULL_SPAN
+    watch_compiles()
+    return _Build(kind)
+
+
+def watch_compiles() -> bool:
+    """Register the jax.monitoring listeners, once per process and only
+    while the registry is enabled; True once they are registered."""
+    global _WATCHING
+    if _WATCHING or not registry().enabled:
+        return _WATCHING
+    with _WATCH_LOCK:
+        if not _WATCHING:
+            import jax.monitoring
+            jax.monitoring.register_event_time_span_listener(
+                _on_compile_phase)
+            jax.monitoring.register_event_listener(_on_cache_event)
+            _WATCHING = True
+    return True
+
+
+def _on_compile_phase(event, start_time, end_time, **kwargs):
+    """A phase of a compile, in ``time.time()`` seconds: a span in the
+    ring (a child of the build in progress, or a root), and the build's
+    own record. Never raises into jax."""
+    try:
+        phase = COMPILE_PHASES.get(event)
+        if phase is None or not registry().enabled:
+            return
+        attrs = {"fun_name": kwargs.get("fun_name", "")}
+        if phase == "compile":
+            cache = getattr(_CACHE_OUTCOME, "outcome", None)
+            if cache:
+                attrs["cache"] = cache
+                _CACHE_OUTCOME.outcome = None
+        build = _BUILD.get()
+        if build is not None:
+            build.spans.append((phase, start_time, end_time))
+            attrs["kind"] = build.kind
+        t = tracer()
+        t.record("jax/" + phase, start_time - t.epoch, end_time - t.epoch,
+                 context=_CTX.get() if build is not None else None, **attrs)
+    except Exception:
+        pass
+
+
+def _on_cache_event(event, **kwargs):
+    """jax's persistent cache answered a compile: counted for the build
+    in progress, and named on the compile span that follows."""
+    try:
+        outcome = CACHE_OUTCOMES.get(event)
+        if outcome is None or not registry().enabled:
+            return
+        _CACHE_OUTCOME.outcome = outcome
+        build = _BUILD.get()
+        if build is not None:
+            registry().counter(
+                "dl4j_jax_cache_requests_total",
+                "jax persistent-cache answers in executable builds",
+                labels=("kind", "outcome")).labels(
+                    kind=build.kind, outcome=outcome).inc()
+    except Exception:
+        pass

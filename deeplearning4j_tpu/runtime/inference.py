@@ -45,8 +45,8 @@ from ..common import faults
 from ..common.environment import environment
 from ..common.locks import ordered_condition, ordered_lock
 from ..common.metrics import linear_buckets, registry
-from ..common.tracing import (current_context, record_disposition, span,
-                              tracer, use_context)
+from ..common.tracing import (build_span, current_context,
+                              record_disposition, span, tracer, use_context)
 
 
 # ---------------------------------------------------------------------------
@@ -162,6 +162,11 @@ def counted_jit(fn: Callable, tag: str, **jit_kwargs) -> Callable:
     falls back to the live jit for that signature — cache problems may
     cost a compile, never an exception; each such fallback is logged at
     warning and observed as ``cache=bypass:call-error``.
+
+    A first call is one build: the span ``compile/<kind>`` with jax's
+    ``jax/trace``, ``jax/lower`` and ``jax/compile`` spans as its children,
+    and their self seconds in ``dl4j_compile_phase_seconds_total{kind,
+    phase}`` beside ``dl4j_compile_seconds`` (``tracing.build_span``).
     """
     from . import compile_cache
 
@@ -179,20 +184,26 @@ def counted_jit(fn: Callable, tag: str, **jit_kwargs) -> Callable:
         call = entries.get(sig)
         if call is None:
             t0 = time.perf_counter()
-            call, label = compile_cache.aot_entry(jfn, tag, args, jit_kwargs)
-            # dl4j_compiles_total keeps the base label; the reasoned form
-            # ("bypass:donation", ...) lands on dl4j_compile_seconds
-            environment().record_compile((tag,) + sig,
-                                         cache=label.partition(":")[0])
-            if call is jfn:
-                out = jfn(*args)  # first call compiles via the live jit
-            else:
-                try:
-                    out = call(*args)
-                except Exception as e:
-                    return call_failed(sig, e, args)
+            # jax's trace / lower / compile of this build are the span's
+            # children and its phase counters (common/tracing.py)
+            with build_span(kind) as build:
+                call, label = compile_cache.aot_entry(jfn, tag, args,
+                                                      jit_kwargs)
+                build.set(cache=label)
+                # dl4j_compiles_total keeps the base label; the reasoned
+                # form ("bypass:donation", ...) lands on dl4j_compile_seconds
+                environment().record_compile((tag,) + sig,
+                                             cache=label.partition(":")[0])
+                if call is jfn:
+                    out = jfn(*args)  # first call compiles via the live jit
+                else:
+                    try:
+                        out = call(*args)
+                    except Exception as e:
+                        return call_failed(sig, e, args)
             compile_cache.observe_compile(kind, label,
                                           time.perf_counter() - t0)
+            build.count_phases()
             entries[sig] = call
             return out
         if call is jfn:
