@@ -52,13 +52,21 @@ and a call without ``causal`` traces to kernels without any of this.
 **Packed rows.** With ``segment_ids`` (``[B, S]`` int32: the document a
 position belongs to) a key is visible only to the queries of its own
 document. The kernels take the ids twice, as a column ``[B, S, 1]`` for the
-rows of a score tile and as a row ``[B, 1, S]`` for its columns, and compare
-them in every tile they compute (the tiles a causal mask empties are
-skipped as before; a tile that lies wholly between two documents is still
-computed). A call without ``segment_ids`` traces to the kernels it traced
-to before: no operand, no compare.
-``dl4j_boundary_kernel_passes_total{kernel,kind}`` counts the passes traced
-with ids.
+rows of a score tile and as a row ``[B, 1, S]`` for its columns. Under
+``causal`` the three passes also take a table of where the documents lie
+by blocks (`_DocTable`: for each query block the first key block its
+documents reach, for each key block the last query block, and each block's
+least and greatest id), made from the ids once a call outside the kernels
+and prefetched into SMEM. A tile that lies wholly between two documents is
+then skipped as the tiles above the diagonal are: no matmul, no exp, and
+index maps clamped to the live run of blocks, so nothing is fetched for it;
+a live tile inside one document computes without comparing ids, and only
+the tiles a boundary crosses compare them. The outputs are those of every
+causal tile computed, to the bit. Ids that do not decrease along a row
+give the most skipping; any ids give the same results. A call without
+``segment_ids`` traces to the kernels it traced to before: no operand, no
+compare. ``dl4j_boundary_kernel_passes_total{kernel,kind}`` counts the
+passes traced with ids; `document_tiles` says how a row's grid falls.
 
 Sequence lengths that don't divide the tiles are zero-padded to the tile
 boundary (padded keys masked off, padded query rows sliced away). A fully
@@ -81,6 +89,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -108,15 +117,99 @@ def _params(n_parallel):
 # forward
 # ---------------------------------------------------------------------------
 
-def _on_live_tile(step, iq, ik, tq, tk):
+def _on_live_tile(step, iq, ik, tq, tk, docs=None):
     """Run ``step(masked)`` once if the [tq, tk] tile (iq, ik) is live
     under a causal mask — with the mask only where the diagonal crosses
-    the tile — and not at all if the mask empties it."""
+    the tile — and not at all if the mask empties it.
+
+    With ``docs`` (a packed call's `_DocTable`, bound to its operand) a
+    tile that holds no pair of one document is not live either, and a live
+    one runs ``step(masked, apart)``: ``apart`` False where the tile lies
+    inside one document, so that its ids need no compare."""
     live = ik * tk <= iq * tq + (tq - 1)     # some key <= some query
     below = ik * tk + (tk - 1) <= iq * tq    # every key <= every query
-    pl.when(below)(lambda: step(False))
-    pl.when(jnp.logical_and(live, jnp.logical_not(below)))(
-        lambda: step(True))
+    if docs is None:
+        pl.when(below)(lambda: step(False))
+        pl.when(jnp.logical_and(live, jnp.logical_not(below)))(
+            lambda: step(True))
+        return
+    bh = pl.program_id(0)
+    live = live & docs.live(bh, iq, ik)
+    whole = docs.whole(bh, iq, ik)
+    for masked, where in ((False, below), (True, ~below)):
+        for apart, kind in ((False, whole), (True, ~whole)):
+            pl.when(live & where & kind)(
+                functools.partial(step, masked, apart))
+
+
+class _DocTable:
+    """Where a packed causal call's documents lie, by blocks: one int32
+    operand ``[B * 3 (n_q + n_k)]`` that the three streaming passes
+    prefetch into SMEM (`_doc_table` makes it). For each batch row, in
+    turn: ``lo`` [n_q], the first key block that a query block can share a
+    document with; ``hi`` [n_k], the last query block that a key block
+    can; the least and the greatest id of each query block; those of each
+    key block. A ``bh`` is a grid's first index, ``heads`` of them to a
+    batch row. Bound to the operand's ref with `at` (an index map's or the
+    kernel's)."""
+
+    def __init__(self, heads, n_q, n_k, ref=None):
+        self.heads, self.n_q, self.n_k, self.ref = heads, n_q, n_k, ref
+
+    def at(self, ref):
+        return _DocTable(self.heads, self.n_q, self.n_k, ref)
+
+    def _get(self, bh, part, i):
+        n_q, n_k = self.n_q, self.n_k
+        start = (0, n_q, n_q + n_k, 2 * n_q + n_k, 3 * n_q + n_k,
+                 3 * n_q + 2 * n_k)[part]
+        # one head a row (the host's count) indexes with plain integers
+        row = bh if self.heads == 1 else jax.lax.div(bh, self.heads)
+        return self.ref[row * (3 * (n_q + n_k)) + start + i]
+
+    def lo(self, bh, iq):
+        return self._get(bh, 0, iq)
+
+    def hi(self, bh, ik):
+        return self._get(bh, 1, ik)
+
+    def live(self, bh, iq, ik):
+        """Whether tile (iq, ik) can hold a pair of one document: the tiles
+        it rules out are a prefix of a query block's key blocks and a
+        suffix of a key block's query blocks, which the index maps clamp
+        away."""
+        return (ik >= self.lo(bh, iq)) & (iq <= self.hi(bh, ik))
+
+    def whole(self, bh, iq, ik):
+        """Whether every query and every key of the tile has one id: the
+        queries' greatest is the keys' least and the queries' least the
+        keys' greatest."""
+        return ((self._get(bh, 3, iq) == self._get(bh, 4, ik))
+                & (self._get(bh, 2, iq) == self._get(bh, 5, ik)))
+
+
+def _doc_table(seg, tile_q, tile_k, xp=jnp):
+    """`_DocTable`'s operand, ``[B, 3 (n_q + n_k)]`` int32, from a packed
+    call's ids ``seg`` [B, S] (S a multiple of both tiles). Made once a
+    call, outside the kernels; ``xp`` numpy counts the tiles of a row on
+    the host (`document_tiles`). Blocks ``meet`` where their id ranges
+    overlap: blocks that do not meet share no document, whatever the ids.
+    Where ids do not decrease along a row (a document is one run), the
+    blocks that meet a query block are the run of key blocks from ``lo``,
+    and those of a key block the run of query blocks up to ``hi``, so
+    every tile that is not live holds no pair of one document."""
+    B, S = seg.shape
+    n_q, n_k = S // tile_q, S // tile_k
+    qb, kb = seg.reshape(B, n_q, tile_q), seg.reshape(B, n_k, tile_k)
+    q_min, q_max, k_min, k_max = qb.min(-1), qb.max(-1), kb.min(-1), kb.max(-1)
+    meet = ((k_max[:, None, :] >= q_min[:, :, None])
+            & (k_min[:, None, :] <= q_max[:, :, None]))      # [B, n_q, n_k]
+    # a query block meets the key block of its last query, a key block the
+    # query block of its first key: neither search comes back empty
+    lo = xp.argmax(meet, axis=2)
+    hi = n_q - 1 - xp.argmax(meet[:, ::-1, :], axis=1)
+    return xp.concatenate([lo, hi, q_min, q_max, k_min, k_max],
+                          axis=1).astype(xp.int32)
 
 
 def _same_document(s, seg):
@@ -128,7 +221,8 @@ def _same_document(s, seg):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                m_sc, l_sc, acc_sc, *, scale, causal, n_k, skip, seg=None):
+                m_sc, l_sc, acc_sc, *, scale, causal, n_k, skip, seg=None,
+                docs=None):
     iq, ik = pl.program_id(1), pl.program_id(2)
     tq, tk = q_ref.shape[1], k_ref.shape[1]
 
@@ -138,7 +232,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def _step(masked):
+    def _step(masked, apart=True):
         # dots run in the input dtype (bf16 stays on the fast MXU path)
         # with f32 accumulation; softmax stats are always f32
         q, k, v = q_ref[0], k_ref[0], v_ref[0]           # [TQ,D],[TK,D]
@@ -146,7 +240,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                                 preferred_element_type=jnp.float32) * scale
         if mask_ref is not None:
             s = jnp.where(mask_ref[0][:, 0][None, :] != 0, s, _NEG_INF)
-        s = _same_document(s, seg)
+        s = _same_document(s, seg if apart else None)
         if masked:
             q_pos = iq * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
             k_pos = ik * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
@@ -162,7 +256,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
             jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     if skip:
-        _on_live_tile(_step, iq, ik, tq, tk)
+        _on_live_tile(_step, iq, ik, tq, tk, docs)
     else:
         _step(causal)
 
@@ -180,11 +274,15 @@ def _fwd_kernel_nomask(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_sc, l_sc, acc_sc, **kw)
 
 
-def _segmented(kernel, masked: bool):
+def _segmented(kernel, masked: bool, docs=None):
     """``kernel`` (one of the three streaming kernels) for a call with
-    document ids: after its first refs come [the key mask,] the queries'
-    ids and the keys' ids, then the rest as the kernel takes them."""
+    document ids: [the `_DocTable` operand first, where ``docs`` is given,]
+    after its first refs come [the key mask,] the queries' ids and the
+    keys' ids, then the rest as the kernel takes them."""
     def run(*refs, n_in, **kw):
+        if docs is not None:
+            kw["docs"] = docs.at(refs[0])
+            refs = refs[1:]
         first, refs = refs[:n_in], refs[n_in:]
         mask_ref = refs[0] if masked else None
         seg = refs[masked:masked + 2]
@@ -192,21 +290,29 @@ def _segmented(kernel, masked: bool):
     return run
 
 
-def _kv_block(iq, ik, tile_q, tile_k, skip):
+def _kv_block(iq, ik, tile_q, tile_k, skip, lo=None):
     """The k/v block that step (iq, ik) of fwd/dq reads. A step the causal
     mask empties names the last live block of its row instead of its own:
-    that block is resident already, so no DMA is issued for it."""
+    that block is resident already, so no DMA is issued for it. With
+    ``lo`` (a packed call's first key block that the query block's
+    documents reach) the steps before it name that block, which the row's
+    first live step reads."""
     if not skip:
         return ik
+    if lo is not None:
+        ik = jnp.maximum(ik, lo)
     return jnp.minimum(ik, jax.lax.div(iq * tile_q + (tile_q - 1), tile_k))
 
 
-def _q_block(ik, iq, tile_q, tile_k, skip):
+def _q_block(ik, iq, tile_q, tile_k, skip, hi=None):
     """The q-side block that step (ik, iq) of dkv reads: the first live
-    one of its column while the steps above the diagonal pass."""
+    one of its column while the steps above the diagonal pass, and with
+    ``hi`` (a packed call's last query block that the key block's
+    documents reach) the last live one once the steps pass it."""
     if not skip:
         return iq
-    return jnp.maximum(iq, jax.lax.div(ik * tile_k, tile_q))
+    iq = jnp.maximum(iq, jax.lax.div(ik * tile_k, tile_q))
+    return iq if hi is None else jnp.minimum(iq, hi)
 
 
 def _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip):
@@ -232,15 +338,47 @@ def _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip):
 def _seg_specs(seg, heads, tile_q, tile_k, q_block, k_block):
     """Specs and operands of a packed call's ids: ``seg`` = (column [B, S,
     1], row [B, 1, S]), shared by the ``heads`` heads of a batch row;
-    ``q_block`` / ``k_block`` give a grid step's block along S."""
+    ``q_block`` / ``k_block`` give a grid step's block along S from the
+    index map's arguments."""
     if seg is None:
         return [], []
     row = lambda bh: jax.lax.div(bh, heads)
     return ([pl.BlockSpec((1, tile_q, 1),
-                          lambda bh, i, j: (row(bh), q_block(i, j), 0)),
+                          lambda bh, *a: (row(bh), q_block(bh, *a), 0)),
              pl.BlockSpec((1, 1, tile_k),
-                          lambda bh, i, j: (row(bh), 0, k_block(i, j)))],
+                          lambda bh, *a: (row(bh), 0, k_block(bh, *a)))],
             list(seg))
+
+
+def _doc_operand(seg, skip, heads, tile_q, tile_k):
+    """(`_DocTable`, [its operand]) of a pass over ``tile_q`` x ``tile_k``
+    tiles where the call is packed and causal; (None, []) for any other,
+    whose pass traces as it did before documents were skipped."""
+    if seg is None or not skip:
+        return None, []
+    ids = seg[1][:, 0, :]                            # the row view, [B, S]
+    S = ids.shape[1]
+    return (_DocTable(heads, S // tile_q, S // tile_k),
+            [_doc_table(ids, tile_q, tile_k).reshape(-1)])
+
+
+def _pallas_call(kernel, tables, *, grid, in_specs, out_specs, out_shape,
+                 scratch_shapes):
+    """A streaming pass's ``pallas_call``: with ``tables`` (a packed causal
+    call's `_DocTable` operand) these come first, prefetched into SMEM,
+    and every index map takes them after the grid's indices."""
+    if not tables:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch_shapes,
+            compiler_params=_params(2), interpret=_interpret())
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
+        out_specs=out_specs, scratch_shapes=scratch_shapes)
+    return functools.partial(
+        pl.pallas_call(kernel, grid_spec=spec, out_shape=out_shape,
+                       compiler_params=_params(2), interpret=_interpret()),
+        *tables)
 
 
 def _count_boundary_pass(kind):
@@ -278,12 +416,17 @@ def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
     _count_tiles("fwd", n_q, n_k, tile_q, tile_k, skip)
     _count_split_pass("fwd", D, Dv)
     grid = (BH, n_q, n_k)
+    docs, tables = _doc_operand(seg, skip, heads, tile_q, tile_k)
 
-    def kv(bh, iq, ik):
-        return bh, _kv_block(iq, ik, tile_q, tile_k, skip), 0
+    def own_q(bh, iq, ik, *t):
+        return bh, iq, 0
+
+    def kv(bh, iq, ik, *t):
+        lo = None if docs is None else docs.at(*t).lo(bh, iq)
+        return bh, _kv_block(iq, ik, tile_q, tile_k, skip, lo), 0
 
     in_specs = [
-        pl.BlockSpec((1, tile_q, D), lambda bh, iq, ik: (bh, iq, 0)),
+        pl.BlockSpec((1, tile_q, D), own_q),
         pl.BlockSpec((1, tile_k, D), kv),
         pl.BlockSpec((1, tile_k, Dv), kv),
     ]
@@ -295,21 +438,20 @@ def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
         kern = _fwd_kernel if mask is not None else _fwd_kernel_nomask
     else:
         _count_boundary_pass("fwd")
-        kern = functools.partial(_segmented(_fwd_kernel, mask is not None),
-                                 n_in=3)
-    specs, ids = _seg_specs(
-        seg, heads, tile_q, tile_k, lambda iq, ik: iq,
-        lambda iq, ik: _kv_block(iq, ik, tile_q, tile_k, skip))
+        kern = functools.partial(
+            _segmented(_fwd_kernel, mask is not None, docs), n_in=3)
+    specs, ids = _seg_specs(seg, heads, tile_q, tile_k,
+                            lambda *a: own_q(*a)[1], lambda *a: kv(*a)[1])
     in_specs, args = in_specs + specs, args + ids
     kern = functools.partial(kern, scale=scale, causal=causal, n_k=n_k,
                              skip=skip)
-    return pl.pallas_call(
-        kern,
+    return _pallas_call(
+        kern, tables,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, tile_q, Dv), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, tile_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, tile_q, Dv), own_q),
+            pl.BlockSpec((1, tile_q, 1), own_q),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, Dv), q.dtype),
@@ -320,8 +462,6 @@ def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
             pltpu.VMEM((tile_q, 1), jnp.float32),
             pltpu.VMEM((tile_q, Dv), jnp.float32),
         ],
-        compiler_params=_params(2),
-        interpret=_interpret(),
     )(*args)
 
 
@@ -345,17 +485,19 @@ def _p_tile(q, k, mask_row, lse, iq, ik, scale, causal, seg=None):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
-               dq_ref, dq_sc, *, scale, causal, n_k, skip, seg=None):
+               dq_ref, dq_sc, *, scale, causal, n_k, skip, seg=None,
+               docs=None):
     iq, ik = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    def _step(masked):
+    def _step(masked, apart=True):
         q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
         mrow = mask_ref[0] if mask_ref is not None else None
-        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked, seg)
+        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked,
+                       seg if apart else None)
         dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [TQ, TK]
         ds = p * (dp - delta_ref[0])
@@ -363,7 +505,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
                               preferred_element_type=jnp.float32) * scale
 
     if skip:
-        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1])
+        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1], docs)
     else:
         _step(causal)
 
@@ -380,7 +522,7 @@ def _dq_kernel_nomask(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
                 dk_ref, dv_ref, dk_sc, dv_sc, *, scale, causal, n_q, skip,
-                seg=None):
+                seg=None, docs=None):
     ik, iq = pl.program_id(1), pl.program_id(2)
 
     @pl.when(iq == 0)
@@ -388,10 +530,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    def _step(masked):
+    def _step(masked, apart=True):
         q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
         mrow = mask_ref[0] if mask_ref is not None else None
-        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked, seg)
+        p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked,
+                       seg if apart else None)
         dv_sc[...] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -403,7 +546,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
             preferred_element_type=jnp.float32) * scale
 
     if skip:
-        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1])
+        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1], docs)
     else:
         _step(causal)
 
@@ -447,12 +590,15 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
     def kspec(f, width=D):
         return pl.BlockSpec((1, tile_k, width), f)
 
+    docs, tables = _doc_operand(seg, skip, heads, tile_q, tile_k)
+
     # dq: stream kv blocks for each q block
-    def own_q(bh, iq, ik):
+    def own_q(bh, iq, ik, *t):
         return bh, iq, 0
 
-    def kv(bh, iq, ik):
-        return bh, _kv_block(iq, ik, tile_q, tile_k, skip), 0
+    def kv(bh, iq, ik, *t):
+        lo = None if docs is None else docs.at(*t).lo(bh, iq)
+        return bh, _kv_block(iq, ik, tile_q, tile_k, skip, lo), 0
 
     in_specs = [qspec(own_q), kspec(kv), kspec(kv, Dv),             # q k v
                 qspec(own_q, Dv),                                   # g
@@ -467,34 +613,32 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
         if seg is None:
             kern = masked if mask is not None else plain
         else:
-            kern = functools.partial(_segmented(masked, mask is not None),
-                                     n_in=6)
+            kern = functools.partial(
+                _segmented(masked, mask is not None, docs), n_in=6)
         return functools.partial(kern, scale=scale, causal=causal, skip=skip,
                                  **kw)
 
     if seg is not None:
         _count_boundary_pass("dq")
         _count_boundary_pass("dkv")
-    specs, ids = _seg_specs(
-        seg, heads, tile_q, tile_k, lambda iq, ik: iq,
-        lambda iq, ik: _kv_block(iq, ik, tile_q, tile_k, skip))
-    dq = pl.pallas_call(
-        kernel(_dq_kernel, _dq_kernel_nomask, n_k=n_k),
+    specs, ids = _seg_specs(seg, heads, tile_q, tile_k,
+                            lambda *a: own_q(*a)[1], lambda *a: kv(*a)[1])
+    dq = _pallas_call(
+        kernel(_dq_kernel, _dq_kernel_nomask, n_k=n_k), tables,
         grid=(BH, n_q, n_k),
         in_specs=in_specs + specs,
         out_specs=qspec(own_q),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((tile_q, D), jnp.float32)],
-        compiler_params=_params(2),
-        interpret=_interpret(),
     )(*args, *ids)
 
     # dk/dv: stream q blocks for each kv block
-    def own_kv(bh, ik, iq):
+    def own_kv(bh, ik, iq, *t):
         return bh, ik, 0
 
-    def qs(bh, ik, iq):
-        return bh, _q_block(ik, iq, tile_q, tile_k, skip), 0
+    def qs(bh, ik, iq, *t):
+        hi = None if docs is None else docs.at(*t).hi(bh, ik)
+        return bh, _q_block(ik, iq, tile_q, tile_k, skip, hi), 0
 
     in_specs = [qspec(qs), kspec(own_kv), kspec(own_kv, Dv), qspec(qs, Dv),
                 qspec(qs, 1), qspec(qs, 1)]
@@ -502,12 +646,10 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
     if mask is not None:
         in_specs.append(kspec(own_kv, 1))
         args.append(mask)
-    specs, _ = _seg_specs(
-        seg, heads, tile_q, tile_k,
-        lambda ik, iq: _q_block(ik, iq, tile_q, tile_k, skip),
-        lambda ik, iq: ik)
-    dk, dv = pl.pallas_call(
-        kernel(_dkv_kernel, _dkv_kernel_nomask, n_q=n_q),
+    specs, _ = _seg_specs(seg, heads, tile_q, tile_k,
+                          lambda *a: qs(*a)[1], lambda *a: own_kv(*a)[1])
+    dk, dv = _pallas_call(
+        kernel(_dkv_kernel, _dkv_kernel_nomask, n_q=n_q), tables,
         grid=(BH, n_k, n_q),
         in_specs=in_specs + specs,
         out_specs=[kspec(own_kv), kspec(own_kv, Dv)],
@@ -515,8 +657,6 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((tile_k, D), jnp.float32),
                         pltpu.VMEM((tile_k, Dv), jnp.float32)],
-        compiler_params=_params(2),
-        interpret=_interpret(),
     )(*args, *ids)
     return dq, dk, dv
 
@@ -891,6 +1031,47 @@ def _bwd_tile_cap(causal):
     return 1024 if causal else 512
 
 
+def _tiles(S, causal, tile_q=None, tile_k=None):
+    """(tile_q, tile_k, S_pad) of a streaming call of length ``S``: the
+    tiles given, or the defaults fitted to the padded length. The one
+    source of the tiles for the kernels and for `document_tiles`."""
+    if tile_q is None or tile_k is None:
+        if S <= 128:
+            return S, S, S
+        S_pad = -(-S // 128) * 128
+        want_q, want_k = _default_tiles(causal)
+        return (_fit_tile(tile_q or want_q, S_pad),
+                _fit_tile(tile_k or want_k, S_pad), S_pad)
+    tile_q = min(tile_q, max(S, 1))
+    tile_k = min(tile_k, max(S, 1))
+    lcm = tile_q * tile_k // math.gcd(tile_q, tile_k)
+    return tile_q, tile_k, -(-S // lcm) * lcm
+
+
+def document_tiles(lengths) -> dict:
+    """How one head's causal grid of a packed row falls, at the tiles the
+    streaming kernels take for the row's length (no tiles given; the
+    backward keeps a causal call's): ``lengths`` are the row's documents'
+    lengths, end to end. ``{"skipped": tiles on or below the diagonal
+    that hold no pair of one document, "whole": live tiles inside one
+    document, "boundary": live tiles that a boundary crosses}``, counted
+    on the host from the kernels' own `_doc_table`."""
+    S = int(sum(lengths))
+    tile_q, tile_k, S_pad = _tiles(S, True)
+    ids = np.repeat(np.arange(len(lengths), dtype=np.int32),
+                    np.asarray(lengths, np.int64))
+    ids = np.pad(ids, (0, S_pad - S), mode="edge")[None]
+    n_q, n_k = S_pad // tile_q, S_pad // tile_k
+    table = _DocTable(1, n_q, n_k, _doc_table(ids, tile_q, tile_k, np)[0])
+    out = {"skipped": 0, "whole": 0, "boundary": 0}
+    for iq in range(n_q):
+        for ik in range(min(n_k, (iq * tile_q + tile_q - 1) // tile_k + 1)):
+            kind = ("skipped" if not table.live(0, iq, ik) else
+                    "whole" if table.whole(0, iq, ik) else "boundary")
+            out[kind] += 1
+    return out
+
+
 def _prep(q, k, v, mask, scale, tile_q, tile_k, causal):
     """Resolve tiles, zero-pad S to the tile boundary, flatten to the
     kernels' [B*H, S_pad, D] layout. Returns (qf, kf, vf, mf, scale,
@@ -898,20 +1079,7 @@ def _prep(q, k, v, mask, scale, tile_q, tile_k, causal):
     B, S, H, D = q.shape
     Dv = v.shape[-1]
     scale = scale if scale is not None else D ** -0.5
-    if tile_q is None or tile_k is None:
-        if S <= 128:
-            S_pad = S
-            tile_q = tile_k = S
-        else:
-            S_pad = -(-S // 128) * 128
-            want_q, want_k = _default_tiles(causal)
-            tile_q = _fit_tile(tile_q or want_q, S_pad)
-            tile_k = _fit_tile(tile_k or want_k, S_pad)
-    else:
-        tile_q = min(tile_q, max(S, 1))
-        tile_k = min(tile_k, max(S, 1))
-        lcm = tile_q * tile_k // math.gcd(tile_q, tile_k)
-        S_pad = -(-S // lcm) * lcm
+    tile_q, tile_k, S_pad = _tiles(S, causal, tile_q, tile_k)
     if S_pad != S:
         pad = [(0, 0), (0, S_pad - S), (0, 0), (0, 0)]
         q = jnp.pad(q, pad)
@@ -955,7 +1123,8 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     kernels (a head's whole [S, S] score tile in VMEM, one fused backward
     kernel, blocks straight from the packed layout) and longer sequences
     stream [tile_q, tile_k] tiles (2048 x 512, or 1024 x 1024 under
-    ``causal``, where the tiles the mask empties are skipped; shrunk to
+    ``causal``, where the tiles the mask empties are skipped, and with
+    ``segment_ids`` the tiles between two documents too; shrunk to
     divisors of the padded length). Times on the chip:
     ``kernels._flash_rule``."""
     D = head_dim if head_dim is not None else q.shape[-1]

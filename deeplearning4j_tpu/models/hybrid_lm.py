@@ -855,9 +855,21 @@ def observe_packed(lengths) -> None:
     ``dl4j_packed_rows_total``, ``dl4j_packed_documents_total`` and
     ``dl4j_packed_attended_pairs_total``: the (query, key) pairs of one
     head's causal attention inside documents, a row's sum of
-    ``len (len + 1) / 2``."""
+    ``len (len + 1) / 2``. ``dl4j_flash_doc_tiles_total{kind}``: one
+    head's causal grid of the streaming flash kernels at the row's length,
+    ``skipped`` (no pair of one document), ``whole`` (inside one) or
+    ``boundary`` (`flash_attention.document_tiles`)."""
     from ..common.environment import environment
+    from ..kernels.flash_attention import document_tiles
     reg = environment().metrics()
+    tiles = reg.counter("dl4j_flash_doc_tiles_total",
+                        "Tiles of one head's causal flash-attention grid "
+                        "over the packed rows trained on: skipped (no pair "
+                        "of one document), whole (inside one document) or "
+                        "boundary", labels=("kind",))
+    for row in lengths:
+        for kind, n in document_tiles(row).items():
+            tiles.labels(kind=kind).inc(n)
     reg.counter("dl4j_packed_rows_total",
                 "Packed rows trained on").inc(len(lengths))
     reg.counter("dl4j_packed_documents_total",

@@ -4,6 +4,8 @@ flash kernels, interpreted) and the whole hybrid model at the tiny granite
 shape, against the benchmark's plain reference
 (`benchmark/reference/granite_hybrid.py`) and against each document run
 alone. The fused conv's part is in `test_ssm_fused.py`."""
+import functools
+import importlib
 import json
 import os
 import sys
@@ -22,6 +24,9 @@ from deeplearning4j_tpu.common.metrics import registry  # noqa: E402
 from deeplearning4j_tpu.kernels import attention, flash_attention  # noqa: E402
 from deeplearning4j_tpu.models import hybrid_lm  # noqa: E402
 from deeplearning4j_tpu.ops import ssm_scan  # noqa: E402
+
+# the package re-exports the kernel function under its module's name
+fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
 
 with open(os.path.join(ROOT, "benchmark/tests/configs/granite-tiny.json")) as f:
     CFG = json.load(f)
@@ -213,6 +218,121 @@ def test_segment_ids_and_a_key_mask_together():
     want = attention(q, k, v, path="xla", head_dim=d, mask=mask,
                      segment_ids=seg)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def tile_kinds(ids, tile_q, tile_k):
+    """By brute force over one row's ids, the causal tiles (those on or
+    below the diagonal) of a ``tile_q`` x ``tile_k`` grid: ``skipped`` where
+    no causal pair is of one document, ``whole`` where every one is,
+    ``boundary`` else."""
+    ids = np.asarray(ids)
+    S = ids.size
+    out = {"skipped": 0, "whole": 0, "boundary": 0}
+    for iq in range(S // tile_q):
+        q = np.arange(iq * tile_q, (iq + 1) * tile_q)[:, None]
+        for ik in range(S // tile_k):
+            k = np.arange(ik * tile_k, (ik + 1) * tile_k)[None, :]
+            causal = q >= k
+            if not causal.any():
+                continue
+            same = ids[q] == ids[k]
+            kind = ("skipped" if not (same & causal).any() else
+                    "whole" if same[causal].all() else "boundary")
+            out[kind] += 1
+    return out
+
+
+# (t, tile_q, tile_k): each row's documents make skipped, whole-document
+# and boundary tiles; "ragged" is no multiple of its tiles
+DOC_TILE_CASES = {"square": (128, 16, 16), "ragged": (120, 16, 16),
+                  "tall": (128, 32, 16), "wide": (128, 16, 32)}
+DOC_STARTS = ([3, 40, 41, 90], [64])
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["ids", "ids+keymask"])
+@pytest.mark.parametrize("case", sorted(DOC_TILE_CASES))
+def test_skipped_document_tiles_change_no_bit(case, masked):
+    """The streaming passes of a packed causal call skip the tiles that
+    lie wholly between two documents and compare no ids inside one: o,
+    lse, dq, dk and dv equal those of the same passes with every causal
+    tile computed and compared (``skip_empty=False``), to the bit, and
+    match the XLA core."""
+    t, tile_q, tile_k = DOC_TILE_CASES[case]
+    b, h, d = 2, 2, 16
+    q, k, v, ct = (x.reshape(b, t, h, d) for x in qkv(b, t, h, h, d, seed=14))
+    seg = segments(t, *DOC_STARTS)
+    mask = None
+    if masked:          # a quarter of the keys off; a document's first on
+        rs = np.random.RandomState(15)
+        m = (rs.rand(b, t) > 0.25).astype(np.int32)
+        m[np.asarray(jnp.diff(seg, axis=1, prepend=-1)) != 0] = 1
+        mask = jnp.asarray(m)
+
+    (qf, kf, vf, mf, scale, tq, tk, _, S_pad, *_) = fa._prep(
+        q, k, v, mask, None, tile_q, tile_k, True)
+    ids = jnp.pad(seg, [(0, 0), (0, S_pad - t)], mode="edge")
+    kinds = [tile_kinds(row, tq, tk) for row in np.asarray(ids)]
+    assert all(sum(n[kind] for n in kinds) for kind in kinds[0])
+    col_row = (ids[:, :, None], ids[:, None, :])
+    gf = jax.random.normal(jax.random.key(16), qf.shape)
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def passes(skip_empty):     # one program: forward, dq, dkv
+        o, lse = fa._flash_fwd(qf, kf, vf, mf, scale, True, tq, tk,
+                               skip_empty=skip_empty, seg=col_row, heads=h)
+        return (o, lse) + tuple(fa._flash_bwd(
+            qf, kf, vf, mf, o, lse, gf, scale, True, tq, tk,
+            skip_empty=skip_empty, seg=col_row, heads=h))
+
+    for name, a, w in zip(("o", "lse", "dq", "dk", "dv"), passes(True),
+                          passes(False)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w),
+                                      err_msg=name)
+
+    kernel = lambda q, k, v: flash_attention(
+        q, k, v, mask=mask, causal=True, segment_ids=seg, tile_q=tile_q,
+        tile_k=tile_k)
+    plain = lambda q, k, v: attention(
+        q, k, v, path="xla", head_dim=d, mask=mask, causal=True,
+        segment_ids=seg)
+    np.testing.assert_allclose(kernel(q, k, v), plain(q, k, v),
+                               rtol=2e-4, atol=2e-5)
+    grads = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * ct),
+                               argnums=(0, 1, 2))(q, k, v)
+    for a, w in zip(grads(kernel), grads(plain)):
+        np.testing.assert_allclose(a, w, rtol=2e-3, atol=2e-4)
+
+
+def test_a_tile_between_documents_is_never_computed():
+    """Two documents that fill whole tiles, and NaN (which poisons whatever
+    reads it) in the first one's values, then in the second one's output
+    cotangent: the second document's o and dq, then the first one's dk and
+    dv, stay finite, as no pass computes a tile between the two; with every
+    causal tile computed they do not."""
+    b, t, h, d, tile = 1, 64, 1, 16, 16
+    q, k, v, _ = (x.reshape(b, t, h, d) for x in qkv(b, t, h, h, d, seed=17))
+    ids = segments(t, [32])
+    (qf, kf, vf, mf, scale, tq, tk, *_) = fa._prep(q, k, v, None, None,
+                                                    tile, tile, True)
+    col_row = (ids[:, :, None], ids[:, None, :])
+    g = jax.random.normal(jax.random.key(18), qf.shape)
+
+    def passes(skip_empty, vf, g):
+        o, lse = fa._flash_fwd(qf, kf, vf, mf, scale, True, tq, tk,
+                               skip_empty=skip_empty, seg=col_row, heads=h)
+        return dict(zip(("o", "dq", "dk", "dv"), (o,) + fa._flash_bwd(
+            qf, kf, vf, mf, o, lse, g, scale, True, tq, tk,
+            skip_empty=skip_empty, seg=col_row, heads=h)))
+
+    first, second = slice(0, 32), slice(32, t)
+    for poisoned, read in (((vf.at[:, first].set(jnp.nan), g),
+                            {"o": second, "dq": second}),
+                           ((vf, g.at[:, second].set(jnp.nan)),
+                            {"dk": first, "dv": first})):
+        got, every = passes(True, *poisoned), passes(False, *poisoned)
+        for name, rows in read.items():
+            assert np.isfinite(np.asarray(got[name][:, rows])).all(), name
+            assert not np.isfinite(np.asarray(every[name][:, rows])).all()
 
 
 def test_the_scale_is_the_callers():
@@ -480,6 +600,42 @@ def test_observe_packed_feeds_the_packing_counters():
     hybrid_lm.observe_packed([[3, 5], [8]])
     after = [value(n) for n in names]
     assert [a - b for a, b in zip(after, before)] == [2, 3, 6 + 15 + 36]
+
+
+DOC_TILE_ROWS = {
+    # a row of the cell's packing at half its length; one document; 16
+    # documents of one tile each
+    "packed": lambda: ref.pack_rows(3_800_000_001, 1, 8192, {
+        "median": 512, "sigma": 1.5, "min": 32, "max": 8192})[1][0],
+    "one-document": lambda: [8192],
+    "block-documents": lambda: [1024] * 16,
+}
+
+
+@pytest.mark.parametrize("row", sorted(DOC_TILE_ROWS))
+def test_observe_packed_counts_the_flash_document_tiles(row):
+    """``dl4j_flash_doc_tiles_total{kind}``, fed on the host from a row's
+    lengths, is a brute-force count over the row's ids at the streaming
+    kernels' tile."""
+    lengths = DOC_TILE_ROWS[row]()
+    fam = lambda: registry().get("dl4j_flash_doc_tiles_total")
+    read = lambda: {kind: fam().labels(kind=kind).value() if fam() else 0.0
+                    for kind in ("skipped", "whole", "boundary")}
+    before = read()
+    hybrid_lm.observe_packed([lengths])
+    got = {kind: n - before[kind] for kind, n in read().items()}
+    S = sum(lengths)
+    tile_q, tile_k, _ = fa._tiles(S, True)
+    assert tile_q == tile_k == 1024
+    ids = np.repeat(np.arange(len(lengths)), lengths)
+    assert got == tile_kinds(ids, tile_q, tile_k)
+    n = S // 1024
+    if row == "one-document":
+        assert got == {"skipped": 0, "whole": n * (n + 1) // 2, "boundary": 0}
+    if row == "block-documents":        # the diagonal alone is live
+        assert got == {"skipped": n * (n - 1) // 2, "whole": n, "boundary": 0}
+    if row == "packed":
+        assert all(got.values())
 
 
 def test_pack_rows_cuts_a_stream_of_documents():

@@ -157,7 +157,9 @@ def test_packed_causal_attention_compiles_for_v5e(shape, grad, one_chip,
                                                   compiled_kernels):
     """The kernels with document ids: the ids' column and row blocks (one
     lane wide, one sublane high) beside the tiles, shared by a batch row's
-    heads through the block index."""
+    heads through the block index; the streaming passes with the
+    documents' table as their first operand, prefetched into SMEM, their
+    index maps clamped by it and the tiles' branches chosen by it."""
     B, H, S, D = shape
     spec = _sds((B, S, H, D), jnp.bfloat16, one_chip)
     ids = _sds((B, S), jnp.int32, one_chip)
@@ -170,9 +172,15 @@ def test_packed_causal_attention_compiles_for_v5e(shape, grad, one_chip,
         fn = core
     compiled, text = _compile(fn, spec, spec, spec, ids)
     one_tile = S <= 512
-    assert text.count('custom_call_target="tpu_custom_call"') == \
-        ((2 if one_tile else 3) if grad else 1)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == ((2 if one_tile else 3) if grad else 1)
     assert compiled.memory_analysis().temp_size_in_bytes < B * H * S * S
+    if not one_tile:
+        tile_q, tile_k, _ = fa._tiles(S, True)
+        table = "operand_layout_constraints={s32[%d]{0}" % (
+            B * 3 * (S // tile_q + S // tile_k))
+        assert all(table in line for line in calls)
 
 
 # the packed granite cell's Mamba-2 layers: B = 1, T = 16,384, d_inner
