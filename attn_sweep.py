@@ -17,6 +17,7 @@ sequence of 8,192, 32 heads of 128).
     chiprun -- python attn_sweep.py --only 512x64x0    # one row, 24 layers
     chiprun -- python attn_sweep.py --only 8192x128x1 --tokens 8192 --hidden 4096 --layers 2
     chiprun -- python attn_sweep.py --only 8192x192x1 --v-head-dim 128 --tokens 16384 --hidden 4096 --layers 2
+    python attn_sweep.py --only 16384x128x1 --tokens 16384 --hidden 4096 --layers 2 --window 1024 --tiles 512x512,1024x1024
     python attn_sweep.py --kernel _parent/deeplearning4j_tpu/kernels/flash_attention.py
 
 Prints one JSON line per row and writes them to
@@ -114,6 +115,12 @@ def main(argv=None):
                     help="the values' width where it is not D (latent "
                          "attention: --only 8192x192x1 --v-head-dim 128 "
                          "--tokens 16384 --hidden 4096); flash only")
+    ap.add_argument("--window", type=int, default=None,
+                    help="a sliding window (causal rows only); flash only")
+    ap.add_argument("--tiles", default=None,
+                    help="comma-separated QxK tiles to time the flash "
+                         "kernel at, each a row of its own (default: the "
+                         "kernel's own choice)")
     ap.add_argument("--out", default="chiprun_out/attn_sweep.jsonl")
     args = ap.parse_args(argv)
 
@@ -129,6 +136,10 @@ def main(argv=None):
     packed = ("head_dim" in inspect.signature(flash).parameters
               and not args.v_head_dim)
     wide = {"v_head_dim": args.v_head_dim} if args.v_head_dim else {}
+    if args.window:
+        wide["window"] = args.window
+    tiles = ([tuple(int(x) for x in t.split("x"))
+              for t in args.tiles.split(",")] if args.tiles else [None])
 
     if args.only:
         rows = [tuple(int(x) for x in r.split("x"))
@@ -137,15 +148,20 @@ def main(argv=None):
         rows = [(T, D, c) for D in HEAD_DIMS for T in SEQS for c in (0, 1)]
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "a") as f:
-        for T, D, causal in rows:
+        for (T, D, causal), tile in ((r, t) for r in rows for t in tiles):
             rec = {"T": T, "head_dim": D, "causal": bool(causal),
                    "B": args.tokens // T,
                    "H": args.hidden // (args.v_head_dim or D), **wide,
                    "layers": args.layers, "kernel": args.kernel or "tree",
                    "device_kind": dev.device_kind}
+            sized = {}
+            if tile:
+                rec["tiles"] = list(tile)
+                sized = {"tile_q": tile[0], "tile_k": tile[1]}
+
             def flash_core(q, k, v, mask, causal):
                 return flash(q, k, v, mask=mask, causal=causal, **wide,
-                             **({"head_dim": D} if packed else {}))
+                             **sized, **({"head_dim": D} if packed else {}))
 
             for name, core in (("xla", xla_core), ("flash", flash_core)):
                 if name not in args.paths.split(",") or (

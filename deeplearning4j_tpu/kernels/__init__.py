@@ -17,7 +17,9 @@ cover the known gaps for the flagship workloads:
   once per traced model; `attention(q, k, v, path=...)` runs the chosen
   core over packed ``[B,T,H·D]`` or ``[B,T,H,D]`` operands with
   ``Hkv <= H`` KV heads: the kernel with the KV heads repeated here, or
-  the one XLA core (f32 scores, key and/or causal mask, f32 softmax).
+  the one XLA core (f32 scores, key and/or causal mask, f32 softmax); a
+  causal call may name a sliding ``window``, which the streaming kernels
+  walk as a band of blocks.
 - `paged_flash_decode`: the decode-side counterpart — walks the paged KV
   block tables in-kernel (scalar-prefetch) with online-softmax
   accumulation, replacing the `jnp.take` gather read of
@@ -306,7 +308,8 @@ def attention_dispatch(seq_len: int, paged: bool = False, *,
 
 def attention(q, k, v, *, path: str, head_dim: int, mask=None,
               causal: bool = False, scale: Optional[float] = None,
-              segment_ids=None, v_head_dim: Optional[int] = None):
+              segment_ids=None, v_head_dim: Optional[int] = None,
+              window: Optional[int] = None):
     """The full-sequence attention core on ``path`` ("flash" | "xla", what
     ``attention_dispatch`` answered for the traced model).
 
@@ -323,13 +326,16 @@ def attention(q, k, v, *, path: str, head_dim: int, mask=None,
     ``v_head_dim`` (None: ``head_dim``) is the values' width where it is
     not the queries' and keys' (latent attention: 192-wide scores,
     128-wide values); ``v`` is then ``[B, T, Hkv*Dv]`` or ``[B, T, Hkv,
-    Dv]``. Returns the context in ``q``'s layout and dtype, ``Dv`` wide a
-    head.
+    Dv]``. ``window`` W (with ``causal``): key ``j`` is visible to query
+    ``i`` iff ``i - W < j <= i``, W keys at most, the query's own among
+    them; None traces to what it traced to before the argument existed.
+    Returns the context in ``q``'s layout and dtype, ``Dv`` wide a head.
 
     "flash" repeats the KV heads for the query heads that share them (the
     kernel's entry takes as many KV heads as query heads) and calls
-    ``flash_attention`` in the layout given. "xla" is the plain core:
-    f32 scores, masks, f32 softmax, weighted sum."""
+    ``flash_attention`` in the layout given (with a window, the streaming
+    kernels over the band's blocks alone). "xla" is the plain core: f32
+    scores, masks, f32 softmax, weighted sum."""
     D = head_dim
     Dv = D if v_head_dim is None else v_head_dim
     B, T = q.shape[:2]
@@ -341,7 +347,8 @@ def attention(q, k, v, *, path: str, head_dim: int, mask=None,
                     for x, d in ((k, D), (v, Dv)))
         return flash_attention(q, k, v, mask=mask, causal=causal,
                                head_dim=D, scale=scale,
-                               segment_ids=segment_ids, v_head_dim=v_head_dim)
+                               segment_ids=segment_ids, v_head_dim=v_head_dim,
+                               window=window)
     big_neg = jnp.finfo(jnp.float32).min
     k, v = (x.reshape(B, T, Hkv, d) for x, d in ((k, D), (v, Dv)))
     s = jnp.einsum("btgrd,bsgd->bgrts", q.reshape(B, T, Hkv, R, D), k,
@@ -354,6 +361,9 @@ def attention(q, k, v, *, path: str, head_dim: int, mask=None,
         s = jnp.where(same[:, None, None], s, big_neg)
     if causal:
         s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, big_neg)
+    if window is not None:
+        pos = jnp.arange(T)
+        s = jnp.where(pos[:, None] - pos[None, :] < window, s, big_neg)
     prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bgrts,bsgd->btgrd", prob, v).reshape(
         q.shape[:2] + ((H, Dv) if q.ndim == 4 else (H * Dv,)))

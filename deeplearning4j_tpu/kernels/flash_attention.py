@@ -68,6 +68,24 @@ give the most skipping; any ids give the same results. A call without
 compare. ``dl4j_boundary_kernel_passes_total{kernel,kind}`` counts the
 passes traced with ids; `document_tiles` says how a row's grid falls.
 
+**A window.** With ``window`` W (causal calls only) key ``j`` is visible to
+query ``i`` iff ``i - W < j <= i``: at most W keys, the query's own among
+them. The three passes then walk only the blocks the band reaches
+(`_Band`): the inner grid axis of fwd and dq is a query block's band of key
+blocks, from the first the band reaches, and that of dkv a key block's band
+of query blocks, from the diagonal's, so a row of 16,384 at W = 1,024 and
+1,024 x 1,024 tiles has 2 steps a block and not 16. A step past the band's
+end (the rows near a sequence's start, whose band is narrower) does no
+matmul, no exp and no fetch: its index maps name the band's last block,
+resident already. Only the tiles a band edge crosses (the diagonal or the
+window's far edge) build the mask; a tile wholly inside the band runs
+unmasked. ``dl4j_flash_tiles_total{kernel,kind}`` counts the band's tiles
+as ``window_computed`` and the rest of the square grid as
+``window_skipped``, ``dl4j_flash_window_passes_total{kernel,window}`` the
+passes. A call without ``window`` traces to the kernels it traced to before
+the argument existed. A window goes with neither ``segment_ids`` nor a key
+mask (not built); a window call always streams.
+
 Sequence lengths that don't divide the tiles are zero-padded to the tile
 boundary (padded keys masked off, padded query rows sliced away). A fully
 masked row degrades to a uniform softmax — identical to what the XLA
@@ -117,7 +135,7 @@ def _params(n_parallel):
 # forward
 # ---------------------------------------------------------------------------
 
-def _on_live_tile(step, iq, ik, tq, tk, docs=None):
+def _on_live_tile(step, iq, ik, tq, tk, docs=None, band=None):
     """Run ``step(masked)`` once if the [tq, tk] tile (iq, ik) is live
     under a causal mask — with the mask only where the diagonal crosses
     the tile — and not at all if the mask empties it.
@@ -125,7 +143,15 @@ def _on_live_tile(step, iq, ik, tq, tk, docs=None):
     With ``docs`` (a packed call's `_DocTable`, bound to its operand) a
     tile that holds no pair of one document is not live either, and a live
     one runs ``step(masked, apart)``: ``apart`` False where the tile lies
-    inside one document, so that its ids need no compare."""
+    inside one document, so that its ids need no compare. With ``band`` (a
+    windowed call's `_Band`) a tile is live where it holds a pair of the
+    band, and masked where a band edge crosses it."""
+    if band is not None:
+        whole = band.whole(iq, ik)
+        pl.when(whole)(lambda: step(False))
+        pl.when(band.live(iq, ik) & jnp.logical_not(whole))(
+            lambda: step(True))
+        return
     live = ik * tk <= iq * tq + (tq - 1)     # some key <= some query
     below = ik * tk + (tk - 1) <= iq * tq    # every key <= every query
     if docs is None:
@@ -212,6 +238,83 @@ def _doc_table(seg, tile_q, tile_k, xp=jnp):
                           axis=1).astype(xp.int32)
 
 
+class _Band:
+    """Where a windowed causal call's band lies, by blocks of ``tq``
+    queries and ``tk`` keys: key ``j`` is visible to query ``i`` iff ``i -
+    window < j <= i``. Query block ``iq`` reaches the key blocks from
+    `kv_first` to `kv_last` (its diagonal's); key block ``ik`` the query
+    blocks from `q_first` (its diagonal's) to `q_last`. ``width_k`` and
+    ``width_q`` are the most blocks a band reaches, the passes' inner grid
+    extents. The block methods take grid indices (traced int32) and give
+    traced ones; the static tile counts are `computed_tiles`."""
+
+    def __init__(self, window, tq, tk, n_q, n_k):
+        self.window, self.tq, self.tk, self.n_q, self.n_k = (
+            int(window), tq, tk, n_q, n_k)
+        W = self.window
+        self.width_k = max((iq * tq + tq - 1) // tk
+                           - max(iq * tq - W + 1, 0) // tk + 1
+                           for iq in range(n_q))
+        self.width_q = max(min((ik * tk + tk + W - 2) // tq, n_q - 1)
+                           - ik * tk // tq + 1 for ik in range(n_k))
+
+    def kv_first(self, iq):
+        return jax.lax.div(jnp.maximum(iq * self.tq - (self.window - 1), 0),
+                           self.tk)
+
+    def kv_block(self, iq, j):
+        """The key block that step ``j`` of query block ``iq`` reads: past
+        the band's last, the last (resident already)."""
+        return jnp.minimum(self.kv_first(iq) + j, jax.lax.div(
+            iq * self.tq + (self.tq - 1), self.tk))
+
+    def q_first(self, ik):
+        return jax.lax.div(ik * self.tk, self.tq)
+
+    def q_block(self, ik, j):
+        """The query block that step ``j`` of key block ``ik`` reads: past
+        the band's last, the last (resident already)."""
+        last = jnp.minimum(jax.lax.div(
+            ik * self.tk + (self.tk + self.window - 2), self.tq),
+            self.n_q - 1)
+        return jnp.minimum(self.q_first(ik) + j, last)
+
+    def live(self, iq, ik):
+        """Whether tile (iq, ik) holds a pair of the band: some key at or
+        before some query, some query fewer than ``window`` after some key,
+        and the query block inside the grid (dkv's steps run past it)."""
+        tq, tk = self.tq, self.tk
+        return ((ik * tk <= iq * tq + (tq - 1))
+                & (iq * tq <= ik * tk + (tk + self.window - 2))
+                & (iq < self.n_q))
+
+    def whole(self, iq, ik):
+        """Whether every pair of tile (iq, ik) lies in the band."""
+        tq, tk = self.tq, self.tk
+        return ((ik * tk + (tk - 1) <= iq * tq)
+                & (ik * tk >= iq * tq + (tq - self.window))
+                & (iq < self.n_q))
+
+    def computed_tiles(self) -> int:
+        """The tiles of one head's square grid that hold a pair of the band
+        (static: the host's count)."""
+        tq, tk, W = self.tq, self.tk, self.window
+        return sum(1 for iq in range(self.n_q) for ik in range(self.n_k)
+                   if ik * tk <= iq * tq + tq - 1
+                   and iq * tq <= ik * tk + tk + W - 2)
+
+
+def _causal_mask(s, iq, ik, tq, tk, window=None):
+    """Scores of tile (iq, ik) with the pairs a causal mask (and, with
+    ``window``, the window's far edge) rules out set to ``_NEG_INF``."""
+    q_pos = iq * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+    k_pos = ik * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = keep & (q_pos - k_pos < window)
+    return jnp.where(keep, s, _NEG_INF)
+
+
 def _same_document(s, seg):
     """Scores with the pairs of different documents masked off; ``seg`` is
     (the queries' ids [1, TQ, 1], the keys' ids [1, 1, TK]) refs or None."""
@@ -222,11 +325,14 @@ def _same_document(s, seg):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                 m_sc, l_sc, acc_sc, *, scale, causal, n_k, skip, seg=None,
-                docs=None):
-    iq, ik = pl.program_id(1), pl.program_id(2)
+                docs=None, band=None):
+    iq, j = pl.program_id(1), pl.program_id(2)
     tq, tk = q_ref.shape[1], k_ref.shape[1]
+    # the key block: the grid's own, or a windowed call's step on its band
+    ik = j if band is None else band.kv_first(iq) + j
+    window = None if band is None else band.window
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _init():
         m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
@@ -242,9 +348,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
             s = jnp.where(mask_ref[0][:, 0][None, :] != 0, s, _NEG_INF)
         s = _same_document(s, seg if apart else None)
         if masked:
-            q_pos = iq * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-            k_pos = ik * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = _causal_mask(s, iq, ik, tq, tk, window)
 
         m_prev = m_sc[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -256,11 +360,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
             jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     if skip:
-        _on_live_tile(_step, iq, ik, tq, tk, docs)
+        _on_live_tile(_step, iq, ik, tq, tk, docs, band)
     else:
         _step(causal)
 
-    @pl.when(ik == n_k - 1)
+    @pl.when(j == n_k - 1)
     def _done():
         l = l_sc[:, 0]
         o_ref[0] = (acc_sc[...] / jnp.maximum(l, 1e-30)[:, None]).astype(
@@ -315,12 +419,18 @@ def _q_block(ik, iq, tile_q, tile_k, skip, hi=None):
     return iq if hi is None else jnp.minimum(iq, hi)
 
 
-def _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip):
+def _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip, band=None):
     """Tick ``dl4j_flash_tiles_total{kernel,kind}`` with one head's grid:
     the tiles a pass computes and the ones it skips, static per traced
-    call."""
-    live = sum(min(n_k, (iq * tile_q + tile_q - 1) // tile_k + 1)
-               for iq in range(n_q)) if skip else n_q * n_k
+    call; a windowed pass's under kinds of their own, ``window_computed``
+    (the band's) and ``window_skipped`` (the rest of the square grid)."""
+    if band is not None:
+        live, kinds = band.computed_tiles(), ("window_computed",
+                                              "window_skipped")
+    else:
+        live = sum(min(n_k, (iq * tile_q + tile_q - 1) // tile_k + 1)
+                   for iq in range(n_q)) if skip else n_q * n_k
+        kinds = ("computed", "skipped")
     try:
         from ..common.environment import environment
         tiles = environment().metrics().counter(
@@ -329,8 +439,23 @@ def _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip):
             "pass computes, and that it skips because a causal mask "
             "empties them, added up at trace time",
             labels=("kernel", "kind"))
-        tiles.labels(kernel=kernel, kind="computed").inc(live)
-        tiles.labels(kernel=kernel, kind="skipped").inc(n_q * n_k - live)
+        tiles.labels(kernel=kernel, kind=kinds[0]).inc(live)
+        tiles.labels(kernel=kernel, kind=kinds[1]).inc(n_q * n_k - live)
+    except Exception:
+        pass  # observability must never break a trace
+
+
+def _count_window_pass(kernel, window):
+    """Tick ``dl4j_flash_window_passes_total{kernel,window}``: one
+    streaming pass traced with a window."""
+    try:
+        from ..common.environment import environment
+        environment().metrics().counter(
+            "dl4j_flash_window_passes_total",
+            "Streaming flash-attention passes traced with a sliding window, "
+            "counted at trace time",
+            labels=("kernel", "window")).labels(
+                kernel=kernel, window=str(window)).inc()
     except Exception:
         pass  # observability must never break a trace
 
@@ -405,23 +530,34 @@ def _count_split_pass(kernel, D, Dv):
         pass  # observability must never break a trace
 
 
+def _band(window, tile_q, tile_k, n_q, n_k):
+    return None if window is None else _Band(window, tile_q, tile_k, n_q, n_k)
+
+
 def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
-               skip_empty=True, seg=None, heads=1):
+               skip_empty=True, seg=None, heads=1, window=None):
     """``skip_empty=False`` (tests only) computes and masks every tile of
-    a causal call, as the kernels did before they skipped."""
+    a causal call, as the kernels did before they skipped. ``window``: a
+    causal call's band (`_Band`), with neither ``mask`` nor ``seg``."""
     BH, S, D = q.shape
     Dv = v.shape[-1]            # the values' width; D where a call names none
     n_q, n_k = S // tile_q, S // tile_k
     skip = causal and skip_empty
-    _count_tiles("fwd", n_q, n_k, tile_q, tile_k, skip)
+    band = _band(window, tile_q, tile_k, n_q, n_k)
+    _count_tiles("fwd", n_q, n_k, tile_q, tile_k, skip, band)
     _count_split_pass("fwd", D, Dv)
     grid = (BH, n_q, n_k)
+    if band is not None:
+        _count_window_pass("fwd", window)
+        grid = (BH, n_q, band.width_k)
     docs, tables = _doc_operand(seg, skip, heads, tile_q, tile_k)
 
     def own_q(bh, iq, ik, *t):
         return bh, iq, 0
 
     def kv(bh, iq, ik, *t):
+        if band is not None:
+            return bh, band.kv_block(iq, ik), 0
         lo = None if docs is None else docs.at(*t).lo(bh, iq)
         return bh, _kv_block(iq, ik, tile_q, tile_k, skip, lo), 0
 
@@ -443,8 +579,10 @@ def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
     specs, ids = _seg_specs(seg, heads, tile_q, tile_k,
                             lambda *a: own_q(*a)[1], lambda *a: kv(*a)[1])
     in_specs, args = in_specs + specs, args + ids
-    kern = functools.partial(kern, scale=scale, causal=causal, n_k=n_k,
+    kern = functools.partial(kern, scale=scale, causal=causal, n_k=grid[2],
                              skip=skip)
+    if band is not None:
+        kern = functools.partial(kern, band=band)
     return _pallas_call(
         kern, tables,
         grid=grid,
@@ -469,7 +607,8 @@ def _flash_fwd(q, k, v, mask, scale, causal, tile_q, tile_k,
 # backward
 # ---------------------------------------------------------------------------
 
-def _p_tile(q, k, mask_row, lse, iq, ik, scale, causal, seg=None):
+def _p_tile(q, k, mask_row, lse, iq, ik, scale, causal, seg=None,
+            window=None):
     """Recompute the [TQ, TK] probability tile from saved lse."""
     tq, tk = q.shape[0], k.shape[0]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -478,18 +617,18 @@ def _p_tile(q, k, mask_row, lse, iq, ik, scale, causal, seg=None):
         s = jnp.where(mask_row[:, 0][None, :] != 0, s, _NEG_INF)
     s = _same_document(s, seg)
     if causal:
-        q_pos = iq * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-        k_pos = ik * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        s = _causal_mask(s, iq, ik, tq, tk, window)
     return jnp.exp(s - lse[:, 0][:, None]), s
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
                dq_ref, dq_sc, *, scale, causal, n_k, skip, seg=None,
-               docs=None):
-    iq, ik = pl.program_id(1), pl.program_id(2)
+               docs=None, band=None):
+    iq, j = pl.program_id(1), pl.program_id(2)
+    ik = j if band is None else band.kv_first(iq) + j
+    window = None if band is None else band.window
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
@@ -497,7 +636,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
         q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
         mrow = mask_ref[0] if mask_ref is not None else None
         p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked,
-                       seg if apart else None)
+                       seg if apart else None, window)
         dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [TQ, TK]
         ds = p * (dp - delta_ref[0])
@@ -505,11 +644,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
                               preferred_element_type=jnp.float32) * scale
 
     if skip:
-        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1], docs)
+        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1], docs,
+                      band)
     else:
         _step(causal)
 
-    @pl.when(ik == n_k - 1)
+    @pl.when(j == n_k - 1)
     def _done():
         dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
 
@@ -522,10 +662,13 @@ def _dq_kernel_nomask(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
                 dk_ref, dv_ref, dk_sc, dv_sc, *, scale, causal, n_q, skip,
-                seg=None, docs=None):
-    ik, iq = pl.program_id(1), pl.program_id(2)
+                seg=None, docs=None, band=None):
+    ik, j = pl.program_id(1), pl.program_id(2)
+    # the query block: the grid's own, or a windowed call's step on its band
+    iq = j if band is None else band.q_first(ik) + j
+    window = None if band is None else band.window
 
-    @pl.when(iq == 0)
+    @pl.when(j == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
@@ -534,7 +677,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
         q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
         mrow = mask_ref[0] if mask_ref is not None else None
         p, _ = _p_tile(q, k, mrow, lse_ref[0], iq, ik, scale, masked,
-                       seg if apart else None)
+                       seg if apart else None, window)
         dv_sc[...] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -546,11 +689,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
             preferred_element_type=jnp.float32) * scale
 
     if skip:
-        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1], docs)
+        _on_live_tile(_step, iq, ik, q_ref.shape[1], k_ref.shape[1], docs,
+                      band)
     else:
         _step(causal)
 
-    @pl.when(iq == n_q - 1)
+    @pl.when(j == n_q - 1)
     def _done():
         dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
@@ -563,7 +707,7 @@ def _dkv_kernel_nomask(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
-               lse_cot=None, skip_empty=True, seg=None, heads=1):
+               lse_cot=None, skip_empty=True, seg=None, heads=1, window=None):
     BH, S, D = q.shape
     Dv = v.shape[-1]
     cap = _bwd_tile_cap(causal)
@@ -573,9 +717,12 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
         tile_k = cap
     n_q, n_k = S // tile_q, S // tile_k
     skip = causal and skip_empty
+    band = _band(window, tile_q, tile_k, n_q, n_k)
     for kernel in ("dq", "dkv"):
-        _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip)
+        _count_tiles(kernel, n_q, n_k, tile_q, tile_k, skip, band)
         _count_split_pass(kernel, D, Dv)
+        if band is not None:
+            _count_window_pass(kernel, window)
     delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1,
                     keepdims=True)  # [BH, S, 1]
     if lse_cot is not None:
@@ -597,6 +744,8 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
         return bh, iq, 0
 
     def kv(bh, iq, ik, *t):
+        if band is not None:
+            return bh, band.kv_block(iq, ik), 0
         lo = None if docs is None else docs.at(*t).lo(bh, iq)
         return bh, _kv_block(iq, ik, tile_q, tile_k, skip, lo), 0
 
@@ -615,17 +764,22 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
         else:
             kern = functools.partial(
                 _segmented(masked, mask is not None, docs), n_in=6)
+        if band is not None:
+            kw["band"] = band
         return functools.partial(kern, scale=scale, causal=causal, skip=skip,
                                  **kw)
 
     if seg is not None:
         _count_boundary_pass("dq")
         _count_boundary_pass("dkv")
+    # a windowed call's inner axes walk the band, not the whole row
+    inner_k, inner_q = ((n_k, n_q) if band is None
+                        else (band.width_k, band.width_q))
     specs, ids = _seg_specs(seg, heads, tile_q, tile_k,
                             lambda *a: own_q(*a)[1], lambda *a: kv(*a)[1])
     dq = _pallas_call(
-        kernel(_dq_kernel, _dq_kernel_nomask, n_k=n_k), tables,
-        grid=(BH, n_q, n_k),
+        kernel(_dq_kernel, _dq_kernel_nomask, n_k=inner_k), tables,
+        grid=(BH, n_q, inner_k),
         in_specs=in_specs + specs,
         out_specs=qspec(own_q),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -637,6 +791,8 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
         return bh, ik, 0
 
     def qs(bh, ik, iq, *t):
+        if band is not None:
+            return bh, band.q_block(ik, iq), 0
         hi = None if docs is None else docs.at(*t).hi(bh, ik)
         return bh, _q_block(ik, iq, tile_q, tile_k, skip, hi), 0
 
@@ -649,8 +805,8 @@ def _flash_bwd(q, k, v, mask, o, lse, g, scale, causal, tile_q, tile_k,
     specs, _ = _seg_specs(seg, heads, tile_q, tile_k,
                           lambda *a: qs(*a)[1], lambda *a: own_kv(*a)[1])
     dk, dv = _pallas_call(
-        kernel(_dkv_kernel, _dkv_kernel_nomask, n_q=n_q), tables,
-        grid=(BH, n_k, n_q),
+        kernel(_dkv_kernel, _dkv_kernel_nomask, n_q=inner_q), tables,
+        grid=(BH, n_k, inner_q),
         in_specs=in_specs + specs,
         out_specs=[kspec(own_kv), kspec(own_kv, Dv)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -719,6 +875,30 @@ def _flash_masked_b(scale, causal, tile_q, tile_k, res, g):
 
 
 _flash_masked.defvjp(_flash_masked_f, _flash_masked_b)
+
+
+# a sliding window: causal, no key mask, no document ids
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_window(q, k, v, scale, tile_q, tile_k, window):
+    o, _ = _flash_fwd(q, k, v, None, scale, True, tile_q, tile_k,
+                      window=window)
+    return o
+
+
+def _flash_window_f(q, k, v, scale, tile_q, tile_k, window):
+    o, lse = _named(*_flash_fwd(q, k, v, None, scale, True, tile_q, tile_k,
+                                window=window))
+    return o, (q, k, v, o, lse)
+
+
+def _flash_window_b(scale, tile_q, tile_k, window, res, g):
+    q, k, v, o, lse = res
+    return _flash_bwd(q, k, v, None, o, lse[..., None], g, scale, True,
+                      tile_q, tile_k, window=window)
+
+
+_flash_window.defvjp(_flash_window_f, _flash_window_b)
 
 
 # packed rows: document ids beside an optional key mask
@@ -987,11 +1167,11 @@ def _flash_one_tile(q, k, v, mask, seg, causal, scale, D):
     return out[:, :S] if S_pad != S else out
 
 
-def streams(seq_len, head_dim, v_head_dim=None) -> bool:
+def streams(seq_len, head_dim, v_head_dim=None, window=None) -> bool:
     """Whether `flash_attention` called with no tiles takes the streaming
     kernels, whose forward rules name their output and log-sum-exp
     (`SAVED_OUT`, `SAVED_LSE`), rather than the one-tile kernels."""
-    return (v_head_dim not in (None, head_dim)
+    return (v_head_dim not in (None, head_dim) or window is not None
             or _padded_len(seq_len) > _ONE_TILE_MAX)
 
 
@@ -1018,7 +1198,15 @@ def _default_tiles(causal):
     5.91 at 512 x 1,024 and 5.24 at 1,024 x 1,024 (36 of 64); S=1,024 and
     2,048 at D=64 and 128 order the same way (``attn_sweep.py``, chip runs
     of PR 28; 2,048-wide tiles need a raised VMEM limit and were no
-    faster). Larger D and longer S than those were not measured."""
+    faster). Larger D and longer S than those were not measured.
+
+    A window takes the causal tiles too: at S=16,384, D=128, W=1,024 (32
+    heads) a layer's forward / forward+backward took 5.84 / 19.48 ms at
+    1,024 x 1,024 (2 band steps a query block, both masked), 7.23 / 20.24
+    at 512 x 512 (3 steps, the middle one unmasked), 6.21 / 20.08 at 512
+    x 1,024, 9.45 / 23.58 at 1,024 x 512 and 8.41 / 24.92 at 256 x 512
+    (``attn_sweep.py --window 1024 --tiles ...``, chip runs on a v5e); the
+    whole causal layer there, 19.56 / 69.63."""
     return (1024, 1024) if causal else (2048, 512)
 
 
@@ -1099,7 +1287,8 @@ def _prep(q, k, v, mask, scale, tile_q, tile_k, causal):
 def flash_attention(q, k, v, mask=None, causal: bool = False,
                     scale: float = None, tile_q: int = None,
                     tile_k: int = None, head_dim: int = None,
-                    segment_ids=None, v_head_dim: int = None):
+                    segment_ids=None, v_head_dim: int = None,
+                    window: int = None):
     """Flash attention over [B, S, H, D] (BTHD, the framework convention)
     or, with ``head_dim`` given, over [B, S, H*D] as the q/k/v projections
     produce it (the result has the layout of the inputs).
@@ -1126,10 +1315,18 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     ``causal``, where the tiles the mask empties are skipped, and with
     ``segment_ids`` the tiles between two documents too; shrunk to
     divisors of the padded length). Times on the chip:
-    ``kernels._flash_rule``."""
+    ``kernels._flash_rule``.
+
+    ``window`` W (``causal`` only, with neither ``mask`` nor
+    ``segment_ids``): key ``j`` is visible to query ``i`` iff ``i - W < j
+    <= i``; the kernels walk the band's blocks alone (the module's
+    docstring). Such a call always streams."""
     D = head_dim if head_dim is not None else q.shape[-1]
     Dv = D if v_head_dim is None else v_head_dim
     scale = scale if scale is not None else D ** -0.5
+    if window is not None:
+        return _flash_windowed(q, k, v, mask, causal, scale, tile_q, tile_k,
+                               D, Dv, segment_ids, int(window))
     one_tile = (tile_q is None and tile_k is None
                 and not streams(q.shape[1], D, Dv))
     # the one-tile kernels take heads packed, the streaming ones apart;
@@ -1158,6 +1355,30 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     out = jnp.moveaxis(out.reshape(B, H, S_pad, Dv), 1, 2)
     out = out[:, :S] if S_pad != S else out
     # the inputs' layout, Dv wide a head
+    return out.reshape(shape[:2] + ((H, Dv) if len(shape) == 4
+                                    else (H * Dv,)))
+
+
+def _flash_windowed(q, k, v, mask, causal, scale, tile_q, tile_k, D, Dv,
+                    segment_ids, window):
+    """`flash_attention` with a window, in the inputs' layout. A padded
+    key lies after every real query, so the causal mask hides it without
+    a key mask; padded query rows are sliced away."""
+    if not causal:
+        raise ValueError("a window is a causal call's: pass causal=True")
+    if mask is not None or segment_ids is not None:
+        raise NotImplementedError(
+            "a window with a key mask or document ids is not built")
+    if window < 1:
+        raise ValueError(f"window must be at least 1, got {window}")
+    shape = q.shape
+    q, k = (x.reshape(shape[:2] + (-1, D)) for x in (q, k))
+    v = v.reshape(shape[:2] + (-1, Dv))
+    (qf, kf, vf, _, scale, tile_q, tile_k,
+     S, S_pad, B, H, D) = _prep(q, k, v, None, scale, tile_q, tile_k, True)
+    out = _flash_window(qf, kf, vf, scale, tile_q, tile_k, window)
+    out = jnp.moveaxis(out.reshape(B, H, S_pad, Dv), 1, 2)
+    out = out[:, :S] if S_pad != S else out
     return out.reshape(shape[:2] + ((H, Dv) if len(shape) == 4
                                     else (H * Dv,)))
 

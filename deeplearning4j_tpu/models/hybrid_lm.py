@@ -4,13 +4,15 @@ with the keys of ``nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16``'s
 ``config.json``), by the same blocks the dense ``granitemoehybrid`` layer
 (``ibm-granite/granite-4.0-h-micro``), and the DeepSeek-V3 family's decoder
 (``jdopensource/JoyAI-LLM-Flash``: latent attention with a rotary part,
-gated-SiLU experts, one multi-token-prediction module), on the training
-path.
+gated-SiLU experts, one multi-token-prediction module), and a decoder that
+mixes sliding-window and full grouped-query attention before softmax-routed
+experts (``JetBrains/Mellum2-12B-A2.5B-Instruct``), on the training path.
 
 A stack of pre-norm residual blocks whose mixers follow a pattern string,
-one letter a block: ``M`` Mamba-2, ``E`` sparse experts beside a shared
-expert, ``*`` causal grouped-query attention, ``L`` causal latent
-attention, ``-`` a dense MLP. Four
+one letter a block: ``M`` Mamba-2, ``E`` sparse experts (beside a shared
+expert where one is configured), ``*`` causal grouped-query attention,
+``W`` the same over a sliding window, ``L`` causal latent attention, ``-``
+a dense MLP. Four
 scalar multipliers (each 1 unless the configuration says otherwise: ``m_e``
 ``embedding_multiplier``, ``m_r`` ``residual_multiplier``, ``m_a``
 ``attention_multiplier``, ``m_l`` ``logits_scaling``):
@@ -50,12 +52,19 @@ d[i]``, and a document's last token predicts nothing.
   time as the minor axis, ``[B, channels, T]``, from the input projection's
   result to the output projection's operand.
 ``E``:
-    s = sigmoid(u_f32 . W_r);  top-k of s;  g_k = scale * s_k / (sum + 1e-20)
+    ``scoring_func`` "sigmoid":
+        s = sigmoid(u_f32 . W_r);  top-k of s;  g_k = scale s_k / (sum + 1e-20)
+    ``scoring_func`` "softmax" (``norm_topk_prob``; Mellum2, scale 1):
+        p = softmax(u_f32 . W_r) over every expert;  top-k of p;
+        g_k = scale p_k / (sum of the chosen p)
     ``moe_hidden_act`` "relu2" (nemotron_h):
         out = sum_k g_k W2_{e_k} relu(W1_{e_k} u)^2  +  V2 relu(V1 u)^2
-    ``moe_hidden_act`` "silu" (DeepSeek-V3 family), routed and shared alike:
+    ``moe_hidden_act`` "silu" (DeepSeek-V3 family, Mellum2), routed and
+    shared alike:
         out = sum_k g_k Down_e(silu(Gate_e u) * Up_e u) + Shared(u)
         (``w1`` holds [Gate_e ; Up_e] as one [2 F, E] matrix an expert)
+  ``moe_shared_expert_intermediate_size`` 0: no shared term and no shared
+  weights (Mellum2).
   with only the terms of the experts HELD here computed (`ops.moe`): the
   config says which contiguous range that is; the router keeps all its
   outputs. ``e_score_correction_bias`` (a buffer the published training
@@ -80,9 +89,30 @@ d[i]``, and a document's last token predicts nothing.
     q = u W_q (H heads), k, v = u W_k, u W_v (H_kv heads), causal
     softmax(m_a q k^T) v with query head i on KV head i // (H/H_kv), then
     W_o; m_a = 1 / sqrt(d) unless ``attention_multiplier`` is given. No
-    bias, no rotary or other positional embedding (neither family's
+    bias. No rotary or other positional embedding unless
+    ``rope_parameters`` names one (neither nemotron_h's nor granite's
     modelling code applies one here). The core goes where
     `kernels.attention_dispatch` says.
+``W``: ``*`` over a window: key j is visible to query i iff
+    i - W < j <= i (W = ``sliding_window``, the query's own key among
+    them); the core goes under the scope ``attn_window`` (``*``'s under
+    ``attn_core``), through the streaming kernels' band
+    (`kernels.flash_attention`) on the flash path.
+Rotary of ``*`` and ``W`` (``rope_parameters``, a section a kind:
+    "full_attention" for ``*``, "sliding_attention" for ``W``; Mellum2):
+    half-split, the pair (x_i, x_{i+d/2}) of q and k turned by pos * f_i at
+    positions 0..T-1, cos and sin times the attention factor a (a score
+    carries a^2); float32 angles and arithmetic.
+    "default": f_i = theta^(-2i/d), a = 1.
+    "yarn" (factor s over ``original_max_position_embeddings`` L, betas
+    beta_fast and beta_slow): with b_i = theta^(2i/d),
+        low  = floor(d ln(L / (beta_fast 2 pi)) / (2 ln theta)),
+        high = ceil(d ln(L / (beta_slow 2 pi)) / (2 ln theta)),
+        r_i  = clip((i - low) / (high - low), 0, 1),
+        f_i  = (1 - r_i) / b_i + r_i / (s b_i),
+    each pair ramped from its own frequency (below ``low``) to a
+    sixteenth of it (above ``high``) at s = 16; a = ``attention_factor``.
+    At d = 128, theta = 5e5, L = 8,192, betas 32 and 1: low 18, high 35.
 ``-``:
     ``mlp_hidden_act`` "silu":  [a | b] = u W_in (E -> 2 F);
                                 out = W_out (silu(a) * b)      (granite)
@@ -110,7 +140,9 @@ final norm:
 Not built (the published models have them): nemotron's second, denoising
 tower and block-diffusion decoding (its ``config.json`` has no key for
 them); the latent paged cache and the absorbed decode form of ``L``; MTP in
-the decode scheduler; the update of the router's bias.
+the decode scheduler; the update of the router's bias; a window-aware
+paged cache for ``W``; packed rows (``segment_ids``) through ``W`` or a
+rotary embedding.
 Training only: no cache, no recurrent state for `DecodeEngine`, no
 sharding rules (``make_train_step`` takes ``mesh=None``).
 """
@@ -134,6 +166,9 @@ from ..ops.ssm_scan import ssd_chunked_scan
 from . import _optim
 
 MAMBA, EXPERTS, ATTENTION, MLP, LATENT = "M", "E", "*", "-", "L"
+WINDOW = "W"
+#: the ``rope_parameters`` section each kind of grouped-query block reads
+ROPE_SECTIONS = {ATTENTION: "full_attention", WINDOW: "sliding_attention"}
 #: the one layer of a multi-token-prediction module, in pattern letters
 MTP_PATTERN = LATENT + EXPERTS
 
@@ -197,6 +232,15 @@ class HybridLMConfig:
     # depth of the whole model, for the residual-output scaling of the
     # initialisation (``rescale_prenorm_residual``); None: the pattern's
     rescale_layers: Optional[int] = None
+    # ``W`` blocks: the keys each query sees, its own among them
+    sliding_window: Optional[int] = None
+    # the rotary embedding of ``*`` and ``W`` blocks, by section
+    # ("full_attention", "sliding_attention"), as ``config.json``'s
+    # ``rope_parameters``; None: none (nemotron_h, granite)
+    rope_parameters: Optional[Dict[str, Dict[str, Any]]] = None
+    # the router's scores: "sigmoid" (DeepSeek-V3, nemotron_h) or
+    # "softmax" (renormalised over the top k)
+    scoring_func: str = "sigmoid"
     dtype: Any = jnp.bfloat16
 
     @property
@@ -278,11 +322,13 @@ def _mixer_shapes(c: HybridLMConfig, kind: str) -> Dict[str, Tuple]:
             raise ValueError(f"unknown moe_hidden_act {c.moe_hidden_act!r}")
         F, Fs = c.moe_intermediate_size, c.moe_shared_expert_intermediate_size
         up = 2 if c.moe_hidden_act == "silu" else 1     # [Gate ; Up]
-        return {"router": ((E, c.n_routed_experts), "router"),
-                "w1": ((c.held, up * F, E), "matrix"),
-                "w2": ((c.held, F, E), "residual_out"),
-                "shared_w1": ((E, up * Fs), "matrix"),
-                "shared_w2": ((Fs, E), "residual_out")}
+        shapes = {"router": ((E, c.n_routed_experts), "router"),
+                  "w1": ((c.held, up * F, E), "matrix"),
+                  "w2": ((c.held, F, E), "residual_out")}
+        if Fs:                          # 0: no shared expert
+            shapes.update(shared_w1=((E, up * Fs), "matrix"),
+                          shared_w2=((Fs, E), "residual_out"))
+        return shapes
     if kind == LATENT:
         H, r = c.num_attention_heads, c.kv_lora_rank
         return {"wq_a": ((E, c.q_lora_rank), "matrix"),
@@ -293,7 +339,7 @@ def _mixer_shapes(c: HybridLMConfig, kind: str) -> Dict[str, Tuple]:
                 "wkv_b": ((r, H * (c.qk_nope_head_dim + c.v_head_dim)),
                           "matrix"),
                 "wo": ((H * c.v_head_dim, E), "residual_out")}
-    if kind == ATTENTION:
+    if kind in (ATTENTION, WINDOW):
         q = c.num_attention_heads * c.head_dim
         kv = c.num_key_value_heads * c.head_dim
         return {"wq": ((E, q), "matrix"), "wk": ((E, kv), "matrix"),
@@ -437,10 +483,13 @@ def _experts(p, u, c: HybridLMConfig):
     u = u.reshape(B * T, E)
     with model_scope("moe_route"):
         idx, gates = moe.route(u, p["router"], c.num_experts_per_tok,
-                               c.routed_scaling_factor)
+                               c.routed_scaling_factor, c.scoring_func)
     routed, counts = moe.routed_experts(
         u, p["w1"], p["w2"], idx, gates, c.first_expert, c.n_routed_experts,
         act=c.moe_hidden_act)
+    if not c.moe_shared_expert_intermediate_size:      # no shared expert
+        with model_scope("moe_route"):
+            return routed.astype(u.dtype).reshape(B, T, E), counts
     shared_mlp = _gated_mlp if c.moe_hidden_act == "silu" else _relu2_mlp
     with model_scope("moe_shared"):
         shared = shared_mlp(u, p["shared_w1"], p["shared_w2"])
@@ -471,6 +520,58 @@ def rotary(x, theta: float):
                 + partner.astype(jnp.float32) * sin).astype(x.dtype)
 
 
+def yarn_range(d: int, theta: float, original: float, beta_fast: float,
+               beta_slow: float) -> Tuple[int, int]:
+    """YaRN's correction range (low, high): the pairs below ``low`` keep
+    their frequency, those above ``high`` are divided by the factor.
+    ``floor`` / ``ceil`` of ``d ln(original / (beta 2 pi)) / (2 ln
+    theta)``, clipped to [0, d - 1]."""
+    dim = lambda beta: (d * math.log(original / (beta * 2 * math.pi))
+                        / (2 * math.log(theta)))
+    return (max(math.floor(dim(beta_fast)), 0),
+            min(math.ceil(dim(beta_slow)), d - 1))
+
+
+def rope_frequencies(d: int, params: Dict[str, Any]):
+    """(inverse frequencies float32 [d / 2], attention factor) of one
+    section of ``rope_parameters``: "default" ``theta^(-2i/d)`` and 1;
+    "yarn" the module docstring's blend and ``attention_factor``."""
+    theta = float(params["rope_theta"])
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    kind = params.get("rope_type", "default")
+    if kind == "default":
+        return inv, 1.0
+    if kind != "yarn":
+        raise ValueError(f"unknown rope_type {kind!r}")
+    factor = float(params["factor"])
+    low, high = yarn_range(d, theta,
+                           float(params["original_max_position_embeddings"]),
+                           float(params["beta_fast"]),
+                           float(params["beta_slow"]))
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    inv = inv / factor * ramp + inv * (1.0 - ramp)
+    return inv, float(params["attention_factor"])
+
+
+def rotary_half(x, inv, attention_factor: float = 1.0):
+    """Half-split (rotate-half) rotary embedding of ``x`` [B, T, heads, d]
+    at positions 0..T-1: the pair ``(x_i, x_{i+d/2})`` turned by ``pos *
+    inv[i]``, cos and sin times ``attention_factor``; float32 angles and
+    arithmetic, the result in ``x``'s dtype."""
+    with model_scope("rope"):
+        d, T = x.shape[-1], x.shape[1]
+        ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+        ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        if attention_factor != 1.0:
+            cos, sin = cos * attention_factor, sin * attention_factor
+        x32 = x.astype(jnp.float32)
+        turned = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]],
+                                 axis=-1)
+        return (x32 * cos + turned * sin).astype(x.dtype)
+
+
 def _latent_attention(p, u, c: HybridLMConfig, path: str):
     B, T, _ = u.shape
     H, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
@@ -496,15 +597,32 @@ def _latent_attention(p, u, c: HybridLMConfig, path: str):
         return jnp.einsum("btf,fe->bte", ctx.reshape(B, T, H * dv), p["wo"])
 
 
-def _attention(p, u, c: HybridLMConfig, path: str, segment_ids=None):
+def _attention(p, u, c: HybridLMConfig, path: str, segment_ids=None,
+               kind: str = ATTENTION):
+    """A ``*`` block, or with ``kind`` ``W`` one over ``sliding_window``
+    keys, its core under a scope of its own (``attn_window``)."""
+    rope = (None if c.rope_parameters is None
+            else c.rope_parameters[ROPE_SECTIONS[kind]])
+    window = c.sliding_window if kind == WINDOW else None
+    if kind == WINDOW and not window:
+        raise ValueError("a `W` block needs sliding_window")
+    if segment_ids is not None and (window or rope):
+        raise NotImplementedError(
+            "packed rows through a window or a rotary embedding are not "
+            "built: positions are a row's 0..T-1")
     with model_scope("attn"):
         q = jnp.einsum("bte,ef->btf", u, p["wq"])
         k = jnp.einsum("bte,ef->btf", u, p["wk"])
         v = jnp.einsum("bte,ef->btf", u, p["wv"])
-        with model_scope("attn_core"):
+        if rope is not None:
+            inv, factor = rope_frequencies(c.head_dim, rope)
+            B, T = q.shape[:2]
+            q, k = (rotary_half(x.reshape(B, T, -1, c.head_dim), inv,
+                                factor).reshape(B, T, -1) for x in (q, k))
+        with model_scope("attn_window" if window else "attn_core"):
             ctx = attention(q, k, v, path=path, head_dim=c.head_dim,
                             causal=True, scale=c.attention_multiplier,
-                            segment_ids=segment_ids)
+                            segment_ids=segment_ids, window=window)
         return jnp.einsum("btf,fe->bte", ctx, p["wo"])
 
 
@@ -536,7 +654,7 @@ def _block(p, h, kind: str, c: HybridLMConfig, path: str, segment_ids=None):
                 "positions are a row's 0..T-1")
         out = _latent_attention(p, u, c, path)
     else:
-        out = _attention(p, u, c, path, segment_ids)
+        out = _attention(p, u, c, path, segment_ids, kind)
     if c.residual_multiplier != 1.0:
         out = out * jnp.asarray(c.residual_multiplier, out.dtype)
     return h + out, counts
@@ -582,14 +700,16 @@ def _embed(params, input_ids, c: HybridLMConfig):
 
 def _attention_path(c: HybridLMConfig, seq_len: int):
     """Asked once per trace, and only by a model that has attention
-    blocks; a model has ``*`` blocks or ``L`` blocks, not both."""
+    blocks; a model has grouped-query blocks (``*``, ``W``) or ``L``
+    blocks, not both."""
     latent = LATENT in c.pattern or c.num_nextn_predict_layers
-    if latent and ATTENTION in c.pattern:
-        raise ValueError("a pattern with both `*` and `L` blocks would ask "
-                         "for the attention path twice")
+    grouped = ATTENTION in c.pattern or WINDOW in c.pattern
+    if latent and grouped:
+        raise ValueError("a pattern with both `*` (or `W`) and `L` blocks "
+                         "would ask for the attention path twice")
     if latent:
         return attention_dispatch(seq_len, head_dim=c.qk_head_dim)
-    if ATTENTION in c.pattern:
+    if grouped:
         return attention_dispatch(seq_len, head_dim=c.head_dim)
     return None
 
@@ -628,10 +748,12 @@ def _blocks(blocks, pattern, h, c: HybridLMConfig, path, remat, segment_ids):
 def _saves_core(kind, c: HybridLMConfig, path, seq_len) -> bool:
     """Whether a block of ``kind`` holds a streaming flash core, whose
     output and log-sum-exp `_SAVE_CORES` keeps."""
-    if path != "flash" or kind not in (ATTENTION, LATENT):
+    if path != "flash" or kind not in (ATTENTION, LATENT, WINDOW):
         return False
     if kind == LATENT:
         return streams(seq_len, c.qk_head_dim, c.v_head_dim)
+    if kind == WINDOW:
+        return streams(seq_len, c.head_dim, window=c.sliding_window)
     return streams(seq_len, c.head_dim)
 
 

@@ -124,14 +124,27 @@ def _grouped_matmul_bwd(transpose_w, res, dy):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-def route(u, router_w, top_k: int, scaling: float):
-    """Sigmoid router over all experts: (``idx`` [t, top_k] int32 expert
-    ids, ``gates`` [t, top_k] float32). ``s = sigmoid(u . W_r)`` in
-    float32; the ``top_k`` largest ``s`` are chosen and weighted
-    ``scaling * s_k / (sum of the chosen s + 1e-20)``."""
-    s = jax.nn.sigmoid(jnp.einsum(
+def route(u, router_w, top_k: int, scaling: float,
+          scoring_func: str = "sigmoid"):
+    """Router over all experts: (``idx`` [t, top_k] int32 expert ids,
+    ``gates`` [t, top_k] float32), from the float32 logits ``u . W_r``.
+    "sigmoid" (DeepSeek-V3, nemotron_h): ``s = sigmoid(logits)``, the
+    ``top_k`` largest ``s`` weighted ``scaling * s_k / (sum of the chosen s
+    + 1e-20)``. "softmax" (``norm_topk_prob``): ``s = softmax(logits)``
+    over all experts, the ``top_k`` largest weighted ``scaling * s_k /
+    (sum of the chosen s)``; a ``scaling`` of 1 multiplies nothing."""
+    logits = jnp.einsum(
         "te,en->tn", u.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+        precision=lax.Precision.HIGHEST)
+    if scoring_func == "softmax":
+        chosen, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        gates = chosen / jnp.sum(chosen, -1, keepdims=True)
+        if scaling != 1.0:
+            gates = scaling * gates
+        return idx.astype(jnp.int32), gates
+    if scoring_func != "sigmoid":
+        raise ValueError(f"unknown scoring_func {scoring_func!r}")
+    s = jax.nn.sigmoid(logits)
     chosen, idx = lax.top_k(s, top_k)
     gates = scaling * chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), gates

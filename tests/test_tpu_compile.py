@@ -145,6 +145,80 @@ def test_latent_attention_core_compiles_for_v5e(grad, one_chip,
                       else [(B, S, H, Dv)])
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("tiles", [None, (1024, 1024)],
+                         ids=["default", "1024x1024"])
+def test_windowed_attention_compiles_for_v5e(tiles, grad, one_chip,
+                                             compiled_kernels):
+    """The Mellum2 cell's window layers: one row of 16,384, 32 query heads
+    of 128 (the 4 KV heads repeated), a window of 1,024, through the three
+    streaming passes over the band's blocks (their inner grid axis a few
+    blocks long, the index maps clamped to the band)."""
+    B, H, S, D = 1, 32, 16384, 128
+    spec = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    sized = {} if tiles is None else dict(tile_q=tiles[0], tile_k=tiles[1])
+    core = lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                              window=1024, **sized)
+    if grad:
+        fn = jax.grad(lambda q, k, v: jnp.sum(
+            core(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    else:
+        fn = core
+    compiled, text = _compile(fn, spec, spec, spec)
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (3 if grad else 1)
+    # no float32 band of scores in HBM: the relayouts of q, k, v, o alone
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        B * H * S * 1024 * 4
+
+
+def test_window_cell_step_compiles_for_v5e(one_chip, compiled_kernels,
+                                           monkeypatch):
+    """The Mellum2 cell's whole training step (`WEWEWE*E`, 16 of 64
+    experts, a 24,576-row vocabulary slice, one row of 16,384, per-block
+    recomputation, Adam) compiles for one v5e and fits its 16 GB: the
+    three window cores, the full one and the grouped expert products as
+    Mosaic kernels (PERF.md gives the compiled sizes)."""
+    import json
+    from benchmark.drivers import train_hybrid_lm, train_window_lm
+    from benchmark.reference import mellum2
+    from deeplearning4j_tpu.models import _optim, hybrid_lm
+    # the expert kernels are interpreted, and the dispatcher answers XLA,
+    # where jax's backend is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root,
+                           "benchmark/configs/mellum2-12b-a2.5b.json")) as f:
+        cfg = json.load(f)
+    c = train_window_lm._program_config(cfg)
+    rate = train_hybrid_lm.learning_rate(cfg["train"])
+
+    def step(params, opt, batch, it):
+        (loss, terms), grads = jax.value_and_grad(
+            lambda p: hybrid_lm._loss_terms(p, batch, c, True),
+            has_aux=True)(params)
+        params, opt = _optim.adam_apply(params, grads, opt, rate(it), it)
+        return params, opt, loss, terms[0]
+
+    on_chip = lambda x: _sds(x.shape, x.dtype, one_chip)
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: mellum2.nest(mellum2.make_flat_params(jax.random.key(0),
+                                                      cfg))))
+    opt = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        hybrid_lm.init_opt_state, params))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) == \
+        595_153_152
+    batch = {"input_ids": _sds((1, 16384), jnp.int32, one_chip)}
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, opt, batch, _sds((), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    # 4 cores x 3 passes, and 8 grouped products a layer (forward, its
+    # recomputation, two backward each)
+    assert text.count('custom_call_target="tpu_custom_call"') == 12 + 4 * 8
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14e9
+
+
 # (B, H, S, D): the packed granite cell's attention layer (32 query heads of
 # 64, the 8 KV heads repeated, scores times 1/64), and a one-tile length
 PACKED_SHAPES = [(1, 32, 16384, 64), (2, 4, 512, 64)]
